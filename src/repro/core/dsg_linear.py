@@ -184,7 +184,6 @@ def swiglu_dsg_gather_sharded(p: dict, x: jax.Array, state: dict,
     n_chunks semantics with chunks == shards), and the FLOP reduction
     ~ (1-gamma) is visible in the compiled HLO."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.parallel import context as pctx
 
     ctx = pctx.current()
@@ -213,11 +212,11 @@ def swiglu_dsg_gather_sharded(p: dict, x: jax.Array, state: dict,
         return jax.lax.psum(y, "model")
 
     nd = (None,) * (x.ndim - 1)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(ba, *nd), P(None, "model"), P(None, "model"),
                   P("model", None), P(), P(None, "model")),
-        out_specs=P(ba, *nd),
+        out_specs=P(ba, *nd), check_vma=False,
     )(x, p["w_gate"], p["w_up"], p["w_down"], state["r"], state["fw"])
 
 

@@ -21,33 +21,28 @@ of v5e.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, wg_ref, wu_ref, wd_ref, tmask_ref, tokmask_ref, o_ref,
-            *, block: int):
+def _kernel(tmask_ref, x_ref, wg_ref, wu_ref, wd_ref, tokmask_ref, o_ref):
+    i = pl.program_id(0)
     f_idx = pl.program_id(1)
 
     @pl.when(f_idx == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(tmask_ref[0, 0] > 0)
+    @pl.when(tmask_ref[i, f_idx] > 0)
     def _compute():
         x = x_ref[...]
         g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
         u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        h = jax.nn.silu(g) * u                          # (bm, bf)
-        # exact per-token mask within the visited block
-        bm, bf = h.shape
-        tok = tokmask_ref[...]                          # (bm, bf//block)
-        h = (h.reshape(bm, bf // block, block)
-             * tok[..., None]).reshape(bm, bf)
+        # exact per-token mask within the visited block, expanded to
+        # neuron columns by the wrapper
+        h = jax.nn.silu(g) * u * tokmask_ref[...]       # (bm, bf)
         o_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[...],
                               preferred_element_type=jnp.float32
                               ).astype(o_ref.dtype)
@@ -68,26 +63,32 @@ def dsg_ffn(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
     gpb = bf // block                                  # groups per f-block
     mt, ft = m // bm, f // bf
 
-    # tile mask: OR of token masks over each (token-tile, f-block) cell
+    # tile mask: OR of token masks over each (token-tile, f-block) cell,
+    # scalar-prefetched so the skip test is an SMEM read
     tile_mask = token_mask.reshape(mt, bm, ft, gpb).max(axis=(1, 3))
-    tile_mask = tile_mask.astype(jnp.float32)
+    tile_mask = (tile_mask > 0).astype(jnp.int32)
+    # per-token mask at neuron granularity: its (bm, bf) blocks tile like h
+    neuron_mask = jnp.repeat(token_mask.astype(jnp.float32), block, axis=1)
 
-    grid = (mt, ft)
-    return pl.pallas_call(
-        functools.partial(_kernel, block=block),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,      # tile mask
+        grid=(mt, ft),
         in_specs=[
-            pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((d, bf), lambda i, j: (0, j)),
-            pl.BlockSpec((d, bf), lambda i, j: (0, j)),
-            pl.BlockSpec((bf, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, gpb), lambda i, j: (i, j)),
+            pl.BlockSpec((bm, d), lambda i, j, tm: (i, 0)),
+            pl.BlockSpec((d, bf), lambda i, j, tm: (0, j)),
+            pl.BlockSpec((d, bf), lambda i, j, tm: (0, j)),
+            pl.BlockSpec((bf, d), lambda i, j, tm: (j, 0)),
+            pl.BlockSpec((bm, bf), lambda i, j, tm: (i, j)),
         ],
-        out_specs=pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
+        out_specs=pl.BlockSpec((bm, d), lambda i, j, tm: (i, 0)),
+    )
+    return pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
+        name="dsg_ffn",
         interpret=interpret,
-    )(x, wg, wu, wd, tile_mask, token_mask.astype(jnp.float32))
+    )(tile_mask, x, wg, wu, wd, neuron_mask)
 
 
 # ---------------------------------------------------------------------------
@@ -108,13 +109,13 @@ def _csr_kernel(idx_ref, cnt_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
 
     @pl.when(j < cnt_ref[b])
     def _compute():
-        x = x_ref[...]                                    # (1, d)
+        x = x_ref[0]                                      # (1, d)
         g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
         u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
         h = jax.nn.silu(g) * u                            # (1, blk)
-        o_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[...],
-                              preferred_element_type=jnp.float32
-                              ).astype(o_ref.dtype)
+        o_ref[0] += jnp.dot(h.astype(x.dtype), wd_ref[...],
+                            preferred_element_type=jnp.float32
+                            ).astype(o_ref.dtype)
 
 
 def dsg_ffn_csr(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
@@ -128,7 +129,9 @@ def dsg_ffn_csr(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
     per lane, zero-padded past counts), counts (B,) -> (B, d).
 
     Grid (B, K), K innermost so the (1, d) output row accumulates in VMEM
-    across the walk.  The index list is scalar-prefetched (the
+    across the walk.  x and the output ride as (B, 1, d), so a lane's
+    (1, 1, d) block keeps its last two dims whole (the TPU compiler's
+    block rule).  The index list is scalar-prefetched (the
     paged-attention page-table idiom): the weight-block index maps read
     `idx[b, j]` directly, so ONLY the kept groups' gate/up/down blocks
     ever leave HBM — weight traffic scales with counts, not F.  Padded
@@ -148,7 +151,7 @@ def dsg_ffn_csr(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
         num_scalar_prefetch=2,      # idx, counts
         grid=(b, k),
         in_specs=[
-            pl.BlockSpec((1, d), lambda bb, jj, idx_p, cnt_p: (bb, 0)),
+            pl.BlockSpec((1, 1, d), lambda bb, jj, idx_p, cnt_p: (bb, 0, 0)),
             pl.BlockSpec((d, block),
                          lambda bb, jj, idx_p, cnt_p: (0, _wcol(bb, jj, idx_p, cnt_p))),
             pl.BlockSpec((d, block),
@@ -156,11 +159,15 @@ def dsg_ffn_csr(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
             pl.BlockSpec((block, d),
                          lambda bb, jj, idx_p, cnt_p: (_wcol(bb, jj, idx_p, cnt_p), 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda bb, jj, idx_p, cnt_p: (bb, 0)),
+        out_specs=pl.BlockSpec((1, 1, d),
+                               lambda bb, jj, idx_p, cnt_p: (bb, 0, 0)),
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         _csr_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, d), x.dtype),
+        name="dsg_ffn_csr",
         interpret=interpret,
-    )(idx.astype(jnp.int32), counts.astype(jnp.int32), x, wg, wu, wd)
+    )(idx.astype(jnp.int32), counts.astype(jnp.int32), x[:, None, :],
+      wg, wu, wd)
+    return y[:, 0, :]

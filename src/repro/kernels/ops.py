@@ -1,15 +1,14 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with interpret=True — the
-kernel body runs as a traced grid loop, validating logic and BlockSpec
-indexing exactly as the Mosaic compiler would see them.  On TPU the same
-call sites compile natively.
+On the CPU the kernels execute with interpret=True — the kernel body
+runs as a traced grid loop, validating logic and BlockSpec indexing.  On
+a TPU the same call sites compile natively, always: interpret mode there
+would hide the device, so it is refused.
 
-`REPRO_INTERPRET=1` (or `=0`) overrides the backend sniffing, so
-tests/CI can force interpret mode explicitly (e.g. when a TPU is
-attached but the suite wants the interpreter's exact semantics).  The
-flag is read at trace time: flipping it after a wrapper has already
-compiled for a given shape will not retrace that shape.
+`REPRO_INTERPRET=1` (or `=0`) overrides the backend sniffing on the CPU
+only; on a TPU `=1` raises.  The flag is read at trace time: flipping it
+after a wrapper has already compiled for a given shape will not retrace
+that shape.
 """
 from __future__ import annotations
 
@@ -25,12 +24,21 @@ from repro.kernels import (drs_search, dsg_ffn, flash_attention as fa,
 def _interpret() -> bool:
     """True when Pallas kernels should run in interpret mode.
 
+    Never on a TPU, where REPRO_INTERPRET=1 raises.  Elsewhere
     REPRO_INTERPRET=1/0 wins when set; otherwise interpret iff the
     default backend is CPU (no Mosaic compiler there)."""
     env = os.environ.get("REPRO_INTERPRET", "")
+    backend = jax.default_backend()
+    if backend == "tpu":
+        if env not in ("", "0"):
+            raise RuntimeError(
+                f"REPRO_INTERPRET={env} on a TPU backend: the Pallas "
+                "kernels compile natively there; interpret mode is for "
+                "the CPU only")
+        return False
     if env != "":
         return env != "0"
-    return jax.default_backend() == "cpu"
+    return backend == "cpu"
 
 
 @partial(jax.jit, static_argnames=("bm",))

@@ -17,10 +17,16 @@ Layout (serving/kv_cache.py PagedBackend, one layer's slice):
                                                 (== the new token's
                                                 absolute position)
 
-Grid: (B, Kv, n_pages), page index innermost so the per-(lane, kv-head)
-flash accumulators carry across the page walk in VMEM scratch.  The page
-table and per-lane depths ride as scalar prefetch, so BlockSpec index
-maps resolve logical->physical page ids before each block fetch:
+Grid: (B, n_pages), page index innermost so the per-lane flash
+accumulators carry across the page walk in VMEM scratch.  Each grid cell
+takes one whole physical page, every KV head of it: the (ps, Kv, D)
+block keeps the pool's last two dims whole, which is what the TPU
+compiler asks of a block (last two dims divisible by (8, 128) or equal
+to the array's).  The page's rows flatten to (ps * Kv, D) and all H
+query heads score against them in one matmul; a static head mask keeps
+each query head on its own KV head.  The page table and per-lane depths
+ride as scalar prefetch, so BlockSpec index maps resolve
+logical->physical page ids before each block fetch:
 
   * depth bounding — the K/V page index map clamps the logical page at
     the lane's depth, `pt[b, min(j, pos[b] // ps)]`; every grid cell
@@ -28,12 +34,12 @@ maps resolve logical->physical page ids before each block fetch:
     and the pipeline's consecutive-identical-index elision skips the
     copy, so pages past the lane's depth are never fetched from HBM.
     `pl.when(j <= pos // ps)` skips their compute as well.
-  * fused scatter — the new token's K/V row is inserted into the
-    gathered tile in VMEM (row `pos % ps` of logical page `pos // ps`),
-    and that updated tile is the kernel's K/V-pool output block (the
-    pools are input/output aliased; the output index map pins the write
-    page for the whole walk, so exactly one page per (lane, kv head) is
-    written back).  Attention therefore sees the new token without a
+  * fused scatter — the write page (logical page `pos // ps`) is copied
+    into the kernel's K/V-pool output block in VMEM and the new token's
+    row is stored at row `pos % ps` (the pools are input/output aliased;
+    the output index map pins the write page for the whole walk, so
+    exactly one page per lane is written back).  Attention reads the
+    write page from that block, so it sees the new token without a
     separate XLA scatter pass.
   * masking convention — row r of logical page j holds absolute
     position t = j * ps + r; valid iff t <= pos (the new token attends
@@ -60,11 +66,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG = -1e30
 
 
-def _kernel(pt_ref, pos_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
+def _kernel(pt_ref, pos_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref, hm_ref,
             o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr, *,
-            scale: float, ps: int, window: int, n_pages: int):
+            scale: float, ps: int, kv: int, window: int, n_pages: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     pos = pos_ref[b]
     lp = pos // ps                   # lane's deepest live logical page
     off = pos % ps                   # new token's row in that page
@@ -79,51 +85,58 @@ def _kernel(pt_ref, pos_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    @pl.when(j == wp)
+    def _scatter():
+        # one page write-back per lane: the output index map pins the
+        # physical write page across the whole walk
+        ko_ref[...] = kp_ref[...]
+        vo_ref[...] = vp_ref[...]
+
+        @pl.when(wp == lp)
+        def _insert():
+            # cast to the pool dtype FIRST so the stored and attended
+            # values match the XLA scatter
+            # (`pool.at[pp, off].set(k_new.astype(pool.dtype))`)
+            ko_ref[0, off] = kn_ref[0].astype(ko_ref.dtype)
+            vo_ref[0, off] = vn_ref[0].astype(vo_ref.dtype)
+
     @pl.when(j <= lp)
     def _compute():
-        # insert the new token's row into the gathered tile (VMEM): cast
-        # to the pool dtype FIRST so the attended values match the XLA
-        # scatter (`pool.at[pp, off].set(k_new.astype(pool.dtype))`)
-        row = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-        ins = (j == lp) & (row == off)
-        k_t = jnp.where(ins, kn_ref[0, 0][None, :].astype(ko_ref.dtype),
-                        kp_ref[0, :, 0, :])
-        v_t = jnp.where(ins, vn_ref[0, 0][None, :].astype(vo_ref.dtype),
-                        vp_ref[0, :, 0, :])
-
-        @pl.when(j == wp)
-        def _scatter():
-            # one page write-back per (lane, kv head): the output index
-            # map pins the physical write page across the whole walk
-            ko_ref[0, :, 0, :] = k_t
-            vo_ref[0, :, 0, :] = v_t
-
-        qg = q_ref[0, 0].astype(jnp.float32)            # (g, D)
+        # the write page is read back from the output block, which holds
+        # the new token's row; every other page comes from the pool
+        cur = j == lp
+        d = q_ref.shape[-1]
+        k_t = jnp.where(cur, ko_ref[0].astype(jnp.float32),
+                        kp_ref[0].astype(jnp.float32)).reshape(ps * kv, d)
+        v_t = jnp.where(cur, vo_ref[0].astype(jnp.float32),
+                        vp_ref[0].astype(jnp.float32)).reshape(ps * kv, d)
+        q = q_ref[0].astype(jnp.float32)                 # (H, D)
         s = jax.lax.dot_general(
-            qg, k_t.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (g, ps)
-        g = s.shape[0]
-        t_abs = j * ps + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
-        valid = t_abs <= pos
+            q, k_t, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (H, ps * Kv)
+        # column c holds page row c // Kv of KV head c % Kv; its absolute
+        # position j * ps + c // Kv is valid iff <= pos (and, for sliding
+        # windows, > pos - window) -- bounds on c, so no vector division
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = (hm_ref[...] != 0) & (col < (pos - j * ps + 1) * kv)
         if window > 0:
-            valid &= t_abs > pos - window
+            valid &= col >= (pos - window - j * ps + 1) * kv
         s = jnp.where(valid, s, NEG)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                              # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(valid, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p, v_t.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p, v_t, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
     @pl.when(j == n_pages - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-20)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-20)
+                    ).astype(o_ref.dtype)
 
 
 def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
@@ -159,65 +172,66 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     token-stream level.
     """
     b, h, d = q.shape
-    n_p, ps, kv, _ = k_pages.shape
+    _, ps, kv, _ = k_pages.shape
     assert h % kv == 0, f"H={h} not a multiple of Kv={kv}"
     g = h // kv
     max_pages = page_table.shape[1]
     walk = min(num_pages, max_pages) if num_pages else max_pages
-    q4 = q.reshape(b, kv, g, d)
+    # head mask: query head r attends flattened page column c iff c's KV
+    # head (c % Kv) is r's group (r // g)
+    head_mask = (jnp.arange(ps * kv)[None, :] % kv
+                 == jnp.arange(h)[:, None] // g).astype(jnp.int32)
 
+    def page(bb, jj, pt, pos_):
+        # depth-clamped physical page: cells past the lane's depth alias
+        # their predecessor's block -> the pipeline elides the fetch
+        # (pages past `pos` never leave HBM)
+        return (pt[bb, jnp.minimum(jj, pos_[bb] // ps)], 0, 0, 0)
+
+    def write_page(bb, jj, pt, pos_):
+        # write page pinned for the whole walk -> one write-back per lane,
+        # flushed when the block index changes (the walk clamp mirrors
+        # the kernel's wp, see _kernel)
+        return (pt[bb, jnp.minimum(pos_[bb] // ps, walk - 1)], 0, 0, 0)
+
+    lane = lambda bb, jj, pt, pos_: (bb, 0, 0)
+    page_block = (1, ps, kv, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,      # page_table, pos
-        grid=(b, kv, walk),
+        grid=(b, walk),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bb, hh, jj, pt, ps_: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda bb, hh, jj, pt, ps_: (bb, hh, 0)),
-            pl.BlockSpec((1, 1, d), lambda bb, hh, jj, pt, ps_: (bb, hh, 0)),
-            # depth-clamped physical page: cells past the lane's depth
-            # alias their predecessor's block -> the pipeline elides the
-            # fetch (pages past `pos` never leave HBM)
-            pl.BlockSpec((1, ps, 1, d),
-                         lambda bb, hh, jj, pt, ps_: (
-                             pt[bb, jnp.minimum(jj, ps_[bb] // ps)],
-                             0, hh, 0)),
-            pl.BlockSpec((1, ps, 1, d),
-                         lambda bb, hh, jj, pt, ps_: (
-                             pt[bb, jnp.minimum(jj, ps_[bb] // ps)],
-                             0, hh, 0)),
+            pl.BlockSpec((1, h, d), lane),
+            pl.BlockSpec((1, kv, d), lane),
+            pl.BlockSpec((1, kv, d), lane),
+            pl.BlockSpec(page_block, page),
+            pl.BlockSpec(page_block, page),
+            pl.BlockSpec((h, ps * kv), lambda bb, jj, pt, pos_: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bb, hh, jj, pt, ps_: (bb, hh, 0, 0)),
-            # write page pinned for the whole walk -> one write-back per
-            # (lane, kv head), flushed when the block index changes (the
-            # walk clamp mirrors the kernel's wp, see _kernel)
-            pl.BlockSpec((1, ps, 1, d),
-                         lambda bb, hh, jj, pt, ps_: (
-                             pt[bb, jnp.minimum(ps_[bb] // ps, walk - 1)],
-                             0, hh, 0)),
-            pl.BlockSpec((1, ps, 1, d),
-                         lambda bb, hh, jj, pt, ps_: (
-                             pt[bb, jnp.minimum(ps_[bb] // ps, walk - 1)],
-                             0, hh, 0)),
+            pl.BlockSpec((1, h, d), lane),
+            pl.BlockSpec(page_block, write_page),
+            pl.BlockSpec(page_block, write_page),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),      # running max
-            pltpu.VMEM((g,), jnp.float32),      # running sum
-            pltpu.VMEM((g, d), jnp.float32),    # output accumulator
+            pltpu.VMEM((h, 1), jnp.float32),    # running max
+            pltpu.VMEM((h, 1), jnp.float32),    # running sum
+            pltpu.VMEM((h, d), jnp.float32),    # output accumulator
         ],
     )
     o, kp, vp = pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / math.sqrt(d), ps=ps,
+        functools.partial(_kernel, scale=1.0 / math.sqrt(d), ps=ps, kv=kv,
                           window=window, n_pages=walk),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, d), q.dtype),
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
         # flat operand indices include the 2 scalar-prefetch args:
         # 5 = k_pages, 6 = v_pages alias pool outputs 1, 2 (in-place)
         input_output_aliases={5: 1, 6: 2},
+        name="paged_decode",
         interpret=interpret,
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
-      q4, k_new, v_new, k_pages, v_pages)
-    return o.reshape(b, h, d), kp, vp
+      q, k_new, v_new, k_pages, v_pages, head_mask)
+    return o, kp, vp

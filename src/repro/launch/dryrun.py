@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run driver (deliverable e).
 
@@ -12,8 +13,10 @@ production mesh, and record:
   * collective bytes   — parsed from the compiled HLO text,
 into a JSON file consumed by the roofline analysis (benchmarks/roofline.py).
 
-NOTE: the XLA_FLAGS line above MUST run before any other import touches
-jax — 512 host platform devices stand in for the 2x16x16 v5e fleet.
+NOTE: the XLA_FLAGS and JAX_PLATFORMS lines above MUST run before any
+other import touches jax — 512 host platform devices stand in for the
+2x16x16 v5e fleet, and pinning the CPU keeps this process (and every
+--all child, which inherits the pin) off an attached chip.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch mistral-nemo-12b \
@@ -281,7 +284,8 @@ def main():
                    "--arch", arch, "--shape", shape, "--mesh", mesh,
                    "--out", args.out] + (["--no-dsg"] if args.no_dsg else [])
             try:
-                subprocess.run(cmd, timeout=3600)
+                subprocess.run(cmd, timeout=3600,
+                               env={**os.environ, "JAX_PLATFORMS": "cpu"})
             except subprocess.TimeoutExpired:
                 with open(fname, "w") as f:
                     json.dump({"arch": arch, "shape": shape, "mesh": mesh,

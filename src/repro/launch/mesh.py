@@ -7,8 +7,16 @@ XLA_FLAGS before the first jax initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """`jax.make_mesh` with every axis Auto: shardings propagate from
+    the `constrain` hints (parallel/context.py), not from explicit
+    per-array types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.parallel import context as pctx
 
@@ -192,6 +193,7 @@ def main():
                          "and aborted")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))

@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.ckpt.manager import CheckpointManager
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import api, specs
 from repro.optim import adamw
@@ -146,6 +147,7 @@ def main():
     ap.add_argument("--production-mesh", action="store_true")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))

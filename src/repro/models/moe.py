@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import drs, masks
 from repro.core.dsg_linear import DSGConfig, init_swiglu, swiglu_ffn
 from repro.models.layers import dense_init
@@ -161,10 +160,10 @@ def moe_ffn(p: dict, x: jax.Array, *, n_experts: int, top_k: int,
         espec = P("model", None, None)
         fw_in = dsg_fw if dsg_fw is not None else \
             jnp.zeros((n_experts, 1, 1), x.dtype)
-        y2d = shard_map(
+        y2d = jax.shard_map(
             body, mesh=mesh,
             in_specs=(bspec, bspec, espec, espec, espec, espec),
-            out_specs=bspec,
+            out_specs=bspec, check_vma=False,
         )(x2d, logits, p["w_gate"], p["w_up"], p["w_down"], fw_in)
     else:
         capacity = max(1, int(capacity_factor * x2d.shape[0] * top_k

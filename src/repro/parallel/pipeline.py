@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def pipeline_forward(stage_fn: Callable, stage_params, x_micro: jax.Array,
                      mesh: Mesh, axis: str = "pipe") -> jax.Array:
@@ -65,10 +63,10 @@ def pipeline_forward(stage_fn: Callable, stage_params, x_micro: jax.Array,
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    outs = shard_map(
+    outs = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P()),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )(stage_params, x_micro)
     # microbatch m exits the last stage at tick m + n_stages - 1
     return outs[n_stages - 1:]

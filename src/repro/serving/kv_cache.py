@@ -423,6 +423,12 @@ class PagedBackend(_Backend):
     def pages_for(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size)
 
+    def _device_table(self) -> jax.Array:
+        """Device copy of the host page table, made on the pools' device
+        (a router replica's own device, not the process default)."""
+        with jax.default_device(self._device):
+            return jnp.asarray(self._table)
+
     def make(self, cfg, n_slots: int, max_seq: int, dtype=None) -> CacheHandle:
         if self._table is not None:
             raise RuntimeError("PagedBackend manages one live handle; "
@@ -442,8 +448,10 @@ class PagedBackend(_Backend):
         self.max_pages = max_seq // self.page_size
         self._table = np.full((n_slots, self.max_pages), NULL_PAGE, np.int32)
         self._resv = np.zeros(n_slots, np.int64)
+        # the pools' device: every later page-table push lands beside them
+        (self._device,) = pool["k"].devices()
         data = {"pages_k": pool["k"], "pages_v": pool["v"],
-                "page_table": jnp.asarray(self._table)}
+                "page_table": self._device_table()}
         return CacheHandle(data, "paged", self.page_size)
 
     def shared_hits(self, chain: Sequence[bytes]) -> int:
@@ -549,7 +557,7 @@ class PagedBackend(_Backend):
             else:
                 pools = self._merge(pools, slot_kv,
                                     jnp.asarray(pp, jnp.int32))
-        pools["page_table"] = jnp.asarray(self._table)
+        pools["page_table"] = self._device_table()
         return CacheHandle(pools, "paged", self.page_size)
 
     def _cow(self, handle: CacheHandle, slot: int, lp: int) -> CacheHandle:
@@ -569,7 +577,7 @@ class PagedBackend(_Backend):
             {"pages_k": handle.data["pages_k"],
              "pages_v": handle.data["pages_v"]},
             jnp.int32(old), jnp.int32(new))
-        pools["page_table"] = jnp.asarray(self._table)
+        pools["page_table"] = self._device_table()
         return CacheHandle(pools, "paged", self.page_size)
 
     def ensure(self, handle: CacheHandle, slot: int, pos: int) -> CacheHandle:
@@ -586,7 +594,7 @@ class PagedBackend(_Backend):
         self._table[slot, lp] = pg
         self._resv[slot] = max(int(self._resv[slot]) - 1, 0)
         return CacheHandle({**handle.data,
-                            "page_table": jnp.asarray(self._table)},
+                            "page_table": self._device_table()},
                            "paged", self.page_size)
 
     def ensure_range(self, handle: CacheHandle, slot: int, start: int,
@@ -610,14 +618,14 @@ class PagedBackend(_Backend):
         if not grew:
             return handle
         return CacheHandle({**handle.data,
-                            "page_table": jnp.asarray(self._table)},
+                            "page_table": self._device_table()},
                            "paged", self.page_size)
 
     def free(self, handle: CacheHandle, slot: int) -> CacheHandle:
         """Return lane `slot`'s pages to the free list (retirement)."""
         self._release(slot)
         return CacheHandle({**handle.data,
-                            "page_table": jnp.asarray(self._table)},
+                            "page_table": self._device_table()},
                            "paged", self.page_size)
 
     def _release(self, slot: int) -> None:

@@ -51,7 +51,7 @@ from repro.models import api
 from repro.serving import dsg_runtime, kv_cache
 from repro.serving.kv_cache import CacheHandle
 
-DEFAULT_BUCKETS = (16, 32, 64, 96, 128, 192, 256)
+DEFAULT_BUCKETS = (16, 32, 64, 96, 128, 192, 256, 384, 512)
 
 
 def bucket_sizes(prompt_bucket: int, max_seq: int,
@@ -79,6 +79,14 @@ def live_page_buckets(max_pages: int) -> tuple:
     the set warm_decode pre-compiles and traffic models enumerate."""
     return tuple(sorted({min(1 << i, max_pages)
                          for i in range(max_pages.bit_length() + 1)}))
+
+
+def params_device(params):
+    """The one device every params leaf lives on; None (the process
+    default) when the params are host arrays or span several devices."""
+    devs = {d for leaf in jax.tree.leaves(params)
+            if isinstance(leaf, jax.Array) for d in leaf.devices()}
+    return devs.pop() if len(devs) == 1 else None
 
 
 _ADMIT_SALT = 0xADA117   # folds admission PRNG keys off the decode stream
@@ -501,11 +509,16 @@ class ServingEngine:
             collections.OrderedDict()
         self._prefill_cache_cap = 128
         self.prefill_cache_hits = 0
-        self.cache = self.backend.make(cfg, n_slots, max_seq)
-        # zero 1-lane dense template reused by every admission (prefill is
-        # functional: the template is never mutated, and its zero tail
-        # wipes any stale K/V when merged over a retired dense lane)
-        self._lane0 = api.make_cache(cfg, 1, max_seq)
+        # the KV cache and the prefill template are created on the device
+        # that holds the params (a threaded Router replica's own device),
+        # not on the process default and moved later
+        with jax.default_device(params_device(params)):
+            self.cache = self.backend.make(cfg, n_slots, max_seq)
+            # zero 1-lane dense template reused by every admission
+            # (prefill is functional: the template is never mutated, and
+            # its zero tail wipes any stale K/V when merged over a retired
+            # dense lane)
+            self._lane0 = api.make_cache(cfg, 1, max_seq)
         # token each lane feeds to its next decode step (sampled from the
         # lane's latest logits; junk for free lanes, masked at emit time)
         self._next_tok = np.zeros(n_slots, np.int32)

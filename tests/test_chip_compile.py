@@ -1,0 +1,101 @@
+"""Compile the served path's Pallas kernels for a described TPU v5e chip.
+
+Interpret mode (every other kernel test) runs the kernel bodies on the
+CPU and accepts BlockSpecs and layouts that the chip's compiler refuses.
+These tests lower each kernel at internlm2-1.8b widths (d=2048, 16 heads
+over 8 KV heads of 128, d_ff=8192, 8 decode lanes, 16-token pages over a
+1024-token window) for one chip of a `v5e:2x2` topology that is described,
+not attached, and assert that the compiled program holds the Mosaic
+kernel (`tpu_custom_call`).  Nothing runs, so they say nothing about
+results or times.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and test collection
+imports this file in every worker.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import drs_search, dsg_ffn, paged_attention
+
+B, H, KV, D = 8, 16, 8, 128             # lanes, heads, KV heads, head dim
+D_MODEL, D_FF, BLOCK = 2048, 8192, 128
+PAGE, MAX_PAGES = 16, 64                 # 1024-token window
+N_PAGES = B * MAX_PAGES + 1              # pool with the scratch page
+K_PROJ = 256                             # projection.jll_dim(2048, 8193, 0.5)
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    # persistent-cache entries written for a described chip cannot be
+    # read back without one; keep these compiles out of the cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(name, fn, sharding, *shapes):
+    """Compile `fn` for the described chip; the compiled program must
+    call the Mosaic kernel `name`."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert f"%{name}" in text
+
+
+def test_paged_decode_compiles(one_chip):
+    def step(q, kn, vn, kp, vp, pt, pos):
+        return paged_attention.paged_decode(q, kn, vn, kp, vp, pt, pos,
+                                            num_pages=MAX_PAGES)
+    pool = ((N_PAGES, PAGE, KV, D), BF16)
+    _compile("paged_decode", step, one_chip,
+             ((B, H, D), BF16), ((B, KV, D), BF16), ((B, KV, D), BF16),
+             pool, pool, ((B, MAX_PAGES), jnp.int32), ((B,), jnp.int32))
+
+
+def test_dsg_ffn_csr_compiles(one_chip):
+    k = D_FF // BLOCK // 2                     # gamma 0.5 CSR bound
+    _compile("dsg_ffn_csr", lambda x, wg, wu, wd, idx, cnt:
+             dsg_ffn.dsg_ffn_csr(x, wg, wu, wd, idx, cnt, block=BLOCK),
+             one_chip,
+             ((B, D_MODEL), BF16), ((D_MODEL, D_FF), BF16),
+             ((D_MODEL, D_FF), BF16), ((D_FF, D_MODEL), BF16),
+             ((B, k), jnp.int32), ((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("m,bm", [(B, B), (512, 128)])  # decode, prefill
+def test_drs_project_compiles(one_chip, m, bm):
+    _compile("drs_project",
+             lambda x, r: drs_search.drs_project(x, r, bm=bm), one_chip,
+             ((m, D_MODEL), BF16), ((K_PROJ, D_MODEL), BF16))
+
+
+@pytest.mark.parametrize("m,bm", [(B, B), (512, 128)])  # decode, prefill
+def test_drs_scores_compiles(one_chip, m, bm):
+    _compile("drs_scores", lambda fx, fw: drs_search.drs_scores(
+             fx, fw, block=BLOCK, bm=bm, bf=512), one_chip,
+             ((m, K_PROJ), BF16), ((K_PROJ, D_FF), BF16))
+
+
+def test_dsg_ffn_block_mask_compiles(one_chip):
+    m = 256
+    _compile("dsg_ffn", lambda x, wg, wu, wd, mask: dsg_ffn.dsg_ffn(
+             x, wg, wu, wd, mask, block=BLOCK, bm=128, bf=128), one_chip,
+             ((m, D_MODEL), BF16), ((D_MODEL, D_FF), BF16),
+             ((D_MODEL, D_FF), BF16), ((D_FF, D_MODEL), BF16),
+             ((m, D_FF // BLOCK), jnp.float32))

@@ -31,7 +31,7 @@ def _scores(seed, rows, g):
 # select_mask threshold modes
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=150)
+@settings(max_examples=150, deadline=None)
 @given(_SEED, _ROWS, _G, _BLOCK, _GAMMA)
 def test_topk_density_respects_gamma(seed, rows, g, block, gamma):
     """topk mode: every row keeps at least keep_groups(gamma) groups, and
@@ -50,7 +50,7 @@ def test_topk_density_respects_gamma(seed, rows, g, block, gamma):
     assert float(masks.density(mask)) >= k / g - 1e-6
 
 
-@settings(max_examples=150)
+@settings(max_examples=150, deadline=None)
 @given(_SEED, _ROWS, _G, _BLOCK, st.sampled_from([0.25, 0.5, 0.75]))
 def test_shared_mode_uses_row0_topk_threshold(seed, rows, g, block,
                                               gamma):
@@ -71,7 +71,7 @@ def test_shared_mode_uses_row0_topk_threshold(seed, rows, g, block,
     assert np.array_equal(got, s >= thr)
 
 
-@settings(max_examples=150)
+@settings(max_examples=150, deadline=None)
 @given(_SEED, _ROWS, _G, _BLOCK)
 def test_ema_deterministic_and_follows_decay(seed, rows, g, block):
     """ema mode is a pure function of (scores, carried threshold): same
@@ -118,7 +118,7 @@ def test_topk_all_tied_scores_keep_everything():
 # mask algebra round trips
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=150)
+@settings(max_examples=150, deadline=None)
 @given(_SEED, _ROWS, _G, _BLOCK)
 def test_apply_expanded_matches_explicit_expansion(seed, rows, g, block):
     """apply_expanded == multiply by jnp.repeat-expanded mask, exactly
@@ -139,7 +139,7 @@ def test_apply_expanded_matches_explicit_expansion(seed, rows, g, block):
     assert np.array_equal(ident, x)
 
 
-@settings(max_examples=200)
+@settings(max_examples=200, deadline=None)
 @given(st.lists(st.integers(1, 6), min_size=1, max_size=3), _G, _BLOCK)
 def test_mask_overhead_bytes_bit_packs_per_row(batch, g, block):
     """One bit per group per row, byte-rounded — and the stash cost for

@@ -11,7 +11,7 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.optim.hierarchical import hierarchical_grad_reduce
 
     mesh = make_mesh((2, 4), ("pod", "data"))
@@ -22,20 +22,22 @@ SCRIPT = textwrap.dedent("""
     def step(g, err):
         return hierarchical_grad_reduce(g, err)
 
-    f = jax.jit(shard_map(step, mesh=mesh,
-                          in_specs=(P(("pod", "data")),
-                                    P(("pod", "data"))),
-                          out_specs=(P(("pod", "data")),
-                                     P(("pod", "data")))))
+    f = jax.jit(jax.shard_map(step, mesh=mesh,
+                              in_specs=(P(("pod", "data")),
+                                        P(("pod", "data"))),
+                              out_specs=(P(("pod", "data")),
+                                         P(("pod", "data"))),
+                              check_vma=False))
 
     # exact reference: fleet mean
     exact = jnp.broadcast_to(gs.mean(0, keepdims=True), gs.shape)
 
     # (a) uncompressed path == exact
-    f0 = jax.jit(shard_map(
+    f0 = jax.jit(jax.shard_map(
         lambda g, e: hierarchical_grad_reduce(g, e, compress=False),
         mesh=mesh, in_specs=(P(("pod", "data")), P(("pod", "data"))),
-        out_specs=(P(("pod", "data")), P(("pod", "data")))))
+        out_specs=(P(("pod", "data")), P(("pod", "data"))),
+        check_vma=False))
     out0, _ = f0(gs.reshape(n, dim), jnp.zeros((n, dim)))
     np.testing.assert_allclose(np.asarray(out0), np.asarray(exact),
                                rtol=1e-5, atol=1e-6)

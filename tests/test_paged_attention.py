@@ -194,6 +194,19 @@ def test_repro_interpret_env_override(monkeypatch):
     assert ops._interpret() == (jax.default_backend() == "cpu")
 
 
+def test_tpu_backend_never_interprets(monkeypatch):
+    """On a TPU the kernels always compile natively: interpret mode there
+    would hide the device, so REPRO_INTERPRET=1 raises."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_INTERPRET", raising=False)
+    assert not ops._interpret()
+    monkeypatch.setenv("REPRO_INTERPRET", "0")
+    assert not ops._interpret()
+    monkeypatch.setenv("REPRO_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="CPU only"):
+        ops._interpret()
+
+
 # ---------------------------------------------------------------------------
 # engine-level: kernel executor vs dense backend token stream
 # ---------------------------------------------------------------------------

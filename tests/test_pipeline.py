@@ -13,7 +13,7 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel.pipeline import pipeline_forward, sequential_reference
 
     mesh = make_mesh((4,), ("pipe",))
