@@ -57,7 +57,7 @@ def _make_engine(cfg, params, dsg, args, apply_mode):
             refresh_interval=args.refresh_interval,
             threshold=args.threshold))
     warmup_engine(eng, cfg.vocab)
-    eng.dsg_rt.step_log.clear()      # FLOP model: measured window only
+    eng.telemetry.reset_counters("dsg.")   # FLOP model: measured window only
     return eng
 
 

@@ -40,7 +40,7 @@ pool.
 """
 from __future__ import annotations
 
-from typing import Callable, List, NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.core import double_mask as dm
 from repro.core import drs, sparse_mask
+from repro.serving.telemetry import Telemetry
 
 
 class DSGServingConfig(NamedTuple):
@@ -108,7 +109,8 @@ class DSGRuntime:
     device stream.
     """
 
-    def __init__(self, cfg, scfg: DSGServingConfig, n_slots: int):
+    def __init__(self, cfg, scfg: DSGServingConfig, n_slots: int,
+                 telemetry: Optional[Telemetry] = None):
         if not cfg.dsg.enabled:
             raise ValueError("dsg_serving needs cfg.dsg.enabled")
         if cfg.d_ff % cfg.dsg.block:
@@ -135,7 +137,8 @@ class DSGRuntime:
         self.counts = np.ones(shape, np.int32)
         self.ema = np.zeros(shape, np.float32)
         self.lane_active = np.zeros(n_slots, bool)
-        self.step_log: List[dict] = []    # per-step FLOP model entries
+        # the engine's recorder: the refresh span and the FLOP model's sums
+        self.telemetry = telemetry if telemetry is not None else Telemetry()
         self._dev = {}
         self._version = 0
 
@@ -179,10 +182,12 @@ class DSGRuntime:
         only the DUE lanes' patterns are rewritten (per-lane cadence —
         co-scheduled lanes refreshing on their own token counts keeps
         streams invariant to slot assignment and replica count)."""
-        scores = np.asarray(scores, np.float32)
-        for i in lanes:
-            if self.lane_active[i]:
+        with self.telemetry.span("repro.dsg.refresh") as span:
+            scores = np.asarray(scores, np.float32)   # waits for the step
+            due = [i for i in lanes if self.lane_active[i]]
+            for i in due:
                 self._write_rows(i, scores[:, i], seed_ema=False)
+            span.set(lanes=len(due))
 
     def reset_lane(self, lane: int):
         """Retirement: drop back to the minimal pattern so a parked lane
@@ -229,26 +234,27 @@ class DSGRuntime:
     # -- FLOP accounting (benchmarks/bench_dsg_serving.py) -------------------
 
     def record_step(self, active, bound: int):
-        """Log this decode step's modeled FFN group-units: dense = every
-        group for every active lane; csr = the per-lane counts the CSR
-        kernel walks; bound = what the padded XLA gather contracts (pow2
-        bucket, the static-shape overhead)."""
+        """Add this decode step's modeled FFN group-units to the running
+        sums: dense = every group for every active lane; csr = the
+        per-lane counts the CSR kernel walks; bound = what the padded XLA
+        gather contracts (pow2 bucket, the static-shape overhead)."""
         n = len(active)
-        self.step_log.append({
-            "active": n,
-            "dense_units": self.n_layers * self.n_groups * n,
-            "csr_units": int(self.counts[:, list(active)].sum()),
-            "bound_units": self.n_layers * bound * n,
-        })
+        count = self.telemetry.count
+        count("dsg.steps")
+        count("dsg.dense_units", self.n_layers * self.n_groups * n)
+        count("dsg.csr_units", int(self.counts[:, list(active)].sum()))
+        count("dsg.bound_units", self.n_layers * bound * n)
 
     def flop_stats(self) -> dict:
-        """Aggregate modeled FFN FLOP reduction over the logged steps."""
-        if not self.step_log:
+        """Modeled FFN FLOP reduction over the recorded steps (the
+        `dsg.*` counters of the engine's recorder; reset them with
+        `telemetry.reset_counters("dsg.")`)."""
+        c = self.telemetry.counters
+        if not c["dsg.steps"]:
             raise ValueError("no decode steps recorded")
-        dense = sum(e["dense_units"] for e in self.step_log)
-        csr = sum(e["csr_units"] for e in self.step_log)
-        bnd = sum(e["bound_units"] for e in self.step_log)
-        return {"steps": len(self.step_log),
+        dense, csr, bnd = (c["dsg.dense_units"], c["dsg.csr_units"],
+                           c["dsg.bound_units"])
+        return {"steps": c["dsg.steps"],
                 "dense_units": dense, "csr_units": csr,
                 "bound_units": bnd,
                 "flop_reduction_csr": dense / max(csr, 1),

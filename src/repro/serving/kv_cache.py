@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import api, transformer
+from repro.serving.telemetry import Telemetry
 
 NULL_PAGE = 0          # reserved scratch page; never handed out
 
@@ -243,7 +244,12 @@ def decode_view(handle: CacheHandle, free_mask=None, donor=None) -> dict:
 class _Backend:
     """Shared backend plumbing: the handle's `data` is always the exact
     pytree `transformer.forward` consumes, and resident bytes are just the
-    bytes the handle keeps alive."""
+    bytes the handle keeps alive.  `write` and `free` record the spans
+    `repro.kv.write` and `repro.kv.free` in `telemetry`, which the serving
+    engine replaces with its own recorder."""
+
+    def __init__(self):
+        self.telemetry = Telemetry()
 
     def view_for_attention(self, handle: CacheHandle, free_mask=None,
                            donor=None) -> dict:
@@ -290,6 +296,7 @@ class DenseBackend(_Backend):
     page_size = 0
 
     def __init__(self):
+        super().__init__()
         self._merge = jax.jit(dense_merge, donate_argnums=(0,))
 
     def make(self, cfg, n_slots: int, max_seq: int, dtype=None) -> CacheHandle:
@@ -300,13 +307,16 @@ class DenseBackend(_Backend):
               n_tokens: Optional[int] = None,
               reserve_tokens: Optional[int] = None,
               chain=None) -> CacheHandle:
-        return CacheHandle(self._merge(handle.data, slot_kv, slot), "dense", 0)
+        with self.telemetry.span("repro.kv.write"):
+            return CacheHandle(self._merge(handle.data, slot_kv, slot),
+                               "dense", 0)
 
     def ensure(self, handle: CacheHandle, slot: int, pos: int) -> CacheHandle:
         return handle
 
     def free(self, handle: CacheHandle, slot: int) -> CacheHandle:
-        return handle
+        with self.telemetry.span("repro.kv.free"):
+            return handle
 
     def can_admit(self, n_tokens: int, chain=None,
                   prompt_tokens: Optional[int] = None) -> bool:
@@ -406,6 +416,7 @@ class PagedBackend(_Backend):
                  prefix_sharing: bool = False):
         if page_size <= 0:
             raise ValueError("page_size must be positive")
+        super().__init__()
         self.page_size = page_size
         self.total_tokens = total_tokens
         self.prefix_sharing = bool(prefix_sharing)
@@ -543,22 +554,23 @@ class PagedBackend(_Backend):
         # partial-tail COW page (see _extra_pages; consumed by _cow)
         tail = 1 if sharing and n_tokens % self.page_size else 0
         self._resv[slot] = need - n_lp + tail
-        pools = {"pages_k": handle.data["pages_k"],
-                 "pages_v": handle.data["pages_v"]}
-        if fresh_lps:
-            if slot_kv is None:
-                raise ValueError(
-                    f"write(slot_kv=None) needs every prompt page shared "
-                    f"({hits} of {n_lp} resident)")
-            if hits:
-                pools = self._merge_subset(
-                    pools, slot_kv, jnp.asarray(pp, jnp.int32),
-                    jnp.asarray(fresh_lps, jnp.int32), n_lp)
-            else:
-                pools = self._merge(pools, slot_kv,
-                                    jnp.asarray(pp, jnp.int32))
-        pools["page_table"] = self._device_table()
-        return CacheHandle(pools, "paged", self.page_size)
+        with self.telemetry.span("repro.kv.write", pages=len(fresh_lps)):
+            pools = {"pages_k": handle.data["pages_k"],
+                     "pages_v": handle.data["pages_v"]}
+            if fresh_lps:
+                if slot_kv is None:
+                    raise ValueError(
+                        f"write(slot_kv=None) needs every prompt page shared "
+                        f"({hits} of {n_lp} resident)")
+                if hits:
+                    pools = self._merge_subset(
+                        pools, slot_kv, jnp.asarray(pp, jnp.int32),
+                        jnp.asarray(fresh_lps, jnp.int32), n_lp)
+                else:
+                    pools = self._merge(pools, slot_kv,
+                                        jnp.asarray(pp, jnp.int32))
+            pools["page_table"] = self._device_table()
+            return CacheHandle(pools, "paged", self.page_size)
 
     def _cow(self, handle: CacheHandle, slot: int, lp: int) -> CacheHandle:
         """Copy-on-write lane `slot`'s logical page `lp` into a private
@@ -623,10 +635,11 @@ class PagedBackend(_Backend):
 
     def free(self, handle: CacheHandle, slot: int) -> CacheHandle:
         """Return lane `slot`'s pages to the free list (retirement)."""
-        self._release(slot)
-        return CacheHandle({**handle.data,
-                            "page_table": self._device_table()},
-                           "paged", self.page_size)
+        with self.telemetry.span("repro.kv.free"):
+            self._release(slot)
+            return CacheHandle({**handle.data,
+                                "page_table": self._device_table()},
+                               "paged", self.page_size)
 
     def _release(self, slot: int) -> None:
         pages = [int(p) for p in self._table[slot] if p != NULL_PAGE]

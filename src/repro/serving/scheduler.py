@@ -48,7 +48,7 @@ import numpy as np
 
 from repro.analysis.contracts import exempt, owned_by, runs_on
 from repro.models import api
-from repro.serving import dsg_runtime, kv_cache
+from repro.serving import dsg_runtime, kv_cache, telemetry
 from repro.serving.kv_cache import CacheHandle
 
 DEFAULT_BUCKETS = (16, 32, 64, 96, 128, 192, 256, 384, 512)
@@ -167,6 +167,7 @@ class StepPlan:
     eos_ids: Optional[np.ndarray] = None   # (n_slots,) int32
     emit_left: Optional[np.ndarray] = None  # (n_slots,) int32 budget
     refresh: bool = False             # DSG: collect scores at last micro-step
+    admits: int = 0                   # prompts admitted by this begin_step
 
 
 def _restore_table(data, c):
@@ -483,12 +484,16 @@ class ServingEngine:
         self.replica_index = 0        # set by the Router (attribution)
         self.fault_injector = None    # ServingFaultInjector (chaos runs)
         self.abort = False
+        # spans and counters of this engine's host work
+        # (serving/telemetry.py; docs/serving.md, "Spans and counters")
+        self.telemetry = telemetry.Telemetry()
 
         self.backend = (cache_backend if hasattr(cache_backend, "make")
                         else kv_cache.get_backend(
                             cache_backend, page_size=page_size,
                             total_tokens=cache_tokens,
                             prefix_sharing=prefix_sharing))
+        self.backend.telemetry = self.telemetry
         # copy-on-write shared-prefix reuse (docs/cache_backends.md):
         # admission hashes the bucketed prompt row into a prefix chain,
         # maps already-resident pages by refcount bump, and — when EVERY
@@ -591,7 +596,8 @@ class ServingEngine:
                     "cadence is per-lane emitted-token count, and a due "
                     "point landing mid-chunk could not rewrite the CSR "
                     "pattern the chunk already dispatched with")
-            self.dsg_rt = dsg_runtime.DSGRuntime(cfg, scfg, n_slots)
+            self.dsg_rt = dsg_runtime.DSGRuntime(cfg, scfg, n_slots,
+                                                 self.telemetry)
 
             def _prefill_dsg(p, d, toks, lane0):
                 logits, lane, scores = api.prefill(
@@ -731,15 +737,17 @@ class ServingEngine:
             self._prefill_cache.popitem(last=False)
 
     @runs_on("worker")
-    def _admit(self):
-        """Admit queued prompts into free lanes via backend cache surgery.
+    def _admit(self) -> int:
+        """Admit queued prompts into free lanes via backend cache surgery;
+        returns how many were admitted.
 
         Overlap policy: every free lane refills immediately (subject to
         the paged backend having pages for the request's reservation).
         Wave policy: admission waits until ALL lanes have drained (the old
         baseline)."""
         if self.admission == "wave" and any(not s.free for s in self.slots):
-            return
+            return 0
+        admitted = 0
         for i, slot in enumerate(self.slots):
             if not slot.free or not self.queue:
                 continue
@@ -786,6 +794,21 @@ class ServingEngine:
             if not admit_ok:
                 break            # retirements will free pages; retry later
             self.queue.popleft()
+            self._admit_one(i, req, toks, need, chain)
+            admitted += 1
+        return admitted
+
+    @runs_on("worker")
+    def _admit_one(self, i: int, req: Request, toks: np.ndarray, need: int,
+                   chain):
+        """Prefill `req` (its bucketed row `toks`) into free lane `i`,
+        splice its K/V into the cache and pick its first token."""
+        tel = self.telemetry
+        pb = toks.shape[1]
+        with tel.span("repro.engine.admit", uid=req.uid,
+                      bucket=pb) as span:
+            tel.record("repro.request.queued", req.submitted, span.t0,
+                       uid=req.uid)
             # zero-recompute path: every prompt page resident AND the
             # full-prompt prefill outputs cached -> skip the prefill
             # dispatch and the K/V scatter entirely.  Probe and write
@@ -795,29 +818,21 @@ class ServingEngine:
             if chain is not None \
                     and self.backend.shared_hits(chain) == len(chain):
                 cached = self._prefill_cache.get(chain[-1])
+            sc = lane = None
             if cached is not None:
                 self._prefill_cache.move_to_end(chain[-1])
                 self.prefill_cache_hits += 1
-                logits, sc_np = cached
-                lane = None
-                if self.dsg_rt is not None:
-                    self.dsg_rt.set_lane_from_scores(i, sc_np[:, 0])
+                logits, sc = cached
             elif self.dsg_rt is not None:
+                # the prompt's last-token DRS scores seed the lane's CSR
+                # pattern: the lane decodes sparsely from step one (a
+                # dense warm-in would dilute the modeled FLOP reduction)
                 logits, lane, sc = self._jit_prefill_dsg(
                     self.params, self.dsg, jnp.asarray(toks), self._lane0)
-                # seed the lane's CSR pattern from the prompt's last-token
-                # DRS scores: the lane decodes sparsely from step one (a
-                # dense warm-in would dilute the modeled FLOP reduction)
-                sc_np = np.asarray(sc)
-                self.dsg_rt.set_lane_from_scores(i, sc_np[:, 0])
-                if chain is not None:
-                    self._remember_prefill(chain[-1], logits, sc_np)
             else:
                 logits, lane = self._jit_prefill(self.params, self.dsg,
                                                  jnp.asarray(toks),
                                                  self._lane0)
-                if chain is not None:
-                    self._remember_prefill(chain[-1], logits, None)
             self.cache = self.backend.write(self.cache, lane, i,
                                             n_tokens=pb, reserve_tokens=need,
                                             chain=chain)
@@ -831,9 +846,17 @@ class ServingEngine:
             else:
                 tok = jnp.argmax(logits)
             req.started = time.perf_counter()
-            slot.req = req
-            slot.pos = pb
-            self._next_tok[i] = int(tok)
+            # the host's reads of the admission's device values wait for
+            # the prefill
+            with tel.span("repro.engine.first_token"):
+                self._next_tok[i] = int(tok)
+                sc_np = None if sc is None else np.asarray(sc)
+            if self.dsg_rt is not None:
+                self.dsg_rt.set_lane_from_scores(i, sc_np[:, 0])
+            if chain is not None and cached is None:
+                self._remember_prefill(chain[-1], logits, sc_np)
+            self.slots[i].req = req
+            self.slots[i].pos = pb
 
     def _live_pages(self, pos: np.ndarray, span: int = 1) -> int:
         """Static page-walk bound for this step's paged decode
@@ -938,6 +961,11 @@ class ServingEngine:
         never be admitted).  Callers must follow a non-None plan with
         the jitted decode dispatch and `commit_step()` — `step()` is
         that composition; replica executors batch the middle."""
+        with self.telemetry.span("repro.engine.begin"):
+            return self._begin_step()
+
+    @runs_on("worker")
+    def _begin_step(self) -> Optional[StepPlan]:
         if self.abort:
             # cleared here (not left sticky) so a restarted replica does
             # not immediately re-abort; ServingEngine.reset() also clears
@@ -950,7 +978,7 @@ class ServingEngine:
             # tokens land), delay sleeps inside the step, poison corrupts
             # resident outputs then raises — see runtime/fault_tolerance
             self.fault_injector.on_step(self)
-        self._admit()
+        admits = self._admit()
         active = [i for i, s in enumerate(self.slots) if not s.free]
         if not active:
             if self.queue:
@@ -977,33 +1005,40 @@ class ServingEngine:
         C = self.decode_chunk
         eos_ids = np.full(self.n_slots, -1, np.int32)
         emit_left = np.ones(self.n_slots, np.int32)
-        for i, s in enumerate(self.slots):
-            if s.free:
-                free_mask[i] = True
-                tok[i] = self._next_tok[donor]
-                pos[i] = self.slots[donor].pos
-            elif C == 1:
-                pos[i] = s.pos
-                temps[i] = s.req.temperature
-                top_ps[i] = s.req.top_p
-                # page-table growth for this step's write position (no-op
-                # for the dense backend or when the page is already mapped)
-                self.cache = self.backend.ensure(self.cache, i, s.pos)
-            else:
-                pos[i] = s.pos
-                temps[i] = s.req.temperature
-                top_ps[i] = s.req.top_p
-                r = s.req
-                eos_ids[i] = -1 if r.eos_id is None else r.eos_id
-                emit_left[i] = r.max_new - len(r.output)
-                # the fused chunk cannot grow the page table mid-scan, so
-                # `ensure` moves ahead of the loop: pre-map every page the
-                # lane can write this chunk.  Clamping to the lane's own
-                # emit budget / max_seq headroom keeps the mapping inside
-                # its admission-time reservation (ensure stays infallible)
-                w = min(C, int(emit_left[i]), self.max_seq - s.pos)
-                self.cache = self.backend.ensure_range(self.cache, i,
-                                                       s.pos, s.pos + w)
+        pushes = 0                  # page-table pushes to the device
+        with self.telemetry.span("repro.kv.grow") as grow:
+            for i, s in enumerate(self.slots):
+                if s.free:
+                    free_mask[i] = True
+                    tok[i] = self._next_tok[donor]
+                    pos[i] = self.slots[donor].pos
+                elif C == 1:
+                    pos[i] = s.pos
+                    temps[i] = s.req.temperature
+                    top_ps[i] = s.req.top_p
+                    # page-table growth for this step's write position (no-op
+                    # for the dense backend or when the page is already mapped)
+                    cache = self.backend.ensure(self.cache, i, s.pos)
+                    pushes += cache is not self.cache
+                    self.cache = cache
+                else:
+                    pos[i] = s.pos
+                    temps[i] = s.req.temperature
+                    top_ps[i] = s.req.top_p
+                    r = s.req
+                    eos_ids[i] = -1 if r.eos_id is None else r.eos_id
+                    emit_left[i] = r.max_new - len(r.output)
+                    # the fused chunk cannot grow the page table mid-scan, so
+                    # `ensure` moves ahead of the loop: pre-map every page the
+                    # lane can write this chunk.  Clamping to the lane's own
+                    # emit budget / max_seq headroom keeps the mapping inside
+                    # its admission-time reservation (ensure stays infallible)
+                    w = min(C, int(emit_left[i]), self.max_seq - s.pos)
+                    cache = self.backend.ensure_range(self.cache, i,
+                                                      s.pos, s.pos + w)
+                    pushes += cache is not self.cache
+                    self.cache = cache
+            grow.set(pushes=pushes)
         if C == 1:
             for i in active:
                 r = self.slots[i].req
@@ -1013,7 +1048,7 @@ class ServingEngine:
             return StepPlan(active=active, donor=donor, tok=tok, pos=pos,
                             free_mask=free_mask, temps=temps, top_ps=top_ps,
                             live_pages=self._live_pages(pos),
-                            sample=bool((temps > 0).any()))
+                            sample=bool((temps > 0).any()), admits=admits)
         # chunked: emission happens on device; commit_chunk appends.  A
         # DSG refresh-due point can only land on the last micro-step
         # (refresh_interval % chunk == 0 and lanes admit at chunk
@@ -1034,7 +1069,7 @@ class ServingEngine:
                         live_pages=self._live_pages(pos, C),
                         sample=bool((temps > 0).any()), chunk=C,
                         eos_ids=eos_ids, emit_left=emit_left,
-                        refresh=refresh)
+                        refresh=refresh, admits=admits)
 
     @runs_on("worker")
     def commit_step(self, plan: StepPlan, next_tok: np.ndarray,
@@ -1043,15 +1078,24 @@ class ServingEngine:
         account the device time/tokens, and retire finished lanes.
         `next_tok` must already be host-side (the caller syncs — that is
         where the device wait belongs in the timing)."""
-        self._next_tok = np.array(next_tok, np.int32)
-        self.decode_seconds += seconds
-        self.decode_tokens += len(plan.active)
-        self.steps += 1
-        # per-slot retirement — AFTER the EOS token has been emitted, so a
-        # stop token always appears in the output it terminates
-        for i in plan.active:
+        with self.telemetry.span("repro.engine.commit") as span:
+            self._next_tok = np.array(next_tok, np.int32)
+            self.decode_seconds += seconds
+            self.decode_tokens += len(plan.active)
+            self.steps += 1
+            for i in plan.active:
+                self.slots[i].pos += 1
+            span.set(retired=len(self._retire(plan.active)))
+
+    @runs_on("worker")
+    def _retire(self, lanes) -> list:
+        """Retire each of `lanes` whose request is finished — after its
+        EOS token has been emitted, so a stop token always appears in the
+        output it terminates — and return their pages; returns the lanes
+        retired."""
+        retired = []
+        for i in lanes:
             slot = self.slots[i]
-            slot.pos += 1
             r = slot.req
             hit_eos = r.eos_id is not None and r.output[-1] == r.eos_id
             if hit_eos or len(r.output) >= r.max_new \
@@ -1062,6 +1106,8 @@ class ServingEngine:
                 slot.req = None
                 slot.pos = 0
                 self.cache = self.backend.free(self.cache, i)
+                retired.append(i)
+        return retired
 
     @runs_on("worker")
     def commit_chunk(self, plan: StepPlan, blk: np.ndarray,
@@ -1075,59 +1121,49 @@ class ServingEngine:
         re-derives the freeze conditions from the appended output, which
         mirrors the device's done logic exactly (EOS == output[-1],
         len(output) >= max_new, pos >= max_seq)."""
-        rt = self.dsg_rt
-        if rt is not None and bound is not None:
-            # one FLOP-model entry per micro-step, over the lanes still
-            # live at that micro-step — keeps flop_stats comparable to a
-            # chunk=1 run of the same traffic
-            for k in range(flags.shape[0]):
-                live = [i for i in plan.active if flags[k, i]]
-                if live:
-                    rt.record_step(live, bound)
-        emitted = 0
-        for i in plan.active:
-            slot = self.slots[i]
-            n = int(flags[:, i].sum())
-            if n and not slot.req.output:
-                # TTFT stamp at host observation time: the token left the
-                # device mid-chunk, but commit is when a caller could
-                # first stream it — the honest latency for a fused loop
-                slot.req.first_token = time.perf_counter()
-            slot.req.output.extend(int(t) for t in blk[:n, i])
-            slot.pos += n
-            emitted += n
-        self._next_tok = np.array(next_tok, np.int32)
-        self.decode_seconds += seconds
-        self.decode_tokens += emitted
-        self.steps += int(flags.any(axis=1).sum())
-        retired = []
-        for i in plan.active:
-            slot = self.slots[i]
-            r = slot.req
-            hit_eos = r.eos_id is not None and r.output[-1] == r.eos_id
-            if hit_eos or len(r.output) >= r.max_new \
-                    or slot.pos >= self.max_seq:
-                r.status = "ok"
-                r.finished = time.perf_counter()
-                self.done[r.uid] = r
-                slot.req = None
-                slot.pos = 0
-                self.cache = self.backend.free(self.cache, i)
-                retired.append(i)
-        if rt is not None:
-            for i in retired:
-                rt.reset_lane(i)
-            if scores is not None:
-                R = rt.cfg.refresh_interval
-                due = [i for i in plan.active
-                       if self.slots[i].req is not None
-                       and len(self.slots[i].req.output) % R == 0]
-                rt.update_from_scores(np.asarray(scores), due)
+        with self.telemetry.span("repro.engine.commit") as span:
+            rt = self.dsg_rt
+            if rt is not None and bound is not None:
+                # one FLOP-model entry per micro-step, over the lanes still
+                # live at that micro-step — keeps flop_stats comparable to a
+                # chunk=1 run of the same traffic
+                for k in range(flags.shape[0]):
+                    live = [i for i in plan.active if flags[k, i]]
+                    if live:
+                        rt.record_step(live, bound)
+            emitted = 0
+            for i in plan.active:
+                slot = self.slots[i]
+                n = int(flags[:, i].sum())
+                if n and not slot.req.output:
+                    # TTFT stamp at host observation time: the token left
+                    # the device mid-chunk, but commit is when a caller
+                    # could first stream it — the honest latency for a
+                    # fused loop
+                    slot.req.first_token = time.perf_counter()
+                slot.req.output.extend(int(t) for t in blk[:n, i])
+                slot.pos += n
+                emitted += n
+            self._next_tok = np.array(next_tok, np.int32)
+            self.decode_seconds += seconds
+            self.decode_tokens += emitted
+            self.steps += int(flags.any(axis=1).sum())
+            retired = self._retire(plan.active)
+            span.set(retired=len(retired))
+            if rt is not None:
+                for i in retired:
+                    rt.reset_lane(i)
+                if scores is not None:
+                    R = rt.cfg.refresh_interval
+                    due = [i for i in plan.active
+                           if self.slots[i].req is not None
+                           and len(self.slots[i].req.output) % R == 0]
+                    rt.update_from_scores(scores, due)
 
     @runs_on("worker")
     def _dispatch_chunk(self, plan: StepPlan):
         """Device half of a fused chunk: one jitted dispatch running
-        `plan.chunk` scanned decode micro-steps.  Returns host-side
+        `plan.chunk` scanned decode micro-steps.  Returns the device's
         (blk, flags, next_tok) plus (scores, bound) for DSG engines."""
         args = (self.params, self.dsg, jnp.asarray(plan.tok), self.cache,
                 jnp.asarray(plan.pos), jnp.asarray(plan.free_mask),
@@ -1152,8 +1188,7 @@ class ServingEngine:
                 plan.top_ps)
         else:
             blk, flags, tok_f, self.cache = self._jit_chunk_greedy(*args)
-        return (np.asarray(blk), np.asarray(flags),
-                np.array(tok_f, np.int32), scores, bound)
+        return blk, flags, tok_f, scores, bound
 
     @runs_on("worker")
     def _dispatch_dsg(self, plan: StepPlan):
@@ -1188,35 +1223,52 @@ class ServingEngine:
         """One full engine step: begin (host) -> jitted decode (device)
         -> commit (host).  Replica executors that batch the device half
         across engines call the begin/commit halves directly."""
-        plan = self.begin_step()
-        if plan is None:
-            return
+        with self.telemetry.span("repro.engine.step",
+                                 step=self.steps) as span:
+            plan = self.begin_step()
+            span.set(lanes=len(plan.active) if plan else 0,
+                     admits=plan.admits if plan else 0)
+            if plan is not None:
+                self._decode(plan)
+
+    @runs_on("worker")
+    def _decode(self, plan: StepPlan):
+        """The device half of `step()` and its commit.  `decode_seconds`
+        gains the dispatch and the wait for its tokens, the spans
+        `repro.engine.dispatch` and `repro.engine.sync`."""
+        tel = self.telemetry
         if plan.chunk > 1:
-            t0 = time.perf_counter()
-            blk, flags, tok_f, scores, bound = self._dispatch_chunk(plan)
+            with tel.span("repro.engine.dispatch",
+                          live_pages=plan.live_pages) as disp:
+                blk, flags, tok_f, scores, bound = self._dispatch_chunk(plan)
+            with tel.span("repro.engine.sync") as sync:
+                blk, flags = np.asarray(blk), np.asarray(flags)
+                tok_f = np.array(tok_f, np.int32)
             self.commit_chunk(plan, blk, flags, tok_f,
-                              time.perf_counter() - t0, scores=scores,
+                              disp.seconds + sync.seconds, scores=scores,
                               bound=bound)
             return
-        t0 = time.perf_counter()
         scores = due = None
-        # PRNG keys depend only on (engine seed, step, lane), so mixing
-        # greedy-only and sampling steps never shifts the key schedule
-        if self.dsg_rt is not None:
-            next_tok, scores, due = self._dispatch_dsg(plan)
-        elif plan.sample:
-            next_tok, self.cache = self._jit_decode_sample(
-                self.params, self.dsg, jnp.asarray(plan.tok)[:, None],
-                self.cache, jnp.asarray(plan.pos), plan.free_mask,
-                plan.donor, plan.live_pages, self._base_key, self.steps,
-                plan.temps, plan.top_ps)
-        else:
-            next_tok, self.cache = self._jit_decode_greedy(
-                self.params, self.dsg, jnp.asarray(plan.tok)[:, None],
-                self.cache, jnp.asarray(plan.pos), plan.free_mask,
-                plan.donor, plan.live_pages)
-        next_host = np.array(next_tok, np.int32)       # syncs the device
-        self.commit_step(plan, next_host, time.perf_counter() - t0)
+        with tel.span("repro.engine.dispatch",
+                      live_pages=plan.live_pages) as disp:
+            # PRNG keys depend only on (engine seed, step, lane), so mixing
+            # greedy-only and sampling steps never shifts the key schedule
+            if self.dsg_rt is not None:
+                next_tok, scores, due = self._dispatch_dsg(plan)
+            elif plan.sample:
+                next_tok, self.cache = self._jit_decode_sample(
+                    self.params, self.dsg, jnp.asarray(plan.tok)[:, None],
+                    self.cache, jnp.asarray(plan.pos), plan.free_mask,
+                    plan.donor, plan.live_pages, self._base_key, self.steps,
+                    plan.temps, plan.top_ps)
+            else:
+                next_tok, self.cache = self._jit_decode_greedy(
+                    self.params, self.dsg, jnp.asarray(plan.tok)[:, None],
+                    self.cache, jnp.asarray(plan.pos), plan.free_mask,
+                    plan.donor, plan.live_pages)
+        with tel.span("repro.engine.sync") as sync:
+            next_host = np.array(next_tok, np.int32)
+        self.commit_step(plan, next_host, disp.seconds + sync.seconds)
         if self.dsg_rt is not None:
             # host pattern bookkeeping lags the device step (the paged
             # page-table split): retire first, then rewrite due lanes
@@ -1225,7 +1277,7 @@ class ServingEngine:
                 if self.slots[i].req is None:          # retired in commit
                     self.dsg_rt.reset_lane(i)
             if scores is not None:
-                self.dsg_rt.update_from_scores(np.asarray(scores), due)
+                self.dsg_rt.update_from_scores(scores, due)
 
     # -- fault containment (called by serving/router.py failover) ------------
     #
