@@ -206,14 +206,15 @@ def kernel_phase(cfg, sizes, seed):
     kn, vn = normal(ks[1], (b, kv, d)), normal(ks[2], (b, kv, d))
     pool = (b * max_pages + 1, ps, kv, d)
     kp, vp = normal(ks[3], pool), normal(ks[4], pool)
-    o, kp2, vp2 = ops.paged_decode_attention(q, kn, vn, kp, vp, table, pos,
+    o, kp2, vp2 = ops.paged_decode_attention(q, kn, vn, kp[None], vp[None],
+                                             table, pos, 0,
                                              num_pages=max_pages)
     with jax.default_matmul_precision("highest"):
         ow, kw, vw = jax.jit(ref.paged_decode_ref)(q, kn, vn, kp, vp,
                                                    table, pos)
     errs["paged_decode"] = _err(o, ow)
-    assert np.array_equal(np.asarray(kp2), np.asarray(kw)) and \
-        np.array_equal(np.asarray(vp2), np.asarray(vw)), \
+    assert np.array_equal(np.asarray(kp2[0]), np.asarray(kw)) and \
+        np.array_equal(np.asarray(vp2[0]), np.asarray(vw)), \
         "paged_decode pool write-back differs from the reference scatter"
 
     # group-CSR SwiGLU: ragged per-lane counts over sorted group lists

@@ -9,13 +9,23 @@ executor must read *only* the activated subset — applies to the serving
 memory plane too: per decode step, a lane's live state is exactly the
 pages at or below `pos // page_size`.  This kernel walks only those.
 
-Layout (serving/kv_cache.py PagedBackend, one layer's slice):
+Layout (serving/kv_cache.py PagedBackend, every layer's pool):
 
-    k_pages / v_pages : (P, page_size, Kv, D)   physical page pool
-    page_table        : (B, max_pages) int32    logical -> physical
-    pos               : (B,) int32              per-lane write position
-                                                (== the new token's
-                                                absolute position)
+    k_pages / v_pages : (L, P, page_size, Kv, D)  stacked page pools
+    layer             : () int32                  the layer to attend
+    page_table        : (B, max_pages) int32      logical -> physical
+    pos               : (B,) int32                per-lane write position
+                                                  (== the new token's
+                                                  absolute position)
+
+The kernel addresses layer `layer` of the stacked pools in place: the
+layer index rides as scalar prefetch beside the page table, every pool
+block index map leads with it, and the stacked pools alias the pool
+outputs.  The model's layer scan carries the stacks and hands the
+kernel its step's layer index, so no layer's pool is sliced out of the
+stack or written back into it; the only pool traffic is the walk's
+reads and one page written per lane.  A single layer's pool is the
+L = 1 case (`pool[None]`, layer 0).
 
 Grid: (B, n_pages), page index innermost so the per-lane flash
 accumulators carry across the page walk in VMEM scratch.  Each grid cell
@@ -66,8 +76,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG = -1e30
 
 
-def _kernel(pt_ref, pos_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref, hm_ref,
-            o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr, *,
+def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, kp_ref,
+            vp_ref, hm_ref, o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr, *,
             scale: float, ps: int, kv: int, window: int, n_pages: int):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -141,17 +151,19 @@ def _kernel(pt_ref, pos_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref, hm_ref,
 
 def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                  k_pages: jax.Array, v_pages: jax.Array,
-                 page_table: jax.Array, pos: jax.Array, *,
+                 page_table: jax.Array, pos: jax.Array, layer, *,
                  window: int = 0, num_pages: int = 0,
                  interpret: bool = False):
     """One fused decode step over the paged KV layout.
 
     q (B, H, D) — the step's queries (RoPE already applied);
     k_new/v_new (B, Kv, D) — the new token's K/V; k_pages/v_pages
-    (P, ps, Kv, D) — one layer's physical pools; page_table
-    (B, max_pages) int32; pos (B,) int32 per-lane write positions.
+    (L, P, ps, Kv, D) — every layer's physical pools; page_table
+    (B, max_pages) int32; pos (B,) int32 per-lane write positions;
+    layer () int32 — the layer whose pool is read and written.
     Returns (o (B, H, D), k_pages', v_pages') with the new rows
-    scattered into the pools.
+    scattered into layer `layer` of the pools; every other layer's
+    pages come back untouched (the pools are aliased in place).
 
     num_pages statically bounds the page walk (the serving scheduler
     passes its bucketed live-page bound so the grid shrinks with actual
@@ -172,7 +184,7 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     token-stream level.
     """
     b, h, d = q.shape
-    _, ps, kv, _ = k_pages.shape
+    _, _, ps, kv, _ = k_pages.shape
     assert h % kv == 0, f"H={h} not a multiple of Kv={kv}"
     g = h // kv
     max_pages = page_table.shape[1]
@@ -182,22 +194,23 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     head_mask = (jnp.arange(ps * kv)[None, :] % kv
                  == jnp.arange(h)[:, None] // g).astype(jnp.int32)
 
-    def page(bb, jj, pt, pos_):
+    def page(bb, jj, pt, pos_, ly):
         # depth-clamped physical page: cells past the lane's depth alias
         # their predecessor's block -> the pipeline elides the fetch
         # (pages past `pos` never leave HBM)
-        return (pt[bb, jnp.minimum(jj, pos_[bb] // ps)], 0, 0, 0)
+        return (ly[0], pt[bb, jnp.minimum(jj, pos_[bb] // ps)], 0, 0, 0)
 
-    def write_page(bb, jj, pt, pos_):
+    def write_page(bb, jj, pt, pos_, ly):
         # write page pinned for the whole walk -> one write-back per lane,
         # flushed when the block index changes (the walk clamp mirrors
         # the kernel's wp, see _kernel)
-        return (pt[bb, jnp.minimum(pos_[bb] // ps, walk - 1)], 0, 0, 0)
+        return (ly[0], pt[bb, jnp.minimum(pos_[bb] // ps, walk - 1)],
+                0, 0, 0)
 
-    lane = lambda bb, jj, pt, pos_: (bb, 0, 0)
-    page_block = (1, ps, kv, d)
+    lane = lambda bb, jj, pt, pos_, ly: (bb, 0, 0)
+    page_block = (None, 1, ps, kv, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,      # page_table, pos
+        num_scalar_prefetch=3,      # page_table, pos, layer
         grid=(b, walk),
         in_specs=[
             pl.BlockSpec((1, h, d), lane),
@@ -205,7 +218,7 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
             pl.BlockSpec((1, kv, d), lane),
             pl.BlockSpec(page_block, page),
             pl.BlockSpec(page_block, page),
-            pl.BlockSpec((h, ps * kv), lambda bb, jj, pt, pos_: (0, 0)),
+            pl.BlockSpec((h, ps * kv), lambda bb, jj, pt, pos_, ly: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, h, d), lane),
@@ -227,11 +240,12 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
-        # flat operand indices include the 2 scalar-prefetch args:
-        # 5 = k_pages, 6 = v_pages alias pool outputs 1, 2 (in-place)
-        input_output_aliases={5: 1, 6: 2},
+        # flat operand indices include the 3 scalar-prefetch args:
+        # 6 = k_pages, 7 = v_pages alias pool outputs 1, 2 (in-place)
+        input_output_aliases={6: 1, 7: 2},
         name="paged_decode",
         interpret=interpret,
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32),
       q, k_new, v_new, k_pages, v_pages, head_mask)
     return o, kp, vp

@@ -185,6 +185,7 @@ def self_attention(p: dict, x: jax.Array, *, n_heads: int, n_kv: int,
                    cache_kv_pos: Optional[jax.Array] = None,
                    page_table: Optional[jax.Array] = None,
                    live_pages: Optional[int] = None,
+                   layer: Optional[jax.Array] = None,
                    paged_kernel: str = "auto",
                    shard: str = "auto", bf16_scores: bool = False):
     """Self-attention over x (B, S, d).
@@ -199,9 +200,12 @@ def self_attention(p: dict, x: jax.Array, *, n_heads: int, n_kv: int,
     (out, updated_cache).
 
     Paged decode (serving/kv_cache.py PagedBackend): page_table is the
-    per-lane (B, max_pages) int32 map, cache={'k','v'} are the physical
-    page pools (P, page_size, Kv, D), and cache_pos carries the per-lane
-    depths.  Two executors behind `paged_kernel` (see _use_paged_kernel):
+    per-lane (B, max_pages) int32 map, cache={'k','v'} are every layer's
+    physical page pools (L, P, page_size, Kv, D), `layer` is the index of
+    this layer's pool in them, and cache_pos carries the per-lane depths;
+    the updated stacks come back whole.  Two executors behind
+    `paged_kernel` (see _use_paged_kernel), both addressing the stacks in
+    place at `layer`:
 
       * Pallas kernel (kernels/paged_attention.py): fused scatter +
         depth-bounded page walk + flash decode — per lane, only pages at
@@ -237,25 +241,31 @@ def self_attention(p: dict, x: jax.Array, *, n_heads: int, n_kv: int,
         if s != 1 or jnp.ndim(cache_pos) != 1:
             raise NotImplementedError(
                 "paged KV cache supports per-lane single-token decode only")
-        ps_sz = cache["k"].shape[1]
+        if layer is None:
+            raise ValueError("paged decode needs the layer index of its "
+                             "pool in the stacked pools")
+        ps_sz = cache["k"].shape[2]
         max_pages = page_table.shape[1]
         walk = min(live_pages, max_pages) if live_pages else max_pages
         if _use_paged_kernel(paged_kernel):
             from repro.kernels import ops as kernel_ops
             o, pk, pv = kernel_ops.paged_decode_attention(
                 q[:, 0], k_new[:, 0], v_new[:, 0], cache["k"], cache["v"],
-                page_table, cache_pos, window=window, num_pages=walk)
+                page_table, cache_pos, layer, window=window,
+                num_pages=walk)
             out = jnp.einsum("bshk,hkd->bsd", o[:, None], p["wo"])
             # pool sharding is deferred to the kernel's page addressing
             return out, {"k": pk, "v": pv}
         lanes = jnp.arange(b)
         pp = page_table[lanes, cache_pos // ps_sz]
         off = cache_pos % ps_sz
-        pk = cache["k"].at[pp, off].set(k_new[:, 0].astype(cache["k"].dtype))
-        pv = cache["v"].at[pp, off].set(v_new[:, 0].astype(cache["v"].dtype))
+        pk = cache["k"].at[layer, pp, off].set(
+            k_new[:, 0].astype(cache["k"].dtype))
+        pv = cache["v"].at[layer, pp, off].set(
+            v_new[:, 0].astype(cache["v"].dtype))
         t = jnp.arange(walk * ps_sz)
-        k = pk[page_table[:, t // ps_sz], t % ps_sz]
-        v = pv[page_table[:, t // ps_sz], t % ps_sz]
+        k = pk[layer, page_table[:, t // ps_sz], t % ps_sz]
+        v = pv[layer, page_table[:, t // ps_sz], t % ps_sz]
         kv_pos = (cache_kv_pos[..., :t.shape[0]]
                   if cache_kv_pos is not None else t)
     elif jnp.ndim(cache_pos) == 1:

@@ -171,7 +171,7 @@ def _drs_scores(h: jax.Array, r: jax.Array, fw: jax.Array,
 
 def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
            page_table, live_pages, mesh, batch_axes, csr_l=None,
-           collect_scores: bool = False):
+           collect_scores: bool = False, layer=None):
     from repro.parallel import context as pctx
 
     def boundary(t):
@@ -194,7 +194,7 @@ def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
         p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
         rope_theta=cfg.rope_theta, q_pos=q_pos, causal=True,
         window=cfg.window, cache=cache, cache_pos=cache_pos,
-        page_table=page_table, live_pages=live_pages,
+        page_table=page_table, live_pages=live_pages, layer=layer,
         paged_kernel=cfg.paged_attn_kernel, shard=cfg.attn_shard,
         bf16_scores=cfg.attn_bf16_scores)
     x = x + boundary(a)
@@ -230,7 +230,11 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
     cache: stacked per-layer KV {'k': (L,B,Smax,Kv,D), 'v': ...} for decode,
     or a paged-backend view {'pages_k': (L,P,ps,Kv,D), 'pages_v': ...,
     'page_table': (B, max_pages)} (see serving/kv_cache.py; the page table
-    is shared by all layers, so it rides outside the layer scan).
+    is shared by all layers, so it rides outside the layer scan).  The
+    dense cache is scanned as xs/ys; the paged pools ride the scan's carry
+    and each layer's attention addresses them at its layer index, so
+    the pools are updated in place and no layer's pool is sliced out of
+    the stack and written back (kernels/paged_attention.py).
     pos0: scalar start position, or a per-lane (B,) vector for continuous
     batching (each batch lane decodes at its own depth).
     live_pages: static page-walk bound for paged decode — the number of
@@ -255,26 +259,31 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
     r = dsg["r"] if dsg is not None else None
     dsg_stack = _layer_dsg(dsg, cfg)
 
-    def body(xc, scanned):
-        p_l, dsg_l, cache_l, csr_l = scanned
+    if page_table is not None:
+        carry, cache_xs = (x, cache), None
+        layer_xs = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    else:
+        carry, cache_xs, layer_xs = (x, None), cache, None
+
+    def body(carry, scanned):
+        xc, pools = carry
+        p_l, dsg_l, cache_l, layer, csr_l = scanned
         y, new_cache, aux, scores = _block(
-            p_l, dsg_l, r, xc, cfg, q_pos, cache_l, pos0, page_table,
-            live_pages, mesh, batch_axes, csr_l, collect_drs_scores)
-        ys = ((new_cache, aux, scores) if collect_drs_scores
-              else (new_cache, aux))
-        return y, ys
+            p_l, dsg_l, r, xc, cfg, q_pos,
+            cache_l if pools is None else pools, pos0, page_table,
+            live_pages, mesh, batch_axes, csr_l, collect_drs_scores, layer)
+        if pools is None:
+            return (y, None), (new_cache, aux, scores)
+        return (y, new_cache), (None, aux, scores)
 
     if cfg.remat and cache is None:
         body = jax.checkpoint(body)
 
-    x, ys = jax.lax.scan(
-        body, x, (params["layers"], dsg_stack, cache, ffn_csr))
-    if collect_drs_scores:
-        new_cache, aux, drs_scores = ys
-    else:
-        (new_cache, aux), drs_scores = ys, None
+    (x, pools), (new_cache, aux, drs_scores) = jax.lax.scan(
+        body, carry, (params["layers"], dsg_stack, cache_xs, layer_xs,
+                      ffn_csr))
     if page_table is not None:
-        new_cache = {"pages_k": new_cache["k"], "pages_v": new_cache["v"],
+        new_cache = {"pages_k": pools["k"], "pages_v": pools["v"],
                      "page_table": page_table}
     x = norm_apply(cfg.norm, params["ln_final"], x)
     if last_only:

@@ -7,20 +7,27 @@ over 8 KV heads of 128, d_ff=8192, 8 decode lanes, 16-token pages over a
 1024-token window) for one chip of a `v5e:2x2` topology that is described,
 not attached, and assert that the compiled program holds the Mosaic
 kernel (`tpu_custom_call`).  Nothing runs, so they say nothing about
-results or times.
+results or times.  One more compiles the serving engine's whole decode
+step at those widths over two layers and pins that the paged KV pools
+are updated in place: no pool-sized copy, slice or write-back.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and test collection
 imports this file in every worker.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
 from repro.kernels import drs_search, dsg_ffn, paged_attention
+from repro.models import api
+from repro.serving.kv_cache import CacheHandle
+from repro.serving.scheduler import make_decode_fns
 
 B, H, KV, D = 8, 16, 8, 128             # lanes, heads, KV heads, head dim
 D_MODEL, D_FF, BLOCK = 2048, 8192, 128
@@ -59,13 +66,59 @@ def _compile(name, fn, sharding, *shapes):
 
 
 def test_paged_decode_compiles(one_chip):
-    def step(q, kn, vn, kp, vp, pt, pos):
+    def step(q, kn, vn, kp, vp, pt, pos, layer):
         return paged_attention.paged_decode(q, kn, vn, kp, vp, pt, pos,
-                                            num_pages=MAX_PAGES)
-    pool = ((N_PAGES, PAGE, KV, D), BF16)
+                                            layer, num_pages=MAX_PAGES)
+    pool = ((2, N_PAGES, PAGE, KV, D), BF16)
     _compile("paged_decode", step, one_chip,
              ((B, H, D), BF16), ((B, KV, D), BF16), ((B, KV, D), BF16),
-             pool, pool, ((B, MAX_PAGES), jnp.int32), ((B,), jnp.int32))
+             pool, pool, ((B, MAX_PAGES), jnp.int32), ((B,), jnp.int32),
+             ((), jnp.int32))
+
+
+def test_decode_step_updates_pools_in_place(one_chip, monkeypatch):
+    """The engine's jitted greedy decode step (`_decode_greedy`) over two
+    layers: the kernel writes the pools the layer scan carries, so the
+    program holds no copy, dynamic slice or dynamic update of one
+    layer's pool or of the stack, and its temporaries stay under one
+    layer's pool."""
+    monkeypatch.setenv("REPRO_INTERPRET", "0")     # the kernel, compiled
+    base = configs.get_config("internlm2-1.8b")
+    cfg = base.replace(n_layers=2, paged_attn_kernel="kernel",
+                       dsg=base.dsg._replace(enabled=False))
+    put = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), t)
+    params = put(jax.eval_shape(lambda k: api.init_model(k, cfg),
+                                jax.random.PRNGKey(0)))
+    layer_pool = (N_PAGES, PAGE, KV, D)
+    pool = (cfg.n_layers,) + layer_pool
+    handle = CacheHandle(put({"pages_k": jax.ShapeDtypeStruct(pool, BF16),
+                              "pages_v": jax.ShapeDtypeStruct(pool, BF16),
+                              "page_table": jax.ShapeDtypeStruct(
+                                  (B, MAX_PAGES), jnp.int32)}),
+                         "paged", PAGE)
+    lanes = put((jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                 jax.ShapeDtypeStruct((B,), jnp.int32),
+                 jax.ShapeDtypeStruct((B,), jnp.bool_),
+                 jax.ShapeDtypeStruct((), jnp.int32)))
+    step = jax.jit(make_decode_fns(cfg)[0], donate_argnums=(3,),
+                   static_argnums=(7,))
+    tok, pos, free, donor = lanes
+    compiled = step.lower(params, None, tok, handle, pos, free, donor,
+                          MAX_PAGES).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert "%paged_decode" in text
+    # a copy, slice or update by its opcode, or a fusion named for one,
+    # whose result is one layer's pool or the stack
+    shapes = "|".join(",".join(map(str, s)) for s in (layer_pool, pool))
+    moves = "copy|dynamic-slice|dynamic-update-slice"
+    pool_op = re.compile(
+        r"= bf16\[(%s)\]\S* (%s)\(|%%\S*(%s)\S* = \(?bf16\[(%s)\]"
+        % (shapes, moves, moves, shapes))
+    assert not [ln for ln in text.splitlines() if pool_op.search(ln)]
+    layer_bytes = N_PAGES * PAGE * KV * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
 def test_dsg_ffn_csr_compiles(one_chip):

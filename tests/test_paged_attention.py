@@ -5,13 +5,18 @@ exactly as Mosaic would see it):
   * kernel vs the pure-jnp oracle (ref.paged_decode_ref) across page
     sizes {8, 16}, ragged per-lane depths, partial final pages, GQA
     group sizes, dtypes, and sliding windows — pools must match the
-    XLA scatter bit-for-bit;
+    XLA scatter bit-for-bit, in the addressed layer of a stacked pool,
+    and every other layer's pages must come back untouched;
   * the self_attention paged branch: Pallas executor vs the bounded
     XLA fallback on identical inputs, and the bounded fallback vs the
     whole-window gather;
   * the serving engine: a kernel-executor paged engine must reproduce
     the dense backend's token stream over admit -> decode -> retire ->
     readmit traffic (lane/page reuse included).
+
+The decode step's layer scan carries the stacked pools (the kernel
+writes them in place at the layer index); a jaxpr test pins that no
+pool-shaped array is scanned as xs or ys.
 """
 import jax
 import jax.numpy as jnp
@@ -27,16 +32,24 @@ TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
        jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
 
 
-def _paged_setup(seed, b, h, kv, d, ps, max_pages, pos, dtype=jnp.float32):
-    """Random pools + a page table mapping each lane's live pages to
-    distinct physical pages (page 0 reserved as scratch, as the backend
-    lays it out)."""
+# (layers in the stacked pool, layer addressed): the single-layer pool
+# as the L = 1 case, and the first and last layer of a 3-layer stack --
+# a wrong layer in an index map reads or writes a neighbour's pages
+LAYERS = [(1, 0), (3, 0), (3, 2)]
+
+
+def _paged_setup(seed, b, h, kv, d, ps, max_pages, pos, dtype=jnp.float32,
+                 n_layers=1):
+    """Random stacked pools (n_layers, P, ps, Kv, D) + a page table
+    mapping each lane's live pages to distinct physical pages (page 0
+    reserved as scratch, as the backend lays it out)."""
     rng = np.random.default_rng(seed)
     n_pages = 1 + b * max_pages
     mk = lambda shape: jnp.asarray(rng.standard_normal(shape), dtype)
     q = mk((b, h, d))
     k_new, v_new = mk((b, kv, d)), mk((b, kv, d))
-    k_pages, v_pages = (mk((n_pages, ps, kv, d)) for _ in range(2))
+    k_pages, v_pages = (mk((n_layers, n_pages, ps, kv, d))
+                        for _ in range(2))
     table = np.zeros((b, max_pages), np.int32)
     nxt = 1
     for lane in range(b):
@@ -47,44 +60,61 @@ def _paged_setup(seed, b, h, kv, d, ps, max_pages, pos, dtype=jnp.float32):
             jnp.asarray(np.asarray(pos, np.int32)))
 
 
+def _layer_args(args, layer):
+    """The oracle's single-layer view of stacked-pool kernel args."""
+    q, k_new, v_new, k_pages, v_pages, table, pos = args
+    return q, k_new, v_new, k_pages[layer], v_pages[layer], table, pos
+
+
+def _assert_other_layers_untouched(before, after, layer):
+    others = [i for i in range(before.shape[0]) if i != layer]
+    np.testing.assert_array_equal(np.asarray(after)[others],
+                                  np.asarray(before)[others])
+
+
+@pytest.mark.parametrize("n_layers,layer", LAYERS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("ps", [8, 16])
 @pytest.mark.parametrize("h,kv", [(4, 2), (2, 2)])
-def test_kernel_matches_oracle(dtype, ps, h, kv):
+def test_kernel_matches_oracle(dtype, ps, h, kv, n_layers, layer):
     # ragged depths: page-boundary cases (0, ps-1, ps) + partial pages
     pos = [0, ps - 1, ps, 2 * ps + 3, 5 * ps - 1]
-    args = _paged_setup(0, len(pos), h, kv, 16, ps, 6, pos, dtype)
-    o, kp, vp = paged_attention.paged_decode(*args, interpret=True)
-    ow, kw, vw = ref.paged_decode_ref(*args)
+    args = _paged_setup(0, len(pos), h, kv, 16, ps, 6, pos, dtype,
+                        n_layers)
+    o, kp, vp = paged_attention.paged_decode(*args, layer, interpret=True)
+    ow, kw, vw = ref.paged_decode_ref(*_layer_args(args, layer))
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(ow, np.float32), **TOL[dtype])
-    np.testing.assert_array_equal(np.asarray(kp), np.asarray(kw))
-    np.testing.assert_array_equal(np.asarray(vp), np.asarray(vw))
+    np.testing.assert_array_equal(np.asarray(kp[layer]), np.asarray(kw))
+    np.testing.assert_array_equal(np.asarray(vp[layer]), np.asarray(vw))
+    _assert_other_layers_untouched(args[3], kp, layer)
+    _assert_other_layers_untouched(args[4], vp, layer)
 
 
 def test_kernel_bounded_walk_and_window():
     ps, pos = 8, [5, 17, 40]
     args = _paged_setup(1, 3, 4, 2, 16, ps, 8, pos)
-    full, _, _ = paged_attention.paged_decode(*args, interpret=True)
+    full, _, _ = paged_attention.paged_decode(*args, 0, interpret=True)
     # depth-bounded walk: 6 pages cover max(pos)=40 -> identical output
-    bounded, _, _ = paged_attention.paged_decode(*args, num_pages=6,
+    bounded, _, _ = paged_attention.paged_decode(*args, 0, num_pages=6,
                                                  interpret=True)
     np.testing.assert_array_equal(np.asarray(bounded), np.asarray(full))
-    w, _, _ = paged_attention.paged_decode(*args, window=10, interpret=True)
-    ww, _, _ = ref.paged_decode_ref(*args, window=10)
+    w, _, _ = paged_attention.paged_decode(*args, 0, window=10,
+                                           interpret=True)
+    ww, _, _ = ref.paged_decode_ref(*_layer_args(args, 0), window=10)
     np.testing.assert_allclose(np.asarray(w), np.asarray(ww),
                                **TOL[jnp.float32])
 
 
-def _attn_inputs(seed, b, d_model, h, kv, hd, ps, max_pages, pos):
+def _attn_inputs(seed, b, d_model, h, kv, hd, ps, max_pages, pos,
+                 n_layers=1):
     rng = np.random.default_rng(seed)
     p = attn.init_attention(jax.random.PRNGKey(seed), d_model, h, kv, hd)
     x = jnp.asarray(rng.standard_normal((b, 1, d_model)), jnp.float32)
     n_pages = 1 + b * max_pages
-    pools = {"k": jnp.asarray(rng.standard_normal((n_pages, ps, kv, hd)),
-                              jnp.float32),
-             "v": jnp.asarray(rng.standard_normal((n_pages, ps, kv, hd)),
-                              jnp.float32)}
+    shape = (n_layers, n_pages, ps, kv, hd)
+    pools = {"k": jnp.asarray(rng.standard_normal(shape), jnp.float32),
+             "v": jnp.asarray(rng.standard_normal(shape), jnp.float32)}
     table = np.zeros((b, max_pages), np.int32)
     nxt = 1
     for lane in range(b):
@@ -95,14 +125,18 @@ def _attn_inputs(seed, b, d_model, h, kv, hd, ps, max_pages, pos):
     return p, x, pools, jnp.asarray(table), cp
 
 
+@pytest.mark.parametrize("n_layers,layer", [(1, 0), (3, 1)])
 @pytest.mark.parametrize("live_pages", [None, 4])
-def test_self_attention_kernel_vs_xla(live_pages):
+def test_self_attention_kernel_vs_xla(live_pages, n_layers, layer):
     """The full paged branch: Pallas executor vs XLA fallback on the same
-    scatter + depth-bounded gather + attend step (RoPE included)."""
+    scatter + depth-bounded gather + attend step (RoPE included), both
+    addressing one layer of the stacked pools."""
     ps, pos = 8, [3, 12, 25]
-    p, x, pools, table, cp = _attn_inputs(3, 3, 32, 4, 2, 8, ps, 8, pos)
+    p, x, pools, table, cp = _attn_inputs(3, 3, 32, 4, 2, 8, ps, 8, pos,
+                                          n_layers)
     kw = dict(n_heads=4, n_kv=2, rope_theta=10_000.0, q_pos=cp[:, None],
-              cache_pos=cp, page_table=table, live_pages=live_pages)
+              cache_pos=cp, page_table=table, live_pages=live_pages,
+              layer=layer)
     out_k, cache_k = attn.self_attention(p, x, cache=dict(pools),
                                          paged_kernel="kernel", **kw)
     out_x, cache_x = attn.self_attention(p, x, cache=dict(pools),
@@ -112,6 +146,7 @@ def test_self_attention_kernel_vs_xla(live_pages):
     for leaf in ("k", "v"):
         np.testing.assert_array_equal(np.asarray(cache_k[leaf]),
                                       np.asarray(cache_x[leaf]))
+        _assert_other_layers_untouched(pools[leaf], cache_k[leaf], layer)
 
 
 def test_xla_fallback_bounded_matches_whole_window():
@@ -120,7 +155,7 @@ def test_xla_fallback_bounded_matches_whole_window():
     ps, pos = 8, [3, 12, 25]
     p, x, pools, table, cp = _attn_inputs(4, 3, 32, 4, 2, 8, ps, 8, pos)
     kw = dict(n_heads=4, n_kv=2, rope_theta=10_000.0, q_pos=cp[:, None],
-              cache_pos=cp, page_table=table, paged_kernel="xla")
+              cache_pos=cp, page_table=table, paged_kernel="xla", layer=0)
     out_full, _ = attn.self_attention(p, x, cache=dict(pools),
                                       live_pages=None, **kw)
     out_bound, _ = attn.self_attention(p, x, cache=dict(pools),
@@ -129,21 +164,23 @@ def test_xla_fallback_bounded_matches_whole_window():
                                rtol=2e-6, atol=2e-6)
 
 
-def test_undersized_walk_never_corrupts_pools():
+@pytest.mark.parametrize("n_layers,layer", LAYERS)
+def test_undersized_walk_never_corrupts_pools(n_layers, layer):
     """An undersized num_pages bound is a caller bug (the scheduler's
     live_page_bound always covers the batch) — it may truncate the
     attended window, but it must never flush garbage over live K/V
     pages: the write-back page is clamped into the walk and degrades to
     an identity rewrite."""
     ps, pos = 8, [5, 17, 40]                  # deepest lane needs 6 pages
-    args = _paged_setup(7, 3, 4, 2, 16, ps, 8, pos)
+    args = _paged_setup(7, 3, 4, 2, 16, ps, 8, pos, n_layers=n_layers)
     q, k_new, v_new, k_pages, v_pages, table, cp = args
-    _, kp, vp = paged_attention.paged_decode(*args, num_pages=2,
+    _, kp, vp = paged_attention.paged_decode(*args, layer, num_pages=2,
                                              interpret=True)
     # lane 0 (depth 5, inside the walk) scatters its token normally;
-    # lanes 1 and 2 are beyond the walk and must leave the pools intact
-    want_k = k_pages.at[table[0, 0], 5].set(k_new[0])
-    want_v = v_pages.at[table[0, 0], 5].set(v_new[0])
+    # lanes 1 and 2 are beyond the walk and must leave the pools intact,
+    # as must every other layer
+    want_k = k_pages.at[layer, table[0, 0], 5].set(k_new[0])
+    want_v = v_pages.at[layer, table[0, 0], 5].set(v_new[0])
     np.testing.assert_array_equal(np.asarray(kp), np.asarray(want_k))
     np.testing.assert_array_equal(np.asarray(vp), np.asarray(want_v))
 
@@ -159,7 +196,7 @@ def test_self_attention_kernel_bf16_scores_tolerance():
     x = x.astype(jnp.bfloat16)
     pools = {k: v.astype(jnp.bfloat16) for k, v in pools.items()}
     kw = dict(n_heads=4, n_kv=2, rope_theta=10_000.0, q_pos=cp[:, None],
-              cache_pos=cp, page_table=table, bf16_scores=True)
+              cache_pos=cp, page_table=table, bf16_scores=True, layer=0)
     out_k, _ = attn.self_attention(p, x, cache=dict(pools),
                                    paged_kernel="kernel", **kw)
     out_x, _ = attn.self_attention(p, x, cache=dict(pools),
@@ -214,6 +251,59 @@ def test_tpu_backend_never_interprets(monkeypatch):
 @pytest.fixture(scope="module")
 def engine_parts():
     return make_engine_parts()
+
+
+def _scans(jaxpr, length):
+    """Every `scan` equation of `length` steps in a jaxpr, nested ones
+    (inside jit, cond, other scans) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["length"] == length:
+            found.append(eqn)
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _scans(sub, length)
+    return found
+
+
+@pytest.mark.parametrize("executor,sparse_ffn",
+                         [("kernel", False), ("xla", False), ("kernel", True)])
+def test_decode_layer_scan_carries_the_pools(engine_parts, executor,
+                                             sparse_ffn):
+    """The paged decode step's layer scan (dense FFN, and the DSG
+    group-CSR step) carries the stacked pools and scans no pool-shaped
+    array as xs or ys: scanning them would slice each layer's pool out
+    of the stack and write it back every layer (plus a whole-stack copy
+    after the loop) -- the copies this layout removes."""
+    from repro.serving.kv_cache import PagedBackend
+    from repro.serving.scheduler import make_decode_fns, make_dsg_decode_fns
+    cfg, params, dsg = engine_parts
+    cfg = cfg.replace(paged_attn_kernel=executor)
+    n_slots = 2
+    handle = PagedBackend(page_size=8).make(cfg, n_slots, 64)
+    pool = tuple(handle.data["pages_k"].shape)
+    assert pool[0] == cfg.n_layers
+    args = (params, dsg, jnp.zeros((n_slots, 1), jnp.int32), handle,
+            jnp.zeros(n_slots, jnp.int32), jnp.zeros(n_slots, bool), 0, 8)
+    if sparse_ffn:
+        csr = {"idx": jnp.zeros((cfg.n_layers, n_slots, 2), jnp.int32),
+               "counts": jnp.full((cfg.n_layers, n_slots), 2, jnp.int32)}
+        closed = jax.make_jaxpr(make_dsg_decode_fns(cfg)[0],
+                                static_argnums=(7, 9))(*args, csr, True)
+    else:
+        closed = jax.make_jaxpr(make_decode_fns(cfg)[0],
+                                static_argnums=(7,))(*args)
+    shapes = lambda vs: [tuple(v.aval.shape) for v in vs]
+    carried = []
+    for eqn in _scans(closed.jaxpr, cfg.n_layers):
+        n_c, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        xs = shapes(eqn.invars[n_c + n_carry:])
+        ys = shapes(eqn.outvars[n_carry:])
+        assert pool not in xs + ys, (xs, ys)
+        carried.append(shapes(eqn.invars[n_c:n_c + n_carry]).count(pool))
+    assert carried == [2], carried
 
 
 @pytest.mark.parametrize("page_size", [8, 16])
