@@ -1,22 +1,14 @@
-"""Plain reference of the served model, and the comparison that decides `correct`.
+"""The comparison that decides `correct`, against the served model's plain
+reference.
 
-The model is the published InternLM2 decoder (arXiv:2403.17297): RMSNorm
-before attention and before the FFN, rotary position embeddings
-(rotate-half, base `rope_theta`), grouped-query attention with each key/value
-head shared by `num_attention_heads / num_key_value_heads` consecutive query
-heads, a SiLU-gated FFN, a final RMSNorm and an untied output head.  Written in
-`jax.numpy` at float32 with every matrix product at HIGHEST precision, layer by
-layer (one compiled layer program walks the stacked weights), with no cache,
-kernel, paging or batching of the engine.  It imports nothing of the program.
-
-DSG (the configuration's `dsg` group, arXiv:1810.00859 with neuron groups of
-`block`): each FFN input h is projected by the ternary matrix R; a group's score
-is the sum over its `block` neurons of relu((h R^T)(R W_gate)); the top
-ceil((1 - gamma) G) groups are kept and the others' SiLU-gated activations are
-zeroed.  A prompt token selects from its own scores.  A generated token at
-position t (prompt length P) uses the selection made from the scores at
-position P - 1 + refresh_interval * floor((t - P) / refresh_interval): the last
-prompt token, then the input of every refresh_interval-th generated token.
+The reference itself is the configuration's model module's (`bench.model`:
+`hidden` and `logits`): the published forward pass in `jax.numpy` at float32
+with every matrix product at HIGHEST precision, with no cache, kernel, paging
+or batching of the engine, importing nothing of the program; with
+`quant=True` it is the control, every weight product in int8.  What stays here
+is the same for every model: the shared building blocks (`mm`, `fake_int8`,
+`rms_norm`, `rope`), the rows and their padding, the gap statistics, DSG's
+selection schedule and check, and the verdict.
 
 The comparison: for each sampled request, the prompt followed by its served
 tokens is run once; at each position that predicted a served token, the gap is
@@ -24,11 +16,13 @@ the reference's best logit minus its logit of that token.  The number compared
 is the mean gap over the served tokens; the widest gap and the share of served
 tokens that are not the reference's first choice are reported beside it.  (The
 widest gap of sound runs and of the control overlap at this model's size: see
-PERF.md.)  The control (`quant=True`) is the same reference with every weight
-product in int8 (symmetric, per output channel for weights, per token for
-activations): at each of those positions its gap is that of the token it puts
-first.
+PERF.md.)  For the control, at each of those positions the gap is that of the
+token it puts first.
 
+DSG: a prompt token selects its FFN groups from its own scores.  A generated
+token at position t (prompt length P) uses the selection made from the scores
+at position P - 1 + refresh_interval * floor((t - P) / refresh_interval): the
+last prompt token, then the input of every refresh_interval-th generated token.
 Under DSG a top-k over 64 group scores is not continuous: rounding moves a
 score across the cut, and a sound bf16 run keeps other groups than a float32
 one would.  So the selection is checked apart from the FFN it drives.  Each
@@ -45,12 +39,11 @@ scores.
 """
 from __future__ import annotations
 
-import math
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench import bench, workcount
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -86,65 +79,8 @@ def rope(x, pos, theta):
 
 
 def dsg_keep(cfg) -> int:
-    g = cfg["intermediate_size"] // cfg["dsg"]["block"]
-    return max(1, math.ceil((1.0 - cfg["dsg"]["gamma"]) * g - 1e-9))
-
-
-@partial(jax.jit, static_argnames=("cfg_key", "quant"))
-def _layer(x, layers, li, r, src, given, use_given, cfg_key, quant):
-    """One decoder layer over rows x (B, T, d) -> (x, DSG group scores
-    (B, T, G), or a placeholder for a dense model).  Under DSG, position t
-    keeps the groups of `given` (B, T, G) where `use_given` (B, T), else
-    the reference's own top-k at position src[t]."""
-    cfg = dict(cfg_key)
-    f32 = lambda a: a.astype(jnp.float32)                # noqa: E731
-    w = jax.tree.map(lambda a: f32(a[li]), layers)
-    b, t, _ = x.shape
-    heads, kv, hd = cfg["heads"], cfg["kv"], cfg["hd"]
-    pos = jnp.arange(t)
-
-    h = rms_norm(x, w["ln_attn"]["scale"], cfg["eps"])
-    q = rope(mm("btd,dhk->bthk", h, w["attn"]["wq"], (0,), quant), pos,
-             cfg["theta"])
-    k = rope(mm("btd,dhk->bthk", h, w["attn"]["wk"], (0,), quant), pos,
-             cfg["theta"])
-    v = mm("btd,dhk->bthk", h, w["attn"]["wv"], (0,), quant)
-    k = jnp.repeat(k, heads // kv, axis=2)
-    v = jnp.repeat(v, heads // kv, axis=2)
-    s = jnp.einsum("bqhk,bshk->bhqs", q, k, precision=HI) / math.sqrt(hd)
-    s = jnp.where(pos[None, None, :, None] >= pos[None, None, None, :], s,
-                  -jnp.inf)
-    o = jnp.einsum("bhqs,bshk->bqhk", jax.nn.softmax(s, -1), v, precision=HI)
-    x = x + mm("bthk,hkd->btd", o, w["attn"]["wo"], (0, 1), quant)
-
-    h = rms_norm(x, w["ln_ffn"]["scale"], cfg["eps"])
-    wg, wu, wd = w["ffn"]["w_gate"], w["ffn"]["w_up"], w["ffn"]["w_down"]
-    a = (jax.nn.silu(mm("btd,df->btf", h, wg, (0,), quant))
-         * mm("btd,df->btf", h, wu, (0,), quant))
-    if cfg["dsg"]:
-        blk, keep = cfg["block"], cfg["keep"]
-        rr = f32(r)
-        if quant:
-            rr = fake_int8(rr, (1,))
-            wg = fake_int8(wg, (0,))
-        fx = mm("btd,kd->btk", h, rr, (1,), quant)
-        fw = jnp.einsum("kd,df->kf", rr, wg, precision=HI)
-        virt = jnp.einsum("btk,kf->btf", fx, fw, precision=HI)
-        sc = jax.nn.relu(virt).reshape(b, t, -1, blk).sum(-1)     # (B, T, G)
-        thr = jax.lax.top_k(sc, keep)[0][..., keep - 1:]
-        sel = jnp.take_along_axis(sc >= thr, src[..., None], axis=1)
-        sel = jnp.where(use_given[..., None], given, sel)
-        a = a * jnp.repeat(sel, blk, axis=-1).astype(a.dtype)
-    else:
-        sc = jnp.zeros((1, 1, 1), jnp.float32)
-    return x + mm("btf,fd->btd", a, wd, (0,), quant), sc
-
-
-@partial(jax.jit, static_argnames=("eps", "quant"))
-def _logits(x, ln_final, head, eps, quant):
-    """Final norm and output head for one row: (T, d) -> (T, V)."""
-    h = rms_norm(x, ln_final.astype(jnp.float32), eps)
-    return mm("td,dv->tv", h, head.astype(jnp.float32), (0,), quant)
+    """Groups of a layer that DSG keeps (`workcount.kept_groups`)."""
+    return workcount.kept_groups(cfg)
 
 
 @jax.jit
@@ -164,47 +100,11 @@ def _row_gaps(ref, ctl, served, valid):
     return prog, ctrl
 
 
-def _cfg_key(cfg: dict) -> tuple:
-    dsg = cfg["dsg"]
-    key = dict(heads=cfg["num_attention_heads"],
-               kv=cfg["num_key_value_heads"], hd=cfg["head_dim"],
-               eps=cfg["rms_norm_eps"], theta=cfg["rope_theta"],
-               dsg=bool(dsg["enabled"]))
-    if dsg["enabled"]:
-        key.update(block=dsg["block"], keep=dsg_keep(cfg))
-    return tuple(sorted(key.items()))
-
-
 def selection_source(prompt_len: int, length: int, refresh: int) -> np.ndarray:
     """Position whose scores pick the FFN groups at each position (DSG)."""
     t = np.arange(length)
     dec = prompt_len - 1 + refresh * ((t - prompt_len) // refresh)
     return np.where(t < prompt_len, t, dec).astype(np.int32)
-
-
-def hidden(cfg: dict, w: dict, tokens: np.ndarray, src: np.ndarray,
-           quant: bool, given=None, use_given=None):
-    """Final residual stream (B, T, d) float32 of the token rows, and the
-    DSG group scores of every layer ([(B, T, G)], empty for a dense model).
-    `given` (L, B, T, G) and `use_given` (B, T): selections to run under."""
-    x = w["embed"][jnp.asarray(tokens)].astype(jnp.float32)
-    if quant:
-        x = fake_int8(x, (-1,))
-    key = _cfg_key(cfg)
-    r = w.get("r", jnp.zeros((1, 1), jnp.float32))
-    src = jnp.asarray(src)
-    if use_given is None:
-        use_given = np.zeros(tokens.shape, bool)
-    use_given = jnp.asarray(use_given)
-    scores = []
-    for li in range(cfg["num_hidden_layers"]):
-        g = (jnp.asarray(given[li]) if given is not None
-             else jnp.zeros((1, 1, 1), bool))
-        x, sc = _layer(x, w["layers"], li, r, src, g, use_given, cfg_key=key,
-                       quant=quant)
-        if cfg["dsg"]["enabled"]:
-            scores.append(sc)
-    return x, scores
 
 
 def selection_miss(scores: np.ndarray, kept: np.ndarray, keep: int) -> float:
@@ -222,7 +122,7 @@ def given_selections(cfg: dict, selections: list, n_rows: int, t_len: int):
     selections [(source position, first position, kept (L, G))]: a
     selection holds from its first position to the next one's."""
     n_layers = cfg["num_hidden_layers"]
-    groups = cfg["intermediate_size"] // cfg["dsg"]["block"]
+    groups = bench.model(cfg).dsg_groups(cfg)
     given = np.zeros((n_layers, n_rows, t_len, groups), bool)
     use = np.zeros((n_rows, t_len), bool)
     for i, sel in enumerate(selections):
@@ -254,16 +154,16 @@ def compare(cfg: dict, w: dict, rows: list, *, n_rows: int, control: bool,
         seq = np.concatenate([prompt, np.asarray(out, np.int32)])[:t_len]
         tokens[i, :len(seq)] = seq
         src[i] = selection_source(len(prompt), t_len, refresh)
-    eps = cfg["rms_norm_eps"]
+    model = bench.model(cfg)
     given = use = None
     if selections is not None:
         given, use = given_selections(cfg, selections, n_rows, t_len)
     xs, scs = {}, {}
-    xs[False], scs[False] = hidden(cfg, w, tokens, src, quant=False,
-                                   given=given, use_given=use)
+    xs[False], scs[False] = model.hidden(cfg, w, tokens, src, quant=False,
+                                         given=given, use_given=use)
     del given
     if control:
-        xs[True], scs[True] = hidden(cfg, w, tokens, src, quant=True)
+        xs[True], scs[True] = model.hidden(cfg, w, tokens, src, quant=True)
     prog, ctrl, n_tok = np.zeros(3), np.zeros(3), 0
     for i, (prompt, out) in enumerate(rows):
         p, n = len(prompt), len(out)
@@ -272,10 +172,9 @@ def compare(cfg: dict, w: dict, rows: list, *, n_rows: int, control: bool,
         valid = np.zeros(t_len, bool)
         served[p - 1:p - 1 + n] = out
         valid[p - 1:p - 1 + n] = True
-        ref = _logits(xs[False][i], w["ln_final"]["scale"], w["lm_head"],
-                      eps=eps, quant=False)
-        ctl = (_logits(xs[True][i], w["ln_final"]["scale"], w["lm_head"],
-                       eps=eps, quant=True) if control else None)
+        ref = model.logits(cfg, w, xs[False][i], quant=False)
+        ctl = (model.logits(cfg, w, xs[True][i], quant=True) if control
+               else None)
         pg, cg = (np.asarray(a, np.float64)
                   for a in _row_gaps(ref, ctl, served, valid))
         prog = np.array([prog[0] + pg[0], max(prog[1], pg[1]),

@@ -4,13 +4,14 @@
     python chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 From the root of a checkout.  The cell is an entry of `BENCHMARK.json`; its
-configuration, traffic mix and metric readers are found by name (bench.py).
-One run: make the weights on the device from the seed, build the serving engine
-the configuration states, warm up every prompt bucket and decode variant the mix
-can reach, then drive the mix for `--seconds` (the window), and after it check
-a seeded sample of the served requests against the plain reference
-(reference.py).  With `--trace 1` the profiler traces a few seconds inside the
-window and the line carries the per-layer metrics instead of the end-to-end ones.
+configuration, model module, traffic mix and metric readers are found by name
+(bench.py).  One run: make the weights on the device from the seed, build the
+serving engine the configuration states, warm up every prompt bucket and
+decode variant the mix can reach, then drive the mix for `--seconds` (the
+window), and after it check a seeded sample of the served requests against the
+plain reference (reference.py).  With `--trace 1` the profiler traces a few
+seconds inside the window and the line carries the per-layer metrics instead
+of the end-to-end ones.
 
 Without an accelerator, or with fewer chips than the cell asks for, it exits
 with code 3 and prints no result.  JAX's persistent compilation cache lives at
@@ -51,28 +52,10 @@ def enable_cache(root: Path) -> Path:
 
 
 def program_config(cfg: dict):
-    """The program's ModelConfig for the configuration file, as stated."""
-    from repro import configs
-    from repro.core import dsg_linear
-    base = configs.get_config(cfg["arch"])
-    d = cfg["dsg"]
-    dsg = base.dsg._replace(enabled=bool(d["enabled"]))
-    if d["enabled"]:
-        dsg = dsg._replace(gamma=d["gamma"], block=d["block"], eps=d["eps"],
-                           threshold_mode=d["threshold_mode"])
-    pc = base.replace(
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv=cfg["num_key_value_heads"], d_head=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        rope_theta=cfg["rope_theta"], dtype=cfg["torch_dtype"],
-        tie_embeddings=cfg["tie_word_embeddings"], dsg=dsg)
-    if d["enabled"]:
-        k = dsg_linear.proj_dim(pc.d_model, pc.d_ff, dsg)
-        if k != d["proj_dim"]:
-            raise ValueError(f"the program projects to {k} dimensions, the "
-                             f"configuration states {d['proj_dim']}")
-    return pc
+    """The program's ModelConfig for the configuration file, as its model
+    module states it."""
+    from chipbench import bench
+    return bench.model(cfg).program_config(cfg)
 
 
 def build_engine(cfg: dict, w: dict, seed: int):
@@ -82,9 +65,9 @@ def build_engine(cfg: dict, w: dict, seed: int):
     from repro.models import api
     from repro.serving.dsg_runtime import DSGServingConfig
     from repro.serving.scheduler import ServingEngine
-    from chipbench.weights import program_params
+    from chipbench import bench
     pc = program_config(cfg)
-    params = program_params(w)
+    params = bench.model(cfg).program_params(w)
     state, serving = None, None
     if cfg["dsg"]["enabled"]:
         state = jax.jit(lambda r, p: api.refresh_dsg({"r": r}, p, pc))(
@@ -119,32 +102,56 @@ def warm_up(eng, traffic, make_request):
 
 
 def sample_rows(drv, check: dict, seed: int):
-    """A seeded sample of served requests, the longest finished one first:
-    ([(prompt, served tokens)], their DSG selections or None), at most
-    `max_requests`, stopping once `min_served_tokens` are in it."""
+    """A sample of the finished requests, drawn from the seed alone: the
+    longest (the first by uid among equals), then the rest in the order of
+    a key that the seed gives each uid, so two runs of one seed check the
+    same requests wherever both finished them.  Returns ([(prompt, served
+    tokens)], their DSG selections or None), at most `max_requests`,
+    stopping once `min_served_tokens` are in it."""
     import numpy as np
     from chipbench.traffic import seed_words
-    done = [s for s in drv.done if s.req.status == "ok"]
-    rest = [s for s in drv.live.values() if s.req.output]
+    done = sorted((s for s in drv.done if s.req.status == "ok"),
+                  key=lambda s: s.spec.uid)
     rows = []
     if done:
         longest = max(done, key=lambda s: len(s.spec.prompt)
                       + len(s.req.output))
         rows.append(longest)
-        done = [s for s in done if s is not longest]
-    rng = np.random.default_rng(seed_words(seed) + [3])
-    pool = done + rest
-    for j in rng.permutation(len(pool)):
+        words = seed_words(seed) + [3]
+        done = sorted((s for s in done if s is not longest),
+                      key=lambda s: np.random.default_rng(
+                          words + seed_words(s.spec.uid)).random())
+    for r in done:
         if (len(rows) >= check["max_requests"]
                 or sum(len(s.req.output) for s in rows)
                 >= check["min_served_tokens"]):
             break
-        rows.append(pool[int(j)])
+        rows.append(r)
     sels = None
     if drv.selections is not None:
         sels = [drv.selections.by_uid.get(s.spec.uid, []) for s in rows]
     return [(np.asarray(s.spec.prompt), list(s.req.output))
             for s in rows], sels
+
+
+def step_spans(step) -> dict:
+    """The engine's own seconds in each of its spans inside one step, summed
+    by name, and the step's seconds outside them all (`outside`); empty for
+    a program that records no spans."""
+    try:
+        from repro.serving import telemetry
+    except ImportError:
+        return {}
+    out, covered = {}, 0.0
+    for rec in telemetry.recorders():
+        spans = rec.spans(step.start, step.end)
+        own = telemetry.self_seconds(spans)
+        for sp in spans:
+            out[sp.name] = out.get(sp.name, 0.0) + own[sp.sid]
+            if sp.parent not in own:
+                covered += sp.seconds
+    out["outside"] = step.end - step.start - covered
+    return out
 
 
 def run_cell(cell, args, dev: dict, log, cache: Path):
@@ -209,7 +216,8 @@ def run_cell(cell, args, dev: dict, log, cache: Path):
     longest = max(drv.steps, key=lambda r: r.end - r.start)
     info["longest_step"] = {"s": longest.end - longest.start,
                             "at_s": longest.start - window[0],
-                            "lanes": longest.lanes, "admits": longest.admits}
+                            "lanes": longest.lanes, "admits": longest.admits,
+                            "spans": step_spans(longest)}
 
     result = {"correct": False, "attempted": 0, "failed": 0, "metrics": {},
               "device": {**dev, "memory_peak_bytes": peak}}
@@ -260,7 +268,11 @@ def main(argv=None, *, require_chip: bool = True, root: Path = ROOT,
     sys.path[:0] = [str(root), str(root / "src")]
     cache = enable_cache(root)
     from chipbench import bench as bench_mod, device, report
-    cell = bench_mod.resolve(args.workload, root, bench)
+    try:
+        cell = bench_mod.resolve(args.workload, root, bench)
+    except bench_mod.UnknownModel as e:
+        print(f"chipbench: {e}", file=sys.stderr)
+        return 2
     try:
         dev = (device.require_chips(cell.chips) if require_chip
                else device.device_record())

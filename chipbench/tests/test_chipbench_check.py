@@ -6,7 +6,6 @@ come out not correct; and the control (the reference in int8) must differ
 from the reference.
 """
 import json
-import os
 
 import numpy as np
 import pytest
@@ -15,26 +14,12 @@ from chipbench import reference, run
 from chipbench.tests import tiny
 from chipbench.weights import make_weights
 
-KEYS = ("jax_compilation_cache_dir",
-        "jax_persistent_cache_min_compile_time_secs",
-        "jax_persistent_cache_min_entry_size_bytes")
-
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
     """A tiny checkout; JAX's cache settings are put back afterwards."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-    saved = {k: getattr(jax.config, k) for k in KEYS}
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    yield tiny.make_root(tmp_path_factory.mktemp("tiny"))
-    for k, v in saved.items():
-        jax.config.update(k, v)
-    if env is None:
-        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-    else:
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = env
-    compilation_cache.reset_cache()
+    with tiny.cache_settings_kept():
+        yield tiny.make_root(tmp_path_factory.mktemp("tiny"))
 
 
 def run_cell(root, capsys, cell, seed=2**31 + 5):
@@ -219,3 +204,36 @@ def test_chip_readings_against_the_limits(side, expect):
         assert ok is expect, (rec["workload"], rec["seed"], check)
         seen.add(name)
     assert seen == set(files)
+
+
+
+def finished(uids, lengths):
+    """A driver's finished requests, in the order given; each prompt holds
+    its uid."""
+    from types import SimpleNamespace as NS
+    return NS(selections=None, done=[
+        NS(spec=NS(uid=u, prompt=np.array([u], np.int32)),
+           req=NS(status="ok", output=[1] * lengths[u])) for u in uids])
+
+
+def test_sample_is_drawn_from_the_seed_alone():
+    """Two runs of one seed that finished the same requests in another
+    order check the same requests, and one that finished a request more
+    checks them too, or that one among them; the longest among equals is
+    the first by uid."""
+    lengths = [5, 9, 3, 9, 7, 2, 6, 8, 4, 1] * 4 + [2]
+    check = {"max_requests": 6, "min_served_tokens": 1000}
+    order = np.random.default_rng(0).permutation(40).tolist()
+
+    def sampled(uids, seed):
+        rows, sels = run.sample_rows(finished(uids, lengths), check, seed)
+        assert sels is None
+        return [int(prompt[0]) for prompt, _ in rows]
+
+    seed = 2**31 + 77
+    first = sampled(range(40), seed)
+    assert first[0] == 1 and len(first) == 6     # 1, 3, 11, ... are longest
+    assert sampled(order, seed) == first
+    more = [u for u in sampled(order + [40], seed) if u != 40]
+    assert more == first[:len(more)]
+    assert sampled(range(40), seed + 1) != first

@@ -2,7 +2,9 @@
 configurations and mixes, in a directory of its own, for runs on the CPU."""
 from __future__ import annotations
 
+import contextlib
 import json
+import os
 import shutil
 from pathlib import Path
 
@@ -10,6 +12,11 @@ HERE = Path(__file__).resolve().parent
 PKG = HERE.parent
 REPO = PKG.parent
 DATA = HERE / "data"
+
+#: JAX settings that `run.enable_cache` changes
+CACHE_KEYS = ("jax_compilation_cache_dir",
+              "jax_persistent_cache_min_compile_time_secs",
+              "jax_persistent_cache_min_entry_size_bytes")
 
 CELLS = {"tiny.tiny_batch": ("tiny", "tiny_batch"),
          "tiny-dsg.tiny_batch": ("tiny-dsg", "tiny_batch"),
@@ -54,3 +61,23 @@ def make_root(tmp: Path) -> Path:
                       if "workloads" in m else dict(m) for m in real[key]]
     (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
     return root
+
+
+@contextlib.contextmanager
+def cache_settings_kept():
+    """Puts JAX's compilation-cache settings back after in-process runs,
+    which point them into their checkout."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    saved = {k: getattr(jax.config, k) for k in CACHE_KEYS}
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        if env is None:
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+        else:
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = env
+        compilation_cache.reset_cache()

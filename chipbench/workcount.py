@@ -6,59 +6,70 @@ share of a roofline or of a peak stays under 100% whatever implements the layer.
 Counts are per decode step or per prefill, summed by the caller over the steps
 of a traced window.  `depth` is the number of keys a lane's query attends to:
 its prompt plus the tokens generated so far, the new one included.
+
+What a model's layers require (weights a token multiplies, attention at a
+depth, the sparse FFN and DRS) is its model module's (`bench.model`); the
+functions of that name here hand the configuration to it.  What is the same
+for every model stays here: DSG's kept share of groups, a decode step and a
+prefill composed from those counts, and the least time at the peaks.
 """
 from __future__ import annotations
 
 import math
 
+from chipbench import bench
+
 BYTES = {"bfloat16": 2, "float32": 4}
 
 
-def _dims(cfg: dict):
-    return (cfg["num_hidden_layers"], cfg["hidden_size"],
-            cfg["num_attention_heads"], cfg["num_key_value_heads"],
-            cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"])
+def kept_of(groups: int, gamma: float) -> int:
+    """Groups DSG keeps of `groups` at sparsity `gamma`:
+    ceil((1 - gamma) groups)."""
+    return max(1, math.ceil((1.0 - gamma) * groups - 1e-9))
 
 
 def keep_share(cfg: dict) -> float:
     """Share of FFN neuron groups DSG keeps (1.0 when DSG is off)."""
-    dsg = cfg["dsg"]
-    if not dsg["enabled"]:
+    if not cfg["dsg"]["enabled"]:
         return 1.0
-    g = cfg["intermediate_size"] // dsg["block"]
-    return kept_groups(cfg) / g
+    return kept_groups(cfg) / bench.model(cfg).dsg_groups(cfg)
 
 
 def kept_groups(cfg: dict) -> int:
-    dsg = cfg["dsg"]
-    g = cfg["intermediate_size"] // dsg["block"]
-    return max(1, math.ceil((1.0 - dsg["gamma"]) * g - 1e-9))
+    """Groups DSG keeps of a layer's `dsg_groups`."""
+    return kept_of(bench.model(cfg).dsg_groups(cfg), cfg["dsg"]["gamma"])
 
 
 def weight_flops_per_token(cfg: dict) -> float:
-    """Two FLOPs per weight a token multiplies: attention projections, the
-    FFN at its kept share, and the output head (the embedding is a lookup)."""
-    L, d, H, kv, hd, f, v = _dims(cfg)
-    attn = d * (H + 2 * kv) * hd + H * hd * d
-    ffn = 3 * d * f * keep_share(cfg)
-    return 2.0 * (L * (attn + ffn) + d * v)
+    """Two FLOPs per weight a token multiplies (the embedding is a lookup)."""
+    return bench.model(cfg).weight_flops_per_token(cfg)
 
 
 def attn_flops(cfg: dict, depth_sum: float) -> float:
-    """QK^T and PV over `depth_sum` keys in all, every layer."""
-    L, _, H, _, hd, _, _ = _dims(cfg)
-    return 4.0 * L * H * hd * depth_sum
+    """Attention's FLOPs over `depth_sum` keys in all, every layer."""
+    return bench.model(cfg).attn_flops(cfg, depth_sum)
+
+
+def attn_bytes(cfg: dict, lanes: float, depth_sum: float) -> float:
+    """Paged decode attention's bytes for `lanes` queries over `depth_sum`
+    keys in all, every layer."""
+    return bench.model(cfg).attn_bytes(cfg, lanes, depth_sum)
 
 
 def drs_flops(cfg: dict, rows: float) -> float:
-    """DRS scoring of `rows` FFN inputs in every layer: the projection
-    h R^T and the virtual product with R W_gate."""
-    dsg = cfg["dsg"]
-    if not dsg["enabled"]:
-        return 0.0
-    L, d, _, _, _, f, _ = _dims(cfg)
-    k = dsg["proj_dim"]
-    return 2.0 * L * rows * (d * k + k * f)
+    """DRS scoring of `rows` FFN inputs in every layer (0 without DSG)."""
+    return bench.model(cfg).drs_flops(cfg, rows)
+
+
+def ffn_csr_flops(cfg: dict, lanes: float) -> float:
+    """Sparse FFN of `lanes` tokens at their kept groups, every layer."""
+    return bench.model(cfg).ffn_csr_flops(cfg, lanes)
+
+
+def ffn_csr_bytes(cfg: dict, steps: float, lanes: float) -> float:
+    """Lower bound on the sparse FFN's bytes over `steps` steps of `lanes`
+    lanes in all, every layer."""
+    return bench.model(cfg).ffn_csr_bytes(cfg, steps, lanes)
 
 
 def decode_flops(cfg: dict, lanes: float, depth_sum: float,
@@ -75,33 +86,6 @@ def prefill_flops(cfg: dict, prompt_len: int) -> float:
     p = prompt_len
     return (p * weight_flops_per_token(cfg) + attn_flops(cfg, p * (p + 1) / 2)
             + drs_flops(cfg, p))
-
-
-def attn_bytes(cfg: dict, lanes: float, depth_sum: float) -> float:
-    """Paged decode attention: K and V of every key attended, the new K and
-    V written, and each lane's query read and output written, every layer."""
-    L, _, H, kv, hd, _, _ = _dims(cfg)
-    b = BYTES[cfg["torch_dtype"]]
-    return L * b * (2 * kv * hd * depth_sum
-                    + lanes * (2 * kv * hd + 2 * H * hd))
-
-
-def ffn_csr_flops(cfg: dict, lanes: float) -> float:
-    """Sparse FFN of `lanes` tokens: each lane's kept groups of the three
-    matrices, every layer."""
-    L, d, _, _, _, _, _ = _dims(cfg)
-    blk = cfg["dsg"]["block"]
-    return 2.0 * L * lanes * kept_groups(cfg) * blk * d * 3
-
-
-def ffn_csr_bytes(cfg: dict, steps: float, lanes: float) -> float:
-    """Lower bound on the sparse FFN's bytes: one lane's kept groups of the
-    three matrices per layer and step (no selection can read less; the
-    union over lanes is larger), plus each lane's input and output row."""
-    L, d, _, _, _, _, _ = _dims(cfg)
-    b = BYTES[cfg["torch_dtype"]]
-    blk = cfg["dsg"]["block"]
-    return L * b * (steps * kept_groups(cfg) * blk * d * 3 + lanes * 2 * d)
 
 
 def least_seconds(flops: float, nbytes: float, peaks: dict) -> float:
