@@ -22,6 +22,7 @@ class ModelConfig:
     rope_theta: float = 1_000_000.0
     act: str = "swiglu"         # swiglu | gelu
     norm: str = "rmsnorm"       # rmsnorm | layernorm
+    norm_eps: float = 1e-6      # RMSNorm epsilon (the published rms_norm_eps)
     tie_embeddings: bool = False
     # --- MoE ---
     moe_experts: int = 0
@@ -29,6 +30,11 @@ class ModelConfig:
     moe_shared: int = 0         # number of shared (always-on) experts
     moe_d_ff: int = 0           # per-expert hidden dim (fine-grained MoE)
     moe_capacity_factor: float = 1.25
+    moe_norm_topk: bool = True  # renormalise the top-k router weights
+    n_dense_layers: int = 0     # leading layers with a dense FFN instead
+                                # (first_k_dense_replace), d_ff wide
+    moe_expert_offset: int = 0  # the routed experts this chip holds:
+    moe_experts_held: int = 0   # [offset, offset + held); 0 holds all
     # --- SSM / hybrid ---
     ssm_state: int = 0          # Mamba2 N
     ssm_expand: int = 2
@@ -83,6 +89,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.moe_experts > 0
+
+    @property
+    def moe_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.moe_experts_held or self.moe_experts
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

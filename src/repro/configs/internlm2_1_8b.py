@@ -10,7 +10,7 @@ def config() -> ModelConfig:
     return ModelConfig(
         name=ARCH_ID, family="dense", n_layers=24, d_model=2048,
         n_heads=16, n_kv=8, d_ff=8192, vocab=92544, d_head=128,
-        rope_theta=1_000_000.0, dtype="bfloat16", attn_bf16_scores=True, microbatches=2,
+        rope_theta=1_000_000.0, norm_eps=1e-5, dtype="bfloat16", attn_bf16_scores=True, microbatches=2,
         dsg=DSGConfig(enabled=True, gamma=0.5, eps=0.5, block=128,
                       threshold_mode="shared", mode="mask", n_chunks=16),
     )

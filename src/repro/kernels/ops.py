@@ -18,7 +18,7 @@ from functools import partial
 import jax
 
 from repro.kernels import (drs_search, dsg_ffn, flash_attention as fa,
-                           paged_attention)
+                           moe_experts as moe_kernel, paged_attention)
 
 
 def _interpret() -> bool:
@@ -103,3 +103,12 @@ def paged_decode_attention(q, k_new, v_new, k_pages, v_pages, page_table,
     return paged_attention.paged_decode(
         q, k_new, v_new, k_pages, v_pages, page_table, pos, layer,
         window=window, num_pages=num_pages, interpret=_interpret())
+
+
+@jax.jit
+def moe_experts(x, starts, sizes, w_gate, w_up, w_down, layer=0):
+    """Grouped SwiGLU over the held experts (kernels/moe_experts.py): x
+    (M, d) rows sorted by expert, groups at `starts` of `sizes` rows, the
+    experts of layer `layer` of the stacked weights (L, E, ...) -> (M, d)."""
+    return moe_kernel.moe_experts(x, starts, sizes, w_gate, w_up, w_down,
+                                  layer, interpret=_interpret())

@@ -100,12 +100,14 @@ def make_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None):
 
 
 def prefill(params, dsg, cfg: ModelConfig, inputs: dict, cache,
-            mesh=None, batch_axes=None, collect_drs_scores: bool = False):
+            mesh=None, batch_axes=None, collect_drs_scores: bool = False,
+            moe_count=None):
     if cfg.family in DECODER_FAMILIES:
         return transformer.prefill(params, dsg, cfg, inputs["tokens"], cache,
                                    prefix_embeds=inputs.get("prefix_embeds"),
                                    mesh=mesh, batch_axes=batch_axes,
-                                   collect_drs_scores=collect_drs_scores)
+                                   collect_drs_scores=collect_drs_scores,
+                                   moe_count=moe_count)
     if collect_drs_scores:
         raise NotImplementedError(
             f"DRS score collection is a decoder-family serving feature "
@@ -128,13 +130,15 @@ def prefill(params, dsg, cfg: ModelConfig, inputs: dict, cache,
 
 def decode_step(params, dsg, cfg: ModelConfig, token, state, pos,
                 live_pages=None, mesh=None, batch_axes=None,
-                ffn_csr=None, collect_drs_scores: bool = False):
+                ffn_csr=None, collect_drs_scores: bool = False,
+                moe_count=None):
     if cfg.family in DECODER_FAMILIES:
         return transformer.decode_step(params, dsg, cfg, token, state, pos,
                                        live_pages=live_pages, mesh=mesh,
                                        batch_axes=batch_axes,
                                        ffn_csr=ffn_csr,
-                                       collect_drs_scores=collect_drs_scores)
+                                       collect_drs_scores=collect_drs_scores,
+                                       moe_count=moe_count)
     if ffn_csr is not None or collect_drs_scores:
         raise NotImplementedError(
             f"group-CSR decode / DRS score collection are decoder-family "

@@ -107,20 +107,20 @@ def encode(params, dsg, cfg: ModelConfig, frames: jax.Array) -> jax.Array:
 
     def body(x, scanned):
         p_l, fw_l = scanned
-        h = norm_apply(cfg.norm, p_l["ln_attn"], x)
+        h = norm_apply(cfg.norm, p_l["ln_attn"], x, cfg.norm_eps)
         a, _ = attn.self_attention(p_l["attn"], h, n_heads=cfg.n_heads,
                                    n_kv=cfg.n_kv, rope_theta=cfg.rope_theta,
                                    q_pos=pos, causal=False, window=cfg.window,
                                    shard=cfg.attn_shard)
         x = x + a
-        h = norm_apply(cfg.norm, p_l["ln_ffn"], x)
+        h = norm_apply(cfg.norm, p_l["ln_ffn"], x, cfg.norm_eps)
         return x + _ffn(p_l["ffn"], fw_l, r, h, cfg), None
 
     if cfg.remat:
         body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, frames.astype(_dtype(cfg)),
                         (params["enc_layers"], fw))
-    return norm_apply(cfg.norm, params["ln_enc"], x)
+    return norm_apply(cfg.norm, params["ln_enc"], x, cfg.norm_eps)
 
 
 def decode(params, dsg, cfg: ModelConfig, tokens: jax.Array,
@@ -135,24 +135,24 @@ def decode(params, dsg, cfg: ModelConfig, tokens: jax.Array,
 
     def body(xc, scanned):
         p_l, fw_l, mem_l, cache_l = scanned
-        h = norm_apply(cfg.norm, p_l["ln_attn"], xc)
+        h = norm_apply(cfg.norm, p_l["ln_attn"], xc, cfg.norm_eps)
         a, new_cache = attn.self_attention(
             p_l["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
             rope_theta=cfg.rope_theta, q_pos=q_pos, causal=True,
             window=0, cache=cache_l, cache_pos=pos0, shard=cfg.attn_shard)
         xc = xc + a
-        h = norm_apply(cfg.norm, p_l["ln_cross"], xc)
+        h = norm_apply(cfg.norm, p_l["ln_cross"], xc, cfg.norm_eps)
         c = attn.cross_attention(p_l["cross"], h, mem_l["k"], mem_l["v"],
                                  n_heads=cfg.n_heads, q_pos=q_pos)
         xc = xc + c
-        h = norm_apply(cfg.norm, p_l["ln_ffn"], xc)
+        h = norm_apply(cfg.norm, p_l["ln_ffn"], xc, cfg.norm_eps)
         return xc + _ffn(p_l["ffn"], fw_l, r, h, cfg), new_cache
 
     if cfg.remat and cache is None:
         body = jax.checkpoint(body)
     x, new_cache = jax.lax.scan(
         body, x, (params["dec_layers"], fw, memory_kv, cache))
-    x = norm_apply(cfg.norm, params["ln_dec"], x)
+    x = norm_apply(cfg.norm, params["ln_dec"], x, cfg.norm_eps)
     if last_only:
         x = x[:, -1:]
     logits = jnp.einsum("bsd,dv->bsv", x,

@@ -25,9 +25,11 @@ def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
     return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dt)
 
 
-def norm_apply(kind: str, p: dict, x: jax.Array) -> jax.Array:
+def norm_apply(kind: str, p: dict, x: jax.Array,
+               eps: float = 1e-6) -> jax.Array:
+    """RMSNorm at `eps` (the configuration's `norm_eps`), or LayerNorm."""
     if kind == "rmsnorm":
-        return rms_norm(x, p["scale"])
+        return rms_norm(x, p["scale"], eps)
     return layer_norm(x, p["scale"], p["bias"])
 
 

@@ -290,7 +290,7 @@ def zamba_forward(params, dsg, cfg: ModelConfig, tokens,
 
         def m_body(xc2, sc):
             p_l, fw_l, ssm_l, cx_l, cbc_l = sc
-            h = norm_apply(cfg.norm, p_l["ln"], xc2)
+            h = norm_apply(cfg.norm, p_l["ln"], xc2, cfg.norm_eps)
             gmask = None
             if fw_l is not None:
                 gmask = _gate_mask(h, r, fw_l, cfg)
@@ -304,7 +304,7 @@ def zamba_forward(params, dsg, cfg: ModelConfig, tokens,
             m_body, xc, (p_g, fw_z_g, ssm_g, cx_g, cbc_g))
 
         sh = params["shared"]
-        h = norm_apply(cfg.norm, sh["ln_attn"], xc)
+        h = norm_apply(cfg.norm, sh["ln_attn"], xc, cfg.norm_eps)
         cache_pos = pos0
         cache_kv_pos = None
         if decode and cfg.window and kv_g is not None:
@@ -318,7 +318,7 @@ def zamba_forward(params, dsg, cfg: ModelConfig, tokens,
             cache_pos=cache_pos, cache_kv_pos=cache_kv_pos,
             shard=cfg.attn_shard)
         xc = xc + a
-        h = norm_apply(cfg.norm, sh["ln_ffn"], xc)
+        h = norm_apply(cfg.norm, sh["ln_ffn"], xc, cfg.norm_eps)
         st = {"r": r, "fw": fw_sh} if fw_sh is not None else None
         xc = xc + dl.swiglu_ffn(sh["ffn"], h, st, cfg.dsg)
         return xc, (ssm_new, cx_new, cbc_new, kv_new)
@@ -339,7 +339,7 @@ def zamba_forward(params, dsg, cfg: ModelConfig, tokens,
     fw_z = dsg["fw_z"] if dsg else None
     x, (ssm_f, cx_f, cbc_f, kv_f) = jax.lax.scan(
         group_body, x, (params["mamba"], fw_z, ssm0, cx0, cbc0, kv0))
-    x = norm_apply(cfg.norm, params["ln_final"], x)
+    x = norm_apply(cfg.norm, params["ln_final"], x, cfg.norm_eps)
     if last_only:
         x = x[:, -1:]
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(dt))

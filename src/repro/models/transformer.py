@@ -2,9 +2,13 @@
 
 Layers are stacked (L, ...) pytrees scanned with lax.scan — HLO size is
 depth-independent (required for the 512-device dry-run compiles) and remat
-wraps the scan body.  The DSG state mirrors the layer stack: one shared
-projection R (d -> k) plus per-layer f(W) buffers refreshed by the training
-loop every cfg.dsg.refresh_every steps.
+wraps the scan body.  A MoE model's leading dense-FFN layers
+(`cfg.n_dense_layers`, DeepSeek's first_k_dense_replace) are a second stack,
+`params["dense_layers"]`, with a scan of its own ahead of the main one;
+`params["layers"]` holds the rest.  Layer indices (the paged pools' first
+axis) are global across both.  The DSG state mirrors the main layer stack:
+one shared projection R (d -> k) plus per-layer f(W) buffers refreshed by
+the training loop every cfg.dsg.refresh_every steps.
 """
 from __future__ import annotations
 
@@ -32,7 +36,9 @@ def _dtype(cfg: ModelConfig):
 # init
 # ---------------------------------------------------------------------------
 
-def init_layer(key: jax.Array, cfg: ModelConfig) -> dict:
+def init_layer(key: jax.Array, cfg: ModelConfig, dense: bool = False) -> dict:
+    """One layer: attention and the FFN, or MoE for a MoE model unless
+    `dense` (a leading dense layer)."""
     ka, kf = jax.random.split(key)
     dt = _dtype(cfg)
     p = {
@@ -41,9 +47,10 @@ def init_layer(key: jax.Array, cfg: ModelConfig) -> dict:
                                     cfg.head_dim, dt),
         "ln_ffn": norm_init(cfg.norm, cfg.d_model, dt),
     }
-    if cfg.is_moe:
+    if cfg.is_moe and not dense:
         p["moe"] = moe_mod.init_moe(kf, cfg.d_model, cfg.moe_experts,
-                                    cfg.moe_d_ff, cfg.moe_shared, dt)
+                                    cfg.moe_d_ff, cfg.moe_shared, dt,
+                                    n_held=cfg.moe_held)
     else:
         p["ffn"] = dl.init_swiglu(kf, cfg.d_model, cfg.d_ff, dt)
     return p
@@ -52,13 +59,18 @@ def init_layer(key: jax.Array, cfg: ModelConfig) -> dict:
 def init_model(key: jax.Array, cfg: ModelConfig) -> dict:
     ke, kl, kh = jax.random.split(key, 3)
     dt = _dtype(cfg)
-    layer_keys = jax.random.split(kl, cfg.n_layers)
+    layer_keys = jax.random.split(kl, cfg.n_layers - cfg.n_dense_layers)
     layers = jax.vmap(lambda k: init_layer(k, cfg))(layer_keys)
     p = {
         "embed": embed_init(ke, cfg.vocab, cfg.d_model, dt),
         "layers": layers,
         "ln_final": norm_init(cfg.norm, cfg.d_model, dt),
     }
+    if cfg.n_dense_layers:
+        dense_keys = jax.random.split(jax.random.fold_in(kl, 1),
+                                      cfg.n_dense_layers)
+        p["dense_layers"] = jax.vmap(
+            lambda k: init_layer(k, cfg, dense=True))(dense_keys)
     if not cfg.tie_embeddings:
         p["lm_head"] = (jax.random.normal(kh, (cfg.d_model, cfg.vocab))
                         / math.sqrt(cfg.d_model)).astype(dt)
@@ -66,7 +78,8 @@ def init_model(key: jax.Array, cfg: ModelConfig) -> dict:
 
 
 def init_dsg(key: jax.Array, params: dict, cfg: ModelConfig) -> Optional[dict]:
-    """DSG buffers: shared R + per-layer f(W) stacks (DESIGN.md §5)."""
+    """DSG buffers: shared R + per-layer f(W) stacks of the main layer
+    stack (DESIGN.md §5); leading dense layers run their FFN dense."""
     if not cfg.dsg.enabled:
         return None
     dt = _dtype(cfg)
@@ -118,8 +131,15 @@ def _layer_dsg(dsg: Optional[dict], cfg: ModelConfig):
 
 def _ffn_apply(p: dict, dsg_l: Optional[dict], r: Optional[jax.Array],
                x: jax.Array, cfg: ModelConfig, mesh, batch_axes,
-               csr_l: Optional[dict] = None):
-    """FFN or MoE with DSG; returns (y, aux).
+               csr_l: Optional[dict] = None,
+               count: Optional[jax.Array] = None,
+               experts: Optional[dict] = None, moe_layer=None):
+    """FFN or MoE with DSG; returns (y, aux, MoE stats or None).
+
+    experts: a served MoE forward's routed-expert stacks (forward); the
+    layer then routes dropless over the held experts of MoE layer
+    `moe_layer` of them (models/moe.py, `moe_ffn_dropless`) and counts
+    the rows of the tokens where `count` holds.
 
     csr_l: this layer's group-CSR selection {'idx': (B, K),
     'counts': (B,)} from the serving DSG runtime — when present the FFN
@@ -127,26 +147,34 @@ def _ffn_apply(p: dict, dsg_l: Optional[dict], r: Optional[jax.Array],
     dense reference, bounded XLA gather, or the CSR Pallas kernel per
     cfg.dsg_ffn_apply) instead of running DRS online per token."""
     if csr_l is not None:
-        if cfg.is_moe:
+        if "moe" in p:
             raise NotImplementedError(
                 "group-CSR serving selection targets the dense-FFN "
                 "family; MoE experts are already conditional compute")
         y = dl.swiglu_csr(p["ffn"], x, csr_l["idx"], csr_l["counts"],
                           block=cfg.dsg.block, apply=cfg.dsg_ffn_apply)
-        return y, jnp.float32(0.0)
-    if cfg.is_moe:
+        return y, jnp.float32(0.0), None
+    if "moe" in p and experts is not None:
+        y, stats = moe_mod.moe_ffn_dropless(
+            p["moe"], x, top_k=cfg.moe_topk, norm_topk=cfg.moe_norm_topk,
+            expert_offset=cfg.moe_expert_offset, count=count,
+            experts=experts, layer=moe_layer)
+        return y, jnp.float32(0.0), stats
+    if "moe" in p:
         dsg_state = None
         if dsg_l is not None:
             dsg_state = {"r": r, "fw_experts": dsg_l["fw_experts"]}
             if "fw_shared" in dsg_l:
                 dsg_state["shared"] = {"r": r, "fw": dsg_l["fw_shared"]}
-        return moe_mod.moe_ffn(
+        y, aux = moe_mod.moe_ffn(
             p["moe"], x, n_experts=cfg.moe_experts, top_k=cfg.moe_topk,
             capacity_factor=cfg.moe_capacity_factor, dsg=cfg.dsg,
             dsg_state=dsg_state, mesh=mesh, batch_axes=batch_axes,
-            aux_kind=cfg.moe_aux)
+            aux_kind=cfg.moe_aux, norm_topk=cfg.moe_norm_topk,
+            expert_offset=cfg.moe_expert_offset)
+        return y, aux, None
     st = {"r": r, "fw": dsg_l["fw"]} if dsg_l is not None else None
-    return dl.swiglu_ffn(p["ffn"], x, st, cfg.dsg), jnp.float32(0.0)
+    return dl.swiglu_ffn(p["ffn"], x, st, cfg.dsg), jnp.float32(0.0), None
 
 
 def _drs_scores(h: jax.Array, r: jax.Array, fw: jax.Array,
@@ -171,7 +199,8 @@ def _drs_scores(h: jax.Array, r: jax.Array, fw: jax.Array,
 
 def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
            page_table, live_pages, mesh, batch_axes, csr_l=None,
-           collect_scores: bool = False, layer=None):
+           collect_scores: bool = False, layer=None, count=None,
+           experts=None, moe_layer=None):
     from repro.parallel import context as pctx
 
     def boundary(t):
@@ -189,7 +218,7 @@ def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
         # Megatron-SP: residual stream (== the remat stash) seq-sharded
         ba = pctx.batch_axes()
         x = pctx.constrain(x, ba, "model", None)
-    h = norm_apply(cfg.norm, p["ln_attn"], x)
+    h = norm_apply(cfg.norm, p["ln_attn"], x, cfg.norm_eps)
     a, new_cache = attn.self_attention(
         p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
         rope_theta=cfg.rope_theta, q_pos=q_pos, causal=True,
@@ -198,15 +227,16 @@ def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
         paged_kernel=cfg.paged_attn_kernel, shard=cfg.attn_shard,
         bf16_scores=cfg.attn_bf16_scores)
     x = x + boundary(a)
-    h = norm_apply(cfg.norm, p["ln_ffn"], x)
+    h = norm_apply(cfg.norm, p["ln_ffn"], x, cfg.norm_eps)
     scores = None
     if collect_scores:
         scores = _drs_scores(h, r, dsg_l["fw"], cfg)
-    f, aux = _ffn_apply(p, dsg_l, r, h, cfg, mesh, batch_axes, csr_l)
+    f, aux, stats = _ffn_apply(p, dsg_l, r, h, cfg, mesh, batch_axes, csr_l,
+                               count, experts, moe_layer)
     x = x + boundary(f)
     if cfg.seq_sharded_residual:
         x = pctx.constrain(x, pctx.batch_axes(), "model", None)
-    return x, new_cache, aux, scores
+    return x, new_cache, aux, scores, stats
 
 
 def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
@@ -215,9 +245,11 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
             live_pages: Optional[int] = None,
             mesh: Optional[Mesh] = None, batch_axes=None,
             last_only: bool = False, ffn_csr: Optional[dict] = None,
-            collect_drs_scores: bool = False):
+            collect_drs_scores: bool = False,
+            moe_count: Optional[jax.Array] = None):
     """tokens (B, S) -> (logits, new_cache, aux_loss)
-    [+ drs_scores (L, B, S, G) when collect_drs_scores].
+    [+ drs_scores (L, B, S, G) when collect_drs_scores]
+    [+ MoE stats (3,) int32 when moe_count is given to a MoE model].
 
     ffn_csr: serving DSG selection stacks {'idx': (L, B, K),
     'counts': (L, B)} — per-layer group-CSR patterns scanned alongside
@@ -241,6 +273,10 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
     leading logical pages that cover every lane's depth (the serving
     scheduler computes it per step, bucketed so the decode jit compiles
     a handful of variants); None/0 walks the full table width.
+    moe_count (B, S) bool: the tokens a served MoE forward counts (active
+    lanes, true prompt tokens); the stats are the sums over the MoE
+    layers of the rows routed to held experts, the held experts hit and
+    each layer's largest group (models/moe.py, `moe_ffn_dropless`).
     """
     page_table = None
     if cache is not None and "page_table" in cache:
@@ -258,42 +294,73 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
 
     r = dsg["r"] if dsg is not None else None
     dsg_stack = _layer_dsg(dsg, cfg)
+    n_dense = cfg.n_dense_layers
+    if n_dense and (ffn_csr is not None or collect_drs_scores):
+        raise NotImplementedError(
+            "group-CSR selection and DRS scores serve the dense-FFN family")
 
-    if page_table is not None:
-        carry, cache_xs = (x, cache), None
-        layer_xs = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
-    else:
-        carry, cache_xs, layer_xs = (x, None), cache, None
+    # served MoE: the routed experts' weights stay stacked, out of the
+    # scanned params, and the expert kernel reads layer i of the stacks in
+    # place (kernels/moe_experts.py); a slice per layer would be a copy
+    stack, experts = params["layers"], None
+    if cache is not None and cfg.is_moe:
+        moe = stack["moe"]
+        experts = {k: moe[k] for k in ("w_gate", "w_up", "w_down")}
+        stack = {**stack, "moe": {k: v for k, v in moe.items()
+                                  if k not in experts}}
 
     def body(carry, scanned):
         xc, pools = carry
-        p_l, dsg_l, cache_l, layer, csr_l = scanned
-        y, new_cache, aux, scores = _block(
+        p_l, dsg_l, cache_l, layer, csr_l, i = scanned
+        y, new_cache, aux, scores, stats = _block(
             p_l, dsg_l, r, xc, cfg, q_pos,
             cache_l if pools is None else pools, pos0, page_table,
-            live_pages, mesh, batch_axes, csr_l, collect_drs_scores, layer)
+            live_pages, mesh, batch_axes, csr_l, collect_drs_scores, layer,
+            moe_count, experts, i)
         if pools is None:
-            return (y, None), (new_cache, aux, scores)
-        return (y, new_cache), (None, aux, scores)
+            return (y, None), (new_cache, aux, scores, stats)
+        return (y, new_cache), (None, aux, scores, stats)
 
     if cfg.remat and cache is None:
         body = jax.checkpoint(body)
 
-    (x, pools), (new_cache, aux, drs_scores) = jax.lax.scan(
-        body, carry, (params["layers"], dsg_stack, cache_xs, layer_xs,
-                      ffn_csr))
+    def scan(carry, stack, dsg_s, first, n, csr, moe):
+        """Layers first .. first + n - 1 of the model, one stack."""
+        cache_xs = layer_xs = None
+        if page_table is not None:
+            layer_xs = jnp.arange(first, first + n, dtype=jnp.int32)
+        elif cache is not None:
+            cache_xs = cache if not n_dense else jax.tree.map(
+                lambda a: a[first:first + n], cache)
+        local = jnp.arange(n, dtype=jnp.int32) if moe else None
+        return jax.lax.scan(body, carry,
+                            (stack, dsg_s, cache_xs, layer_xs, csr, local))
+
+    carry = (x, cache if page_table is not None else None)
+    if n_dense:
+        carry, (dense_cache, _, _, _) = scan(
+            carry, params["dense_layers"], None, 0, n_dense, None, False)
+    (x, pools), (new_cache, aux, drs_scores, stats) = scan(
+        carry, stack, dsg_stack, n_dense, cfg.n_layers - n_dense, ffn_csr,
+        experts is not None)
     if page_table is not None:
         new_cache = {"pages_k": pools["k"], "pages_v": pools["v"],
                      "page_table": page_table}
-    x = norm_apply(cfg.norm, params["ln_final"], x)
+    elif cache is not None and n_dense:
+        new_cache = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                                 dense_cache, new_cache)
+    x = norm_apply(cfg.norm, params["ln_final"], x, cfg.norm_eps)
     if last_only:
         x = x[:, -1:]
     head = (params["embed"].T if cfg.tie_embeddings
             else params["lm_head"]).astype(_dtype(cfg))
     logits = jnp.einsum("bsd,dv->bsv", x, head)
+    out = (logits, new_cache, jnp.sum(aux))
     if collect_drs_scores:
-        return logits, new_cache, jnp.sum(aux), drs_scores
-    return logits, new_cache, jnp.sum(aux)
+        out += (drs_scores,)
+    if moe_count is not None and cfg.is_moe:
+        out += (jnp.sum(stats, axis=0),)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -338,32 +405,36 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
 
 def prefill(params, dsg, cfg: ModelConfig, tokens, cache,
             prefix_embeds=None, mesh=None, batch_axes=None,
-            collect_drs_scores: bool = False):
+            collect_drs_scores: bool = False, moe_count=None):
     """Prefill the cache with the prompt; returns (last_logits, cache)
     [+ last-token DRS scores (L, B, G) when collect_drs_scores — what the
-    serving runtime seeds a lane's CSR pattern from at admission]."""
+    serving runtime seeds a lane's CSR pattern from at admission]
+    [+ MoE stats (3,) over the tokens where moe_count holds (forward)]."""
     out = forward(params, dsg, cfg, tokens, prefix_embeds=prefix_embeds,
                   cache=cache, pos0=0, mesh=mesh, batch_axes=batch_axes,
-                  last_only=True, collect_drs_scores=collect_drs_scores)
+                  last_only=True, collect_drs_scores=collect_drs_scores,
+                  moe_count=moe_count)
+    logits, new_kv = out[0], out[1]
+    rest = out[3:]
     if collect_drs_scores:
-        logits, new_kv, _, scores = out
-        return logits[:, -1], new_kv, scores[:, :, -1]
-    logits, new_kv, _ = out
-    return logits[:, -1], new_kv
+        rest = (rest[0][:, :, -1],) + rest[1:]
+    return (logits[:, -1], new_kv) + rest
 
 
 def decode_step(params, dsg, cfg: ModelConfig, token, cache, pos,
                 live_pages=None, mesh=None, batch_axes=None,
-                ffn_csr=None, collect_drs_scores: bool = False):
+                ffn_csr=None, collect_drs_scores: bool = False,
+                moe_count=None):
     """One decode step.  token (B, 1), pos scalar or per-lane (B,) vector
     -> (logits (B, V), cache) [+ DRS scores (L, B, G) when
-    collect_drs_scores].  live_pages: static paged-walk bound; ffn_csr:
+    collect_drs_scores] [+ MoE stats (3,) over the lanes where moe_count
+    (B, 1) holds].  live_pages: static paged-walk bound; ffn_csr:
     per-layer group-CSR selection stacks (see forward)."""
     out = forward(params, dsg, cfg, token, cache=cache, pos0=pos,
                   live_pages=live_pages, mesh=mesh, batch_axes=batch_axes,
-                  ffn_csr=ffn_csr, collect_drs_scores=collect_drs_scores)
+                  ffn_csr=ffn_csr, collect_drs_scores=collect_drs_scores,
+                  moe_count=moe_count)
+    rest = out[3:]
     if collect_drs_scores:
-        logits, new_cache, _, scores = out
-        return logits[:, -1], new_cache, scores[:, :, 0]
-    logits, new_cache, _ = out
-    return logits[:, -1], new_cache
+        rest = (rest[0][:, :, 0],) + rest[1:]
+    return (out[0][:, -1], out[1]) + rest

@@ -185,25 +185,42 @@ def make_decode_fns(cfg):
     the sharded replica executor can vmap THE SAME step bodies over a
     leading replica axis — one definition, two compilation strategies,
     no drift between the per-engine and batched paths.
+
+    A MoE model's step returns the next tokens (B,) followed by its three
+    expert-layer counts over the active lanes (models/moe.py,
+    `moe_ffn_dropless`), (B + 3,) int32, so that they reach the host in
+    the tokens' transfer; `commit_step` splits them.
     """
-    def _decode_greedy(p, d, tok, c, pos, free_mask, donor, live_pages):
+    def _step(p, d, tok, c, pos, free_mask, donor, live_pages):
         view = kv_cache.decode_view(c, free_mask, donor)
-        logits, data = api.decode_step(p, d, cfg, tok, view, pos,
-                                       live_pages=live_pages)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if not cfg.is_moe:
+            logits, data = api.decode_step(p, d, cfg, tok, view, pos,
+                                           live_pages=live_pages)
+            return logits, data, None
+        return api.decode_step(p, d, cfg, tok, view, pos,
+                               live_pages=live_pages,
+                               moe_count=~free_mask[:, None])
+
+    def _out(nxt, stats, data, c):
+        if stats is not None:
+            nxt = jnp.concatenate([nxt, stats])
         return nxt, CacheHandle(_restore_table(data, c), c.kind,
                                 c.page_size)
 
+    def _decode_greedy(p, d, tok, c, pos, free_mask, donor, live_pages):
+        logits, data, stats = _step(p, d, tok, c, pos, free_mask, donor,
+                                    live_pages)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return _out(nxt, stats, data, c)
+
     def _decode_sample(p, d, tok, c, pos, free_mask, donor, live_pages,
                        key, step, temps, top_ps):
-        view = kv_cache.decode_view(c, free_mask, donor)
-        logits, data = api.decode_step(p, d, cfg, tok, view, pos,
-                                       live_pages=live_pages)
+        logits, data, stats = _step(p, d, tok, c, pos, free_mask, donor,
+                                    live_pages)
         keys = jax.random.split(jax.random.fold_in(key, step),
                                 tok.shape[0])
         nxt = sample_tokens(logits, keys, temps, top_ps)
-        return nxt, CacheHandle(_restore_table(data, c), c.kind,
-                                c.page_size)
+        return _out(nxt, stats, data, c)
 
     return _decode_greedy, _decode_sample
 
@@ -539,6 +556,16 @@ class ServingEngine:
             logits, lane = api.prefill(p, d, cfg, {"tokens": toks}, lane0)
             return logits[0], lane
 
+        def _prefill_moe(p, d, toks, lane0, n_prompt):
+            # the greedy first token and the expert-layer counts over the
+            # true prompt tokens (the row's last n_prompt), in one array:
+            # the admission's one read of the device
+            count = jnp.arange(toks.shape[1]) >= toks.shape[1] - n_prompt
+            logits, lane, stats = api.prefill(p, d, cfg, {"tokens": toks},
+                                              lane0, moe_count=count[None])
+            first = jnp.argmax(logits[0]).astype(jnp.int32)
+            return logits[0], lane, jnp.concatenate([first[None], stats])
+
         def _first_tok(logits, key, draw, temp, top_p):
             k = jax.random.fold_in(jax.random.fold_in(key, _ADMIT_SALT),
                                    draw)
@@ -552,6 +579,7 @@ class ServingEngine:
         # compiles one variant per live-page bucket (see _live_pages).
         _decode_greedy, _decode_sample = make_decode_fns(cfg)
         self._jit_prefill = jax.jit(_prefill)
+        self._jit_prefill_moe = jax.jit(_prefill_moe) if cfg.is_moe else None
         self._jit_first = jax.jit(_first_tok)
         self._jit_decode_greedy = jax.jit(_decode_greedy,
                                           donate_argnums=(3,),
@@ -818,11 +846,15 @@ class ServingEngine:
             if chain is not None \
                     and self.backend.shared_hits(chain) == len(chain):
                 cached = self._prefill_cache.get(chain[-1])
-            sc = lane = None
+            sc = lane = first = None
             if cached is not None:
                 self._prefill_cache.move_to_end(chain[-1])
                 self.prefill_cache_hits += 1
                 logits, sc = cached
+            elif self._jit_prefill_moe is not None:
+                logits, lane, first = self._jit_prefill_moe(
+                    self.params, self.dsg, jnp.asarray(toks), self._lane0,
+                    np.int32(min(len(req.prompt), pb)))
             elif self.dsg_rt is not None:
                 # the prompt's last-token DRS scores seed the lane's CSR
                 # pattern: the lane decodes sparsely from step one (a
@@ -843,14 +875,19 @@ class ServingEngine:
                 tok = self._jit_first(logits, self._base_key, self._draws,
                                       np.float32(req.temperature),
                                       np.float32(req.top_p))
-            else:
+            elif first is None:
                 tok = jnp.argmax(logits)
+            else:
+                tok = None          # the greedy token leads `first`
             req.started = time.perf_counter()
             # the host's reads of the admission's device values wait for
             # the prefill
             with tel.span("repro.engine.first_token"):
-                self._next_tok[i] = int(tok)
+                got = None if first is None else np.asarray(first)
+                self._next_tok[i] = int(got[0] if tok is None else tok)
                 sc_np = None if sc is None else np.asarray(sc)
+            if got is not None:
+                span.set(**self._count_moe(got[1:]))
             if self.dsg_rt is not None:
                 self.dsg_rt.set_lane_from_scores(i, sc_np[:, 0])
             if chain is not None and cached is None:
@@ -1073,19 +1110,36 @@ class ServingEngine:
 
     @runs_on("worker")
     def commit_step(self, plan: StepPlan, next_tok: np.ndarray,
-                    seconds: float):
+                    seconds: float) -> dict:
         """Record a decode result: latch each lane's next input token,
         account the device time/tokens, and retire finished lanes.
         `next_tok` must already be host-side (the caller syncs — that is
-        where the device wait belongs in the timing)."""
+        where the device wait belongs in the timing).  A MoE step's
+        counts follow the tokens (make_decode_fns); they are counted and
+        returned as span attrs (empty for a dense model)."""
         with self.telemetry.span("repro.engine.commit") as span:
-            self._next_tok = np.array(next_tok, np.int32)
+            self._next_tok = np.array(next_tok[:self.n_slots], np.int32)
             self.decode_seconds += seconds
             self.decode_tokens += len(plan.active)
             self.steps += 1
             for i in plan.active:
                 self.slots[i].pos += 1
             span.set(retired=len(self._retire(plan.active)))
+            return self._count_moe(next_tok[self.n_slots:])
+
+    @runs_on("worker")
+    def _count_moe(self, stats: np.ndarray) -> dict:
+        """A MoE forward's counts (rows routed to held experts, held
+        experts hit, largest group; sums over the MoE layers) into the
+        running counters `moe.rows` and `moe.experts_hit`; returns them
+        as span attrs.  Empty for a dense model's empty `stats`."""
+        if not len(stats):
+            return {}
+        rows, hit, most = (int(v) for v in stats)
+        self.telemetry.count("moe.rows", rows)
+        self.telemetry.count("moe.experts_hit", hit)
+        return {"moe_rows": rows, "moe_experts_hit": hit,
+                "moe_rows_max": most}
 
     @runs_on("worker")
     def _retire(self, lanes) -> list:
@@ -1229,13 +1283,14 @@ class ServingEngine:
             span.set(lanes=len(plan.active) if plan else 0,
                      admits=plan.admits if plan else 0)
             if plan is not None:
-                self._decode(plan)
+                span.set(**self._decode(plan))
 
     @runs_on("worker")
-    def _decode(self, plan: StepPlan):
+    def _decode(self, plan: StepPlan) -> dict:
         """The device half of `step()` and its commit.  `decode_seconds`
         gains the dispatch and the wait for its tokens, the spans
-        `repro.engine.dispatch` and `repro.engine.sync`."""
+        `repro.engine.dispatch` and `repro.engine.sync`.  Returns the
+        step span's MoE attrs (commit_step)."""
         tel = self.telemetry
         if plan.chunk > 1:
             with tel.span("repro.engine.dispatch",
@@ -1247,7 +1302,7 @@ class ServingEngine:
             self.commit_chunk(plan, blk, flags, tok_f,
                               disp.seconds + sync.seconds, scores=scores,
                               bound=bound)
-            return
+            return {}
         scores = due = None
         with tel.span("repro.engine.dispatch",
                       live_pages=plan.live_pages) as disp:
@@ -1268,7 +1323,7 @@ class ServingEngine:
                     plan.donor, plan.live_pages)
         with tel.span("repro.engine.sync") as sync:
             next_host = np.array(next_tok, np.int32)
-        self.commit_step(plan, next_host, disp.seconds + sync.seconds)
+        attrs = self.commit_step(plan, next_host, disp.seconds + sync.seconds)
         if self.dsg_rt is not None:
             # host pattern bookkeeping lags the device step (the paged
             # page-table split): retire first, then rewrite due lanes
@@ -1278,6 +1333,7 @@ class ServingEngine:
                     self.dsg_rt.reset_lane(i)
             if scores is not None:
                 self.dsg_rt.update_from_scores(scores, due)
+        return attrs
 
     # -- fault containment (called by serving/router.py failover) ------------
     #

@@ -4,12 +4,14 @@ Interpret mode (every other kernel test) runs the kernel bodies on the
 CPU and accepts BlockSpecs and layouts that the chip's compiler refuses.
 These tests lower each kernel at internlm2-1.8b widths (d=2048, 16 heads
 over 8 KV heads of 128, d_ff=8192, 8 decode lanes, 16-token pages over a
-1024-token window) for one chip of a `v5e:2x2` topology that is described,
+1024-token window), and the expert kernel at deepseek-moe-16b's (8 held
+experts of 1408), for one chip of a `v5e:2x2` topology that is described,
 not attached, and assert that the compiled program holds the Mosaic
 kernel (`tpu_custom_call`).  Nothing runs, so they say nothing about
-results or times.  One more compiles the serving engine's whole decode
-step at those widths over two layers and pins that the paged KV pools
-are updated in place: no pool-sized copy, slice or write-back.
+results or times.  Two more compile the serving engine's whole decode
+step at those widths, over two layers and over deepseek's dense layer and
+two MoE layers, and pin that the paged KV pools are updated in place: no
+pool-sized copy, slice or write-back.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and test collection
@@ -24,7 +26,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
-from repro.kernels import drs_search, dsg_ffn, paged_attention
+from repro.kernels import (drs_search, dsg_ffn, moe_experts, ops,
+                           paged_attention)
 from repro.models import api
 from repro.serving.kv_cache import CacheHandle
 from repro.serving.scheduler import make_decode_fns
@@ -76,6 +79,18 @@ def test_paged_decode_compiles(one_chip):
              ((), jnp.int32))
 
 
+def _pool_moves(compiled, layer_pool, pool):
+    """Lines of a compiled program that copy, slice or update one layer's
+    pool or the stack, by opcode or by a fusion named for one."""
+    shapes = "|".join(",".join(map(str, s)) for s in (layer_pool, pool))
+    moves = "copy|dynamic-slice|dynamic-update-slice"
+    pool_op = re.compile(
+        r"= bf16\[(%s)\]\S* (%s)\(|%%\S*(%s)\S* = \(?bf16\[(%s)\]"
+        % (shapes, moves, moves, shapes))
+    return [ln for ln in compiled.as_text().splitlines()
+            if pool_op.search(ln)]
+
+
 def test_decode_step_updates_pools_in_place(one_chip, monkeypatch):
     """The engine's jitted greedy decode step (`_decode_greedy`) over two
     layers: the kernel writes the pools the layer scan carries, so the
@@ -109,14 +124,7 @@ def test_decode_step_updates_pools_in_place(one_chip, monkeypatch):
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
     assert "%paged_decode" in text
-    # a copy, slice or update by its opcode, or a fusion named for one,
-    # whose result is one layer's pool or the stack
-    shapes = "|".join(",".join(map(str, s)) for s in (layer_pool, pool))
-    moves = "copy|dynamic-slice|dynamic-update-slice"
-    pool_op = re.compile(
-        r"= bf16\[(%s)\]\S* (%s)\(|%%\S*(%s)\S* = \(?bf16\[(%s)\]"
-        % (shapes, moves, moves, shapes))
-    assert not [ln for ln in text.splitlines() if pool_op.search(ln)]
+    assert not _pool_moves(compiled, layer_pool, pool)
     layer_bytes = N_PAGES * PAGE * KV * D * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
@@ -152,3 +160,54 @@ def test_dsg_ffn_block_mask_compiles(one_chip):
              ((m, D_MODEL), BF16), ((D_MODEL, D_FF), BF16),
              ((D_MODEL, D_FF), BF16), ((D_FF, D_MODEL), BF16),
              ((m, D_FF // BLOCK), jnp.float32))
+
+
+@pytest.mark.parametrize("rows", [320, 896])        # decode, a prefill
+def test_moe_experts_compiles(one_chip, rows):
+    """The grouped expert product at deepseek-moe-16b widths: 8 held
+    experts of 1408, the rows of 32 decode lanes or of a 128-token prompt
+    at 6 experts a token."""
+    e, f = 8, 1408
+    _compile("moe_experts", lambda x, st, sz, wg, wu, wd:
+             moe_experts.moe_experts(x, st, sz, wg, wu, wd), one_chip,
+             ((rows, D_MODEL), BF16), ((e,), jnp.int32), ((e,), jnp.int32),
+             ((e, D_MODEL, f), BF16), ((e, D_MODEL, f), BF16),
+             ((e, f, D_MODEL), BF16))
+
+
+def test_moe_decode_step_updates_pools_in_place(one_chip, monkeypatch):
+    """deepseek-moe-16b's greedy decode step at its widths over its dense
+    layer and two MoE layers of 8 held experts: both layer scans carry the
+    pools, the expert layer runs the `moe_experts` kernel, and no pool is
+    copied, sliced or written back."""
+    monkeypatch.setenv("REPRO_INTERPRET", "0")     # the kernels, compiled
+    monkeypatch.setattr(moe_experts, "grouped_swiglu", ops.moe_experts)
+    base = configs.get_config("deepseek-moe-16b")
+    cfg = base.replace(n_layers=3, moe_experts_held=8,
+                       paged_attn_kernel="kernel",
+                       dsg=base.dsg._replace(enabled=False))
+    put = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), t)
+    params = put(jax.eval_shape(lambda k: api.init_model(k, cfg),
+                                jax.random.PRNGKey(0)))
+    kv = cfg.n_kv
+    layer_pool = (N_PAGES, PAGE, kv, D)
+    pool = (cfg.n_layers,) + layer_pool
+    handle = CacheHandle(put({"pages_k": jax.ShapeDtypeStruct(pool, BF16),
+                              "pages_v": jax.ShapeDtypeStruct(pool, BF16),
+                              "page_table": jax.ShapeDtypeStruct(
+                                  (B, MAX_PAGES), jnp.int32)}),
+                         "paged", PAGE)
+    tok, pos, free, donor = put((jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                                 jax.ShapeDtypeStruct((B,), jnp.int32),
+                                 jax.ShapeDtypeStruct((B,), jnp.bool_),
+                                 jax.ShapeDtypeStruct((), jnp.int32)))
+    step = jax.jit(make_decode_fns(cfg)[0], donate_argnums=(3,),
+                   static_argnums=(7,))
+    compiled = step.lower(params, None, tok, handle, pos, free, donor,
+                          MAX_PAGES).compile()
+    text = compiled.as_text()
+    assert "%paged_decode" in text and "%moe_experts" in text
+    assert not _pool_moves(compiled, layer_pool, pool)
+    layer_bytes = N_PAGES * PAGE * kv * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
