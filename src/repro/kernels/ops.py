@@ -93,13 +93,15 @@ def paged_decode_attention(q, k_new, v_new, k_pages, v_pages, page_table,
                            pos, layer, window: int = 0, num_pages: int = 0):
     """Fused paged decode step (kernels/paged_attention.py): scatter the
     new token's K/V through the page table into layer `layer` of the
-    stacked pools, walk only the pages at or below each lane's `pos`,
-    flash-decode online softmax.
+    stacked pools, walk each lane's pages at or below its `pos` in
+    blocks of whole pages (`paged_attention.pages_per_block`), flash-
+    decode online softmax.
 
     q (B, H, D), k_new/v_new (B, Kv, D), k_pages/v_pages
     (L, P, ps, Kv, D), page_table (B, max_pages), pos (B,), layer ()
     -> (o (B, H, D), k_pages', v_pages').  `num_pages` statically bounds
-    the walk (0 = all); it must exceed max(pos) // page_size."""
+    the walk (0 = all); it must exceed max(pos) // page_size.  A step
+    costs the lanes' live blocks, not the bound."""
     return paged_attention.paged_decode(
         q, k_new, v_new, k_pages, v_pages, page_table, pos, layer,
         window=window, num_pages=num_pages, interpret=_interpret())

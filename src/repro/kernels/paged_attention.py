@@ -1,5 +1,5 @@
-"""Paged-attention decode (Pallas TPU): fused page-table scatter +
-depth-bounded page walk + flash-decode online softmax.
+"""Paged-attention decode (Pallas TPU): fused page-table scatter + a walk
+over blocks of each lane's live pages + flash-decode online softmax.
 
 Motivation (ROADMAP "Pallas gather kernel for decode"): the XLA paged
 decode path gathers the full `max_pages * page_size` logical window
@@ -7,7 +7,8 @@ through the page table every step, so a lane 40 tokens deep still
 streams the worst-case window from HBM.  The DSG discipline — the
 executor must read *only* the activated subset — applies to the serving
 memory plane too: per decode step, a lane's live state is exactly the
-pages at or below `pos // page_size`.  This kernel walks only those.
+pages at or below `pos // page_size`.  This kernel walks only those, and
+spends no work on the pages past them.
 
 Layout (serving/kv_cache.py PagedBackend, every layer's pool):
 
@@ -19,49 +20,45 @@ Layout (serving/kv_cache.py PagedBackend, every layer's pool):
                                                   absolute position)
 
 The kernel addresses layer `layer` of the stacked pools in place: the
-layer index rides as scalar prefetch beside the page table, every pool
-block index map leads with it, and the stacked pools alias the pool
-outputs.  The model's layer scan carries the stacks and hands the
-kernel its step's layer index, so no layer's pool is sliced out of the
-stack or written back into it; the only pool traffic is the walk's
-reads and one page written per lane.  A single layer's pool is the
-L = 1 case (`pool[None]`, layer 0).
+pools stay in HBM (`memory_space=pl.ANY`), alias the pool outputs, and
+every copy in or out names `pool.at[layer, page]`.  The model's layer
+scan carries the stacks and hands the kernel its step's layer index, so
+no layer's pool is sliced out of the stack or written back into it; the
+only pool traffic is the walk's reads and one row written per lane.  A
+single layer's pool is the L = 1 case (`pool[None]`, layer 0).
 
-Grid: (B, n_pages), page index innermost so the per-lane flash
-accumulators carry across the page walk in VMEM scratch.  Each grid cell
-takes one whole physical page, every KV head of it: the (ps, Kv, D)
-block keeps the pool's last two dims whole, which is what the TPU
-compiler asks of a block (last two dims divisible by (8, 128) or equal
-to the array's).  The page's rows flatten to (ps * Kv, D) and all H
-query heads score against them in one matmul; a static head mask keeps
-each query head on its own KV head.  The page table and per-lane depths
-ride as scalar prefetch, so BlockSpec index maps resolve
-logical->physical page ids before each block fetch:
+Grid: (B,), one step per lane.  Inside it a loop walks the lane's live
+pages in blocks of `pages_per_block` whole pages (about 128 tokens; the
+count comes from the pool's shapes and a VMEM budget, clamped to the
+page table), so the loop's trip count is the lane's own depth,
+`cdiv(pos // ps + 1, pages_per_block)`: no iteration is spent past it.
+Each page of a block is one async copy from HBM, a contiguous
+(ps, Kv, D) run, into one of two VMEM slots per K and per V; the next
+block's copies (the next lane's first block, when this lane ends) start
+before this block's wait, so the fetch runs under the compute.  The
+block flattens to (pages_per_block * ps * Kv, D) rows and all H query
+heads score against them in one matmul; a static head mask keeps each
+query head on its own KV head.
 
-  * depth bounding — the K/V page index map clamps the logical page at
-    the lane's depth, `pt[b, min(j, pos[b] // ps)]`; every grid cell
-    past the depth maps to the same physical block as its predecessor,
-    and the pipeline's consecutive-identical-index elision skips the
-    copy, so pages past the lane's depth are never fetched from HBM.
-    `pl.when(j <= pos // ps)` skips their compute as well.
-  * fused scatter — the write page (logical page `pos // ps`) is copied
-    into the kernel's K/V-pool output block in VMEM and the new token's
-    row is stored at row `pos % ps` (the pools are input/output aliased;
-    the output index map pins the write page for the whole walk, so
-    exactly one page per lane is written back).  Attention reads the
-    write page from that block, so it sees the new token without a
-    separate XLA scatter pass.
+  * fused scatter — in the lane's last block, the new token's row is
+    stored at row `pos % ps` of its page (logical page `pos // ps`) in
+    VMEM before attention reads the block, so the token attends itself
+    without a separate XLA scatter pass; that one (Kv, D) row is then
+    copied to the pool in HBM.
   * masking convention — row r of logical page j holds absolute
     position t = j * ps + r; valid iff t <= pos (the new token attends
     itself, matching the dense path's `kp <= qp`) and, for sliding
     windows, t > pos - window.  The partial final page's tail (t > pos)
-    reads whatever the pool holds — junk is masked by position, exactly
-    as unwritten dense slots are.
+    and a partial block's unfetched slots hold whatever VMEM or the
+    pool held — junk is masked by position, and the masked V rows are
+    zeroed so junk never reaches the accumulator.
 
 Lanes that share a page-table row (the scheduler mirrors retired lanes
-onto a donor lane) scatter identical rows to the same physical page, so
-the duplicate write-back is order-independent — the same argument that
-makes the XLA scatter's duplicate-index semantics safe.
+onto a donor lane) write identical rows to the same physical page, so
+the order of their writes does not matter, and a mirrored lane that
+fetched the page before its donor's write inserts the same row itself —
+the same argument that makes the XLA scatter's duplicate-index semantics
+safe.
 """
 from __future__ import annotations
 
@@ -70,83 +67,162 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+BLOCK_TOKENS = 128       # tokens a block of the walk aims to hold
+VMEM_BUDGET = 8 << 20    # bytes for the blocks' slots and f32 copies
 
 
-def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, kp_ref,
-            vp_ref, hm_ref, o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr, *,
-            scale: float, ps: int, kv: int, window: int, n_pages: int):
+def pages_per_block(page_size: int, kv_heads: int, head_dim: int, dtype,
+                    max_pages: int) -> int:
+    """Whole pages per step of the page walk: about BLOCK_TOKENS tokens,
+    no more than VMEM_BUDGET holds (two slots of the block for K and for
+    V in the pool dtype, plus the f32 copies of one K and one V block),
+    and no more than the page table's `max_pages`.  It does not depend
+    on the walk bound, so every bound walks the same blocks."""
+    page = page_size * kv_heads * head_dim
+    per_page = page * (4 * jnp.dtype(dtype).itemsize + 2 * 4)
+    n = min(max(1, BLOCK_TOKENS // page_size),
+            max(1, VMEM_BUDGET // per_page))
+    return max(1, min(n, max_pages))
+
+
+def walk_blocks(pos, page_size: int, block: int) -> int:
+    """Blocks of `block` pages the kernel walks for lanes at `pos` (host
+    ints), summed over lanes: each lane walks its pages 0..pos//ps."""
+    live = np.asarray(pos) // page_size + 1
+    return int((-(-live // block)).sum())
+
+
+def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, hm_ref,
+            kp_hbm, vp_hbm, o_ref, ko_hbm, vo_hbm, kbuf, vbuf, sems,
+            wsems, slot_ref, *, scale: float, ps: int, kv: int,
+            window: int, walk: int, ppb: int):
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    layer = layer_ref[0]
+    rows = ppb * ps * kv
+    d = q_ref.shape[-1]
+
+    def last_page(lane):
+        # the lane's deepest live page, clamped to the walk: a correctly
+        # sized walk never clamps; an undersized one (caller bug) walks
+        # its first `walk` pages and writes nothing, so no page past the
+        # walk is read or written
+        return jnp.minimum(pos_ref[lane] // ps, walk - 1)
+
+    def block_copies(lane, blk, slot, wait):
+        """Start, or wait for, the copies of block `blk` of `lane` into
+        `slot`: one per live page, K and V each on the slot's
+        semaphore."""
+        first = blk * ppb
+
+        def page(i, carry):
+            phys = pt_ref[lane, first + i]
+            for c, (src, buf) in enumerate(((kp_hbm, kbuf), (vp_hbm, vbuf))):
+                cp = pltpu.make_async_copy(src.at[layer, phys],
+                                           buf.at[slot, i], sems.at[slot, c])
+                cp.wait() if wait else cp.start()
+            return carry
+
+        n = jnp.minimum(last_page(lane) - first + 1, ppb)
+        jax.lax.fori_loop(0, n, page, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        slot_ref[0] = 0
+        block_copies(0, 0, 0, wait=False)
+
     pos = pos_ref[b]
-    lp = pos // ps                   # lane's deepest live logical page
-    off = pos % ps                   # new token's row in that page
-    # write page clamped to the walk: with a correctly sized walk wp == lp;
-    # an undersized walk (caller bug) degrades to an identity write-back
-    # of page walk-1 instead of flushing uninitialized VMEM over live K/V
-    wp = jnp.minimum(lp, n_pages - 1)
+    lp = last_page(b)
+    nb = lp // ppb + 1
+    off = pos % ps
+    writes = pos // ps == lp          # False only past an undersized walk
+    # last attended position: the lane's own, or its walk's end
+    end = jnp.minimum(pos, (lp + 1) * ps - 1)
+    q = q_ref[0].astype(jnp.float32)                     # (H, D)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def row_writes(slot, i):
+        """The copies of the new token's K and V rows, held in page `i`
+        of block slot `slot`, to the lane's write page in the pool."""
+        phys = pt_ref[b, lp]
+        return [pltpu.make_async_copy(buf.at[slot, i, off],
+                                      dst.at[layer, phys, off], wsems.at[c])
+                for c, (buf, dst) in enumerate(((kbuf, ko_hbm),
+                                                (vbuf, vo_hbm)))]
 
-    @pl.when(j == wp)
-    def _scatter():
-        # one page write-back per lane: the output index map pins the
-        # physical write page across the whole walk
-        ko_ref[...] = kp_ref[...]
-        vo_ref[...] = vp_ref[...]
+    def body(blk, carry):
+        m_prev, l_prev, acc, slot = carry
+        nxt = 1 - slot
 
-        @pl.when(wp == lp)
+        @pl.when(blk + 1 < nb)
+        def _next_block():
+            block_copies(b, blk + 1, nxt, wait=False)
+
+        @pl.when((blk + 1 == nb) & (b + 1 < pl.num_programs(0)))
+        def _next_lane():
+            block_copies(b + 1, 0, nxt, wait=False)
+
+        block_copies(b, blk, slot, wait=True)
+
+        @pl.when((blk + 1 == nb) & writes)
         def _insert():
             # cast to the pool dtype FIRST so the stored and attended
             # values match the XLA scatter
-            # (`pool.at[pp, off].set(k_new.astype(pool.dtype))`)
-            ko_ref[0, off] = kn_ref[0].astype(ko_ref.dtype)
-            vo_ref[0, off] = vn_ref[0].astype(vo_ref.dtype)
+            # (`pool.at[pp, off].set(k_new.astype(pool.dtype))`), then
+            # write that one row back to the pool
+            i = lp - blk * ppb
+            kbuf[slot, i, off] = kn_ref[0].astype(kbuf.dtype)
+            vbuf[slot, i, off] = vn_ref[0].astype(vbuf.dtype)
+            for cp in row_writes(slot, i):
+                cp.start()
 
-    @pl.when(j <= lp)
-    def _compute():
-        # the write page is read back from the output block, which holds
-        # the new token's row; every other page comes from the pool
-        cur = j == lp
-        d = q_ref.shape[-1]
-        k_t = jnp.where(cur, ko_ref[0].astype(jnp.float32),
-                        kp_ref[0].astype(jnp.float32)).reshape(ps * kv, d)
-        v_t = jnp.where(cur, vo_ref[0].astype(jnp.float32),
-                        vp_ref[0].astype(jnp.float32)).reshape(ps * kv, d)
-        q = q_ref[0].astype(jnp.float32)                 # (H, D)
+        k_t = kbuf[slot].astype(jnp.float32).reshape(rows, d)
+        v_t = vbuf[slot].astype(jnp.float32).reshape(rows, d)
         s = jax.lax.dot_general(
             q, k_t, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (H, ps * Kv)
-        # column c holds page row c // Kv of KV head c % Kv; its absolute
-        # position j * ps + c // Kv is valid iff <= pos (and, for sliding
-        # windows, > pos - window) -- bounds on c, so no vector division
+            preferred_element_type=jnp.float32) * scale  # (H, rows)
+        # column c holds block row c // Kv of KV head c % Kv; its absolute
+        # position blk * ppb * ps + c // Kv is valid iff <= end (and, for
+        # sliding windows, > pos - window) -- bounds on c, so no vector
+        # division
+        base = blk * ppb * ps
+        hi = (end - base + 1) * kv
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = (hm_ref[...] != 0) & (col < (pos - j * ps + 1) * kv)
+        valid = (hm_ref[...] != 0) & (col < hi)
         if window > 0:
-            valid &= col >= (pos - window - j * ps + 1) * kv
+            valid &= col >= (pos - window - base + 1) * kv
         s = jnp.where(valid, s, NEG)
-        m_prev = m_scr[...]                              # (H, 1)
+        # rows past `end` hold junk (a partial page's tail, or slots no
+        # page was copied into): zero them so 0 * junk cannot reach acc
+        row = jax.lax.broadcasted_iota(jnp.int32, v_t.shape, 0)
+        v_t = jnp.where(row < hi, v_t, 0.0)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         p = jnp.where(valid, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
             p, v_t, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        return m_new, l_new, acc, nxt
 
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-20)
-                    ).astype(o_ref.dtype)
+    h = q.shape[0]
+    init = (jnp.full((h, 1), NEG, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, d), jnp.float32), slot_ref[0])
+    _, l_run, acc, slot = jax.lax.fori_loop(0, nb, body, init)
+    slot_ref[0] = slot
+
+    @pl.when(writes)
+    def _wait_writes():
+        # the last block sat in the slot before `slot`
+        for cp in row_writes(1 - slot, lp - (nb - 1) * ppb):
+            cp.wait()
+
+    o_ref[0] = (acc / jnp.maximum(l_run, 1e-20)).astype(o_ref.dtype)
 
 
 def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
@@ -165,16 +241,19 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     scattered into layer `layer` of the pools; every other layer's
     pages come back untouched (the pools are aliased in place).
 
-    num_pages statically bounds the page walk (the serving scheduler
-    passes its bucketed live-page bound so the grid shrinks with actual
-    batch depth); it must cover every lane: num_pages > max(pos) // ps.
-    An undersized bound cannot corrupt the pools (the write-back page is
-    clamped into the walk, degrading to an identity rewrite) but the
-    truncated window yields wrong attention output and the new token is
-    not persisted — the bound is the caller's contract.  Every logical
-    page 0..pos//ps of each lane must be mapped in the page table (the
-    backend's `ensure` guarantees this for live lanes; retired lanes
-    must be mirrored onto a live donor row).
+    Each lane walks its own live pages, 0..pos//ps, in blocks of
+    `pages_per_block` pages (from ps, Kv * D, the pool dtype, a VMEM
+    budget and the table width); a step costs the lanes' live blocks,
+    whatever `num_pages` is.  num_pages statically bounds the walk (the
+    serving scheduler passes its bucketed live-page bound) and must
+    cover every lane: num_pages > max(pos) // ps.  A
+    lane past an undersized bound cannot corrupt the pools (it reads
+    its first num_pages pages and writes nothing), but its attention
+    window is truncated and the new token is not persisted — the bound
+    is the caller's contract.  Every logical page 0..pos//ps of each
+    lane must be mapped in the page table (the backend's `ensure`
+    guarantees this for live lanes; retired lanes must be mirrored onto
+    a live donor row).
 
     Softmax statistics and the score tile are f32 regardless of
     `attn_bf16_scores`: that flag is an HBM-traffic lever for the XLA
@@ -189,51 +268,37 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     g = h // kv
     max_pages = page_table.shape[1]
     walk = min(num_pages, max_pages) if num_pages else max_pages
-    # head mask: query head r attends flattened page column c iff c's KV
+    ppb = pages_per_block(ps, kv, d, k_pages.dtype, max_pages)
+    # head mask: query head r attends flattened block column c iff c's KV
     # head (c % Kv) is r's group (r // g)
-    head_mask = (jnp.arange(ps * kv)[None, :] % kv
+    head_mask = (jnp.arange(ppb * ps * kv)[None, :] % kv
                  == jnp.arange(h)[:, None] // g).astype(jnp.int32)
 
-    def page(bb, jj, pt, pos_, ly):
-        # depth-clamped physical page: cells past the lane's depth alias
-        # their predecessor's block -> the pipeline elides the fetch
-        # (pages past `pos` never leave HBM)
-        return (ly[0], pt[bb, jnp.minimum(jj, pos_[bb] // ps)], 0, 0, 0)
-
-    def write_page(bb, jj, pt, pos_, ly):
-        # write page pinned for the whole walk -> one write-back per lane,
-        # flushed when the block index changes (the walk clamp mirrors
-        # the kernel's wp, see _kernel)
-        return (ly[0], pt[bb, jnp.minimum(pos_[bb] // ps, walk - 1)],
-                0, 0, 0)
-
-    lane = lambda bb, jj, pt, pos_, ly: (bb, 0, 0)
-    page_block = (None, 1, ps, kv, d)
+    lane = lambda bb, pt, pos_, ly: (bb, 0, 0)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,      # page_table, pos, layer
-        grid=(b, walk),
+        grid=(b,),
         in_specs=[
             pl.BlockSpec((1, h, d), lane),
             pl.BlockSpec((1, kv, d), lane),
             pl.BlockSpec((1, kv, d), lane),
-            pl.BlockSpec(page_block, page),
-            pl.BlockSpec(page_block, page),
-            pl.BlockSpec((h, ps * kv), lambda bb, jj, pt, pos_, ly: (0, 0)),
+            pl.BlockSpec((h, ppb * ps * kv), lambda bb, pt, pos_, ly: (0, 0)),
+            hbm,
+            hbm,
         ],
-        out_specs=[
-            pl.BlockSpec((1, h, d), lane),
-            pl.BlockSpec(page_block, write_page),
-            pl.BlockSpec(page_block, write_page),
-        ],
+        out_specs=[pl.BlockSpec((1, h, d), lane), hbm, hbm],
         scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),    # running max
-            pltpu.VMEM((h, 1), jnp.float32),    # running sum
-            pltpu.VMEM((h, d), jnp.float32),    # output accumulator
+            pltpu.VMEM((2, ppb, ps, kv, d), k_pages.dtype),  # K slots
+            pltpu.VMEM((2, ppb, ps, kv, d), v_pages.dtype),  # V slots
+            pltpu.SemaphoreType.DMA((2, 2)),    # [slot, K/V] block reads
+            pltpu.SemaphoreType.DMA((2,)),      # [K/V] row writes
+            pltpu.SMEM((1,), jnp.int32),        # slot of the lane's block 0
         ],
     )
     o, kp, vp = pl.pallas_call(
         functools.partial(_kernel, scale=1.0 / math.sqrt(d), ps=ps, kv=kv,
-                          window=window, n_pages=walk),
+                          window=window, walk=walk, ppb=ppb),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, h, d), q.dtype),
@@ -241,11 +306,15 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ],
         # flat operand indices include the 3 scalar-prefetch args:
-        # 6 = k_pages, 7 = v_pages alias pool outputs 1, 2 (in-place)
-        input_output_aliases={6: 1, 7: 2},
+        # 7 = k_pages, 8 = v_pages alias pool outputs 1, 2 (in-place)
+        input_output_aliases={7: 1, 8: 2},
+        # lanes run in order: each lane's walk starts on the block the
+        # previous lane prefetched
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         name="paged_decode",
         interpret=interpret,
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
       jnp.reshape(layer, (1,)).astype(jnp.int32),
-      q, k_new, v_new, k_pages, v_pages, head_mask)
+      q, k_new, v_new, head_mask, k_pages, v_pages)
     return o, kp, vp

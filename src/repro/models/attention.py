@@ -208,8 +208,10 @@ def self_attention(p: dict, x: jax.Array, *, n_heads: int, n_kv: int,
     place at `layer`:
 
       * Pallas kernel (kernels/paged_attention.py): fused scatter +
-        depth-bounded page walk + flash decode — per lane, only pages at
-        or below `cache_pos` are read from HBM.
+        a per-lane walk over blocks of whole pages + flash decode — each
+        lane reads and spends work on only its pages at or below
+        `cache_pos`, so `live_pages` bounds the page table the kernel
+        may address, not its cost.
       * XLA fallback: scatter through the page table, then gather the
         leading `live_pages` pages (a static bound the scheduler sizes
         to the deepest live lane, bucketed to limit recompiles) —

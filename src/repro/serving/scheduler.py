@@ -47,7 +47,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.contracts import exempt, owned_by, runs_on
+from repro.kernels import paged_attention
 from repro.models import api
+from repro.models.attention import _use_paged_kernel
 from repro.serving import dsg_runtime, kv_cache, telemetry
 from repro.serving.kv_cache import CacheHandle
 
@@ -910,6 +912,20 @@ class ServingEngine:
         return live_page_bound(deepest, self.cache.page_size,
                                self.max_seq // self.cache.page_size)
 
+    def _kv_blocks(self, plan: StepPlan) -> int:
+        """Blocks of pages the paged decode kernel walks in one layer of
+        this step, summed over lanes (kernels/paged_attention.py), from
+        the lanes' depths and the kernel's own block size; 0 where no
+        kernel walks pages.  A fused chunk counts its first micro-step."""
+        if self.cache.kind != "paged" or not _use_paged_kernel(
+                self.cfg.paged_attn_kernel):
+            return 0
+        pool = self.cache.data["pages_k"]
+        _, _, ps, kv, d = pool.shape
+        block = paged_attention.pages_per_block(
+            ps, kv, d, pool.dtype, self.cache.data["page_table"].shape[1])
+        return paged_attention.walk_blocks(plan.pos, ps, block)
+
     @runs_on("worker")
     def warm_decode(self, sample: bool = False):
         """Pre-compile the jitted decode step for every static live-page
@@ -1294,7 +1310,8 @@ class ServingEngine:
         tel = self.telemetry
         if plan.chunk > 1:
             with tel.span("repro.engine.dispatch",
-                          live_pages=plan.live_pages) as disp:
+                          live_pages=plan.live_pages,
+                          kv_blocks=self._kv_blocks(plan)) as disp:
                 blk, flags, tok_f, scores, bound = self._dispatch_chunk(plan)
             with tel.span("repro.engine.sync") as sync:
                 blk, flags = np.asarray(blk), np.asarray(flags)
@@ -1305,7 +1322,8 @@ class ServingEngine:
             return {}
         scores = due = None
         with tel.span("repro.engine.dispatch",
-                      live_pages=plan.live_pages) as disp:
+                      live_pages=plan.live_pages,
+                      kv_blocks=self._kv_blocks(plan)) as disp:
             # PRNG keys depend only on (engine seed, step, lane), so mixing
             # greedy-only and sampling steps never shifts the key schedule
             if self.dsg_rt is not None:

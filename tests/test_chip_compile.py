@@ -4,11 +4,12 @@ Interpret mode (every other kernel test) runs the kernel bodies on the
 CPU and accepts BlockSpecs and layouts that the chip's compiler refuses.
 These tests lower each kernel at internlm2-1.8b widths (d=2048, 16 heads
 over 8 KV heads of 128, d_ff=8192, 8 decode lanes, 16-token pages over a
-1024-token window), and the expert kernel at deepseek-moe-16b's (8 held
-experts of 1408), for one chip of a `v5e:2x2` topology that is described,
-not attached, and assert that the compiled program holds the Mosaic
-kernel (`tpu_custom_call`).  Nothing runs, so they say nothing about
-results or times.  Two more compile the serving engine's whole decode
+1024-token window), the paged decode kernel also at deepseek-moe-16b's
+attention (16 heads over 16 KV heads, a 640-token window), and the
+expert kernel at deepseek-moe-16b's (8 held experts of 1408), for one
+chip of a `v5e:2x2` topology that is described, not attached, and assert
+that the compiled program holds the Mosaic kernel (`tpu_custom_call`).
+Nothing runs, so they say nothing about results or times.  Two more compile the serving engine's whole decode
 step at those widths, over two layers and over deepseek's dense layer and
 two MoE layers, and pin that the paged KV pools are updated in place: no
 pool-sized copy, slice or write-back.
@@ -68,14 +69,17 @@ def _compile(name, fn, sharding, *shapes):
     assert f"%{name}" in text
 
 
-def test_paged_decode_compiles(one_chip):
+# (heads, KV heads, page-table width): internlm2-1.8b's GQA over a
+# 1024-token window, deepseek-moe-16b's MHA over 640
+@pytest.mark.parametrize("h,kv,max_pages", [(H, KV, MAX_PAGES), (16, 16, 40)])
+def test_paged_decode_compiles(one_chip, h, kv, max_pages):
     def step(q, kn, vn, kp, vp, pt, pos, layer):
         return paged_attention.paged_decode(q, kn, vn, kp, vp, pt, pos,
-                                            layer, num_pages=MAX_PAGES)
-    pool = ((2, N_PAGES, PAGE, KV, D), BF16)
+                                            layer, num_pages=max_pages)
+    pool = ((2, B * max_pages + 1, PAGE, kv, D), BF16)
     _compile("paged_decode", step, one_chip,
-             ((B, H, D), BF16), ((B, KV, D), BF16), ((B, KV, D), BF16),
-             pool, pool, ((B, MAX_PAGES), jnp.int32), ((B,), jnp.int32),
+             ((B, h, D), BF16), ((B, kv, D), BF16), ((B, kv, D), BF16),
+             pool, pool, ((B, max_pages), jnp.int32), ((B,), jnp.int32),
              ((), jnp.int32))
 
 

@@ -75,7 +75,7 @@ def _assert_other_layers_untouched(before, after, layer):
 @pytest.mark.parametrize("n_layers,layer", LAYERS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("ps", [8, 16])
-@pytest.mark.parametrize("h,kv", [(4, 2), (2, 2)])
+@pytest.mark.parametrize("h,kv", [(4, 2), (2, 2), (16, 8), (16, 16), (4, 1)])
 def test_kernel_matches_oracle(dtype, ps, h, kv, n_layers, layer):
     # ragged depths: page-boundary cases (0, ps-1, ps) + partial pages
     pos = [0, ps - 1, ps, 2 * ps + 3, 5 * ps - 1]
@@ -104,6 +104,73 @@ def test_kernel_bounded_walk_and_window():
     ww, _, _ = ref.paged_decode_ref(*_layer_args(args, 0), window=10)
     np.testing.assert_allclose(np.asarray(w), np.asarray(ww),
                                **TOL[jnp.float32])
+
+
+def _edge_case(case, ps, blk):
+    """(lane depths, table width, walk bound, window) of a walk-edge case;
+    `blk` tokens make one block of the walk."""
+    return {
+        # depths at block edges: the first token, the last token of the
+        # first block, the first of the second, 3 blocks and a partial page
+        "block_edges": ([0, blk - 1, blk, 3 * blk + ps + 3], 64, 0, 0),
+        # one deep lane beside lanes on their first page
+        "deep_beside_shallow": ([0, 3 * blk + 5, ps - 1, 2], 64, 0, 0),
+        # lanes 0 and 1 share a page-table row (a mirrored free lane)
+        "mirrored": ([blk + 2 * ps + 1, 0, ps + 4, 2 * blk], 64, 0, 0),
+        # a table and a walk bound far above every depth
+        "wide_table": ([3, ps + 1, 2 * ps], 64, 64, 0),
+        # a window whose first position lies inside a block
+        "window_mid_block": ([2 * blk + 5, blk + 3, 7, 3 * blk], 64, 0,
+                             blk // 2 + 3),
+    }[case]
+
+
+@pytest.mark.parametrize("case", ["block_edges", "deep_beside_shallow",
+                                  "mirrored", "wide_table",
+                                  "window_mid_block"])
+def test_kernel_walk_edges(case):
+    """The block walk at its edges, f32 pools in a 3-layer stack: output
+    against the oracle, the addressed layer's pools bit for bit, every
+    other layer untouched."""
+    ps, h, kv, d, layer = 8, 4, 2, 16, 1
+    ppb = paged_attention.pages_per_block(ps, kv, d, jnp.float32, 64)
+    pos, width, walk, window = _edge_case(case, ps, ppb * ps)
+    args = _paged_setup(11, len(pos), h, kv, d, ps, width, pos,
+                        n_layers=3)
+    q, k_new, v_new, k_pages, v_pages, table, cp = args
+    if case == "mirrored":
+        # lane 1 mirrors lane 0: its row, depth, query and new K/V
+        cp = cp.at[1].set(cp[0])
+        table = table.at[1].set(table[0])
+        q, k_new, v_new = (a.at[1].set(a[0]) for a in (q, k_new, v_new))
+        args = (q, k_new, v_new, k_pages, v_pages, table, cp)
+    o, kp, vp = paged_attention.paged_decode(*args, layer, window=window,
+                                             num_pages=walk, interpret=True)
+    ow, kw, vw = ref.paged_decode_ref(*_layer_args(args, layer),
+                                      window=window)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ow),
+                               **TOL[jnp.float32])
+    np.testing.assert_array_equal(np.asarray(kp[layer]), np.asarray(kw))
+    np.testing.assert_array_equal(np.asarray(vp[layer]), np.asarray(vw))
+    _assert_other_layers_untouched(k_pages, kp, layer)
+    _assert_other_layers_untouched(v_pages, vp, layer)
+
+
+@pytest.mark.parametrize("ps,kv,d,dtype,width,want", [
+    (16, 8, 128, jnp.bfloat16, 64, 8),     # internlm2-1.8b: 128 tokens
+    (16, 16, 128, jnp.bfloat16, 40, 8),    # deepseek-moe-16b
+    (8, 2, 16, jnp.float32, 64, 16),       # the tests' pools
+    (8, 2, 16, jnp.float32, 6, 6),         # clamped to the table
+    (16, 32, 256, jnp.float32, 64, 2),     # clamped by the VMEM budget
+])
+def test_pages_per_block_from_shapes(ps, kv, d, dtype, width, want):
+    assert paged_attention.pages_per_block(ps, kv, d, dtype, width) == want
+
+
+def test_walk_blocks_counts_each_lanes_live_blocks():
+    # 1, 8, 9 and 33 live pages at 8 pages a block
+    pos = np.array([0, 127, 128, 16 * 32 + 3])
+    assert paged_attention.walk_blocks(pos, 16, 8) == 1 + 1 + 2 + 5
 
 
 def _attn_inputs(seed, b, d_model, h, kv, hd, ps, max_pages, pos,
@@ -320,5 +387,17 @@ def test_kernel_engine_stream_matches_dense(engine_parts, page_size):
                     page_size=page_size, cache_tokens=80),
         mixed_traffic(cfg), return_engine=True)
     assert_streams_equal(dense_out, kernel_out, "kernel engine vs dense")
+    # every dispatch counts the kernel's blocks: at least one a lane, at
+    # most the walk bound's worth a lane
+    pool = eng.cache.data["pages_k"]
+    _, _, ps, kv, d = pool.shape
+    width = eng.cache.data["page_table"].shape[1]
+    ppb = paged_attention.pages_per_block(ps, kv, d, pool.dtype, width)
+    disp = [s.attrs for s in eng.telemetry.spans()
+            if s.name == "repro.engine.dispatch"]
+    assert disp and all(
+        eng.n_slots <= a["kv_blocks"] <= eng.n_slots * -(-a["live_pages"]
+                                                         // ppb)
+        for a in disp)
     alloc = eng.backend.allocator
     assert alloc.free_pages == alloc.n_pages - alloc.reserved

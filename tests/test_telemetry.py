@@ -66,7 +66,8 @@ def test_span_tree(engine_parts, kind):
                  "repro.engine.commit"):
         assert len(named[name]) == len(decode)
         assert {s.parent for s in named[name]} == {s.sid for s in decode}
-    assert all("live_pages" in s.attrs for s in named["repro.engine.dispatch"])
+    assert all({"live_pages", "kv_blocks"} <= set(s.attrs)
+               for s in named["repro.engine.dispatch"])
     # one growth pass per step that has active lanes, inside begin
     assert len(named["repro.kv.grow"]) == len(decode)
     assert all(parent(s) == "repro.engine.begin"
