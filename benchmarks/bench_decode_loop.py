@@ -5,7 +5,7 @@ Two engines run the SAME mixed traffic (threshold_mode="topk", greedy):
   * chunk-1 — the historical loop: one jitted dispatch per token, the
     host syncing (device->host token copy + python bookkeeping) between
     every step.
-  * chunk-N — the fused loop (scheduler.make_chunked_decode_fns): N
+  * chunk-N — the fused loop (scheduler.make_decode_fns, chunk=N): N
     micro-steps scanned inside ONE dispatch, per-lane EOS/budget
     freezing on device, host bookkeeping lagging a chunk behind.
 
@@ -41,7 +41,7 @@ from repro.serving.workload import mixed_requests, warmup_engine
 
 def _engine(cfg, params, dsg, args, chunk):
     return ServingEngine(cfg, params, dsg, n_slots=args.slots,
-                         max_seq=args.max_seq, admission="overlap",
+                         max_seq=args.max_seq,
                          prompt_bucket=args.prompt_bucket,
                          decode_chunk=chunk)
 

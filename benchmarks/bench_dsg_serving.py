@@ -51,7 +51,7 @@ def _make_engine(cfg, params, dsg, args, apply_mode):
     vcfg = cfg.replace(dsg_ffn_apply=apply_mode)
     eng = ServingEngine(
         vcfg, params, dsg, n_slots=args.slots, max_seq=args.max_seq,
-        prompt_bucket=args.prompt_bucket, admission="overlap",
+        prompt_bucket=args.prompt_bucket,
         cache_backend=args.cache_backend, page_size=args.page_size,
         dsg_serving=DSGServingConfig(
             refresh_interval=args.refresh_interval,

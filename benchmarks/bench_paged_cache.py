@@ -52,8 +52,7 @@ def run(args) -> dict:
             prompt_range=(args.prompt_min, args.prompt_max),
             max_new_range=(args.gen_min, args.gen_max))
         st = run_workload(
-            cfg, params, dsg, reqs, admission="overlap",
-            n_slots=args.slots, max_seq=args.max_seq,
+            cfg, params, dsg, reqs, n_slots=args.slots, max_seq=args.max_seq,
             prompt_bucket=args.prompt_bucket, cache_backend=backend,
             page_size=args.page_size,
             cache_tokens=cache_tokens if backend == "paged" else None)

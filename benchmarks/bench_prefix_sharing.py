@@ -53,7 +53,7 @@ TPOT_P95_SLO_S = 0.25
 
 def _engine(cfg, params, dsg, args, sharing):
     return ServingEngine(cfg, params, dsg, n_slots=args.slots,
-                         max_seq=args.max_seq, admission="overlap",
+                         max_seq=args.max_seq,
                          prompt_bucket=args.prompt_bucket,
                          cache_backend="paged", page_size=args.page_size,
                          cache_tokens=args.cache_tokens,

@@ -16,8 +16,8 @@ differential check below asserts the merged greedy token streams agree
 token-for-token).  The reported number is the MODELED data-parallel rate:
 per-replica busy time is recorded by the executor, the makespan is the
 slowest replica's busy time (what N truly parallel replica groups would
-take), and parallel tok/s = total tokens / makespan — the same
-record-then-model discipline as bench_paged_decode's HBM-bytes gate.
+take), and parallel tok/s = total tokens / makespan: the time is
+recorded, the parallel wall clock is modeled from it.
 
 **Executor gate** (`--exec-mode threaded` / `sharded`): sequential vs
 parallel execution of the same router under round_robin (identical

@@ -207,8 +207,7 @@ def kernel_phase(cfg, sizes, seed):
     pool = (b * max_pages + 1, ps, kv, d)
     kp, vp = normal(ks[3], pool), normal(ks[4], pool)
     o, kp2, vp2 = ops.paged_decode_attention(q, kn, vn, kp[None], vp[None],
-                                             table, pos, 0,
-                                             num_pages=max_pages)
+                                             table, pos, 0)
     with jax.default_matmul_precision("highest"):
         ow, kw, vw = jax.jit(ref.paged_decode_ref)(q, kn, vn, kp, vp,
                                                    table, pos)
@@ -285,8 +284,8 @@ def lowered_kernels(cfg, params, dsg, sizes):
                "counts": sds((cfg.n_layers, b), jnp.int32)}
 
     def step(p, d, t, c, q, s):
-        return api.decode_step(p, d, cfg, t, c, q, live_pages=max_pages,
-                               ffn_csr=s, collect_drs_scores=s is not None)
+        return api.decode_step(p, d, cfg, t, c, q, ffn_csr=s,
+                               collect_drs_scores=s is not None)
 
     text = jax.jit(step).lower(abstract(params), abstract(dsg), tok, view,
                                pos, csr).as_text()

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Validate committed BENCH_*.json artifacts against the shared envelope.
 
-Every gated benchmark (benchmarks/bench_paged_decode.py, bench_router.py,
-bench_router_faults.py, bench_dsg_serving.py, bench_decode_loop.py,
-bench_prefix_sharing.py) wraps its payload in the envelope from
+Every gated benchmark (benchmarks/bench_router.py, bench_router_faults.py,
+bench_dsg_serving.py, bench_decode_loop.py, bench_prefix_sharing.py) wraps its payload in the envelope from
 benchmarks/common.py:
 
   {"name":       str,

@@ -9,10 +9,10 @@ root through calls the index can resolve repo-locally.
 
 Resolution is deliberately best-effort and syntactic: `api.decode_step`
 resolves through the module's imports, `self.method()` resolves inside
-the enclosing class, and the repo's tuple-unpack idiom
+the enclosing class, and the tuple-unpack idiom
 
-    _decode_greedy, _decode_sample = make_decode_fns(cfg)
-    self._jit_decode_greedy = jax.jit(_decode_greedy, static_argnums=(7,))
+    greedy, sample = make_decode_fns(cfg, dsg=..., chunk=..., max_seq=...)
+    self._jit_decode = (jax.jit(greedy, donate_argnums=(3,), ...), ...)
 
 resolves because the index records which nested defs a function returns.
 Anything it cannot resolve it drops silently — the checkers trade recall

@@ -71,7 +71,7 @@ class ModelConfig:
                                      # halves attention HBM traffic
     paged_attn_kernel: str = "auto"  # paged decode executor: "kernel"
                                      # (Pallas paged_attention, interpret
-                                     # on CPU), "xla" (bounded gather
+                                     # on CPU), "xla" (whole-table gather
                                      # fallback), "auto" (kernel on TPU)
     dsg_ffn_apply: str = "auto"      # group-CSR serving FFN executor:
                                      # "dense" (masked-dense reference),

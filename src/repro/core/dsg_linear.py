@@ -243,8 +243,8 @@ def swiglu_csr_masked(p: dict, x: jax.Array, idx: jax.Array,
 def swiglu_csr_gather(p: dict, x: jax.Array, idx: jax.Array,
                       counts: jax.Array, *, block: int) -> jax.Array:
     """XLA fallback: contract only the leading K = active-group bound
-    blocks per lane (the paged-attention bounded-gather trick — K is a
-    static pow2 bucket, so FLOPs scale with the bound, not F).  Per-lane
+    blocks per lane (K is a static pow2 bucket, so FLOPs scale with the
+    bound, not F).  Per-lane
     patterns force a per-lane weight-block gather (B, K, d, block); the
     CSR Pallas kernel avoids materializing it — this path is the
     non-Mosaic fallback.  Padded slots (>= counts) are zeroed before the

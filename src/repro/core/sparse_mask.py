@@ -13,10 +13,8 @@ CSR, SNIPPETS.md).
 A CSR row is (idx, count): `idx[:count]` are the active group indices in
 ascending order, entries past `count` are zero-padded and must be ignored
 (`csr_to_dense` and every consumer guard on `count`).  The row width is a
-static *bound* bucketed to a power of two — the same trick as
-`scheduler.live_page_bound` for the paged-attention walk — so the decode
-step compiles at most log2(G)+1 variants as per-lane counts drift, not one
-per count.
+static *bound* bucketed to a power of two, so the decode step compiles at
+most log2(G)+1 variants as per-lane counts drift, not one per count.
 """
 from __future__ import annotations
 
@@ -27,7 +25,7 @@ import jax.numpy as jnp
 def active_group_bound(max_count: int, n_groups: int) -> int:
     """Static CSR row width covering rows with up to `max_count` active
     groups: the count rounded up to a power of two, capped at G — decode
-    compiles ≤ log2(G)+1 variants (mirrors scheduler.live_page_bound)."""
+    compiles ≤ log2(G)+1 variants."""
     need = max(1, int(max_count))
     return min(1 << (need - 1).bit_length(), n_groups)
 

@@ -88,9 +88,9 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                               block_k=block_k, interpret=_interpret())
 
 
-@partial(jax.jit, static_argnames=("window", "num_pages"))
+@partial(jax.jit, static_argnames=("window",))
 def paged_decode_attention(q, k_new, v_new, k_pages, v_pages, page_table,
-                           pos, layer, window: int = 0, num_pages: int = 0):
+                           pos, layer, window: int = 0):
     """Fused paged decode step (kernels/paged_attention.py): scatter the
     new token's K/V through the page table into layer `layer` of the
     stacked pools, walk each lane's pages at or below its `pos` in
@@ -99,12 +99,11 @@ def paged_decode_attention(q, k_new, v_new, k_pages, v_pages, page_table,
 
     q (B, H, D), k_new/v_new (B, Kv, D), k_pages/v_pages
     (L, P, ps, Kv, D), page_table (B, max_pages), pos (B,), layer ()
-    -> (o (B, H, D), k_pages', v_pages').  `num_pages` statically bounds
-    the walk (0 = all); it must exceed max(pos) // page_size.  A step
-    costs the lanes' live blocks, not the bound."""
+    -> (o (B, H, D), k_pages', v_pages').  A step costs the lanes' live
+    blocks."""
     return paged_attention.paged_decode(
         q, k_new, v_new, k_pages, v_pages, page_table, pos, layer,
-        window=window, num_pages=num_pages, interpret=_interpret())
+        window=window, interpret=_interpret())
 
 
 @jax.jit

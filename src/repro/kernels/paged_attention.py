@@ -82,7 +82,7 @@ def pages_per_block(page_size: int, kv_heads: int, head_dim: int, dtype,
     no more than VMEM_BUDGET holds (two slots of the block for K and for
     V in the pool dtype, plus the f32 copies of one K and one V block),
     and no more than the page table's `max_pages`.  It does not depend
-    on the walk bound, so every bound walks the same blocks."""
+    on the lanes' depths, so every step walks the same blocks."""
     page = page_size * kv_heads * head_dim
     per_page = page * (4 * jnp.dtype(dtype).itemsize + 2 * 4)
     n = min(max(1, BLOCK_TOKENS // page_size),
@@ -100,18 +100,18 @@ def walk_blocks(pos, page_size: int, block: int) -> int:
 def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, hm_ref,
             kp_hbm, vp_hbm, o_ref, ko_hbm, vo_hbm, kbuf, vbuf, sems,
             wsems, slot_ref, *, scale: float, ps: int, kv: int,
-            window: int, walk: int, ppb: int):
+            window: int, width: int, ppb: int):
     b = pl.program_id(0)
     layer = layer_ref[0]
     rows = ppb * ps * kv
     d = q_ref.shape[-1]
 
     def last_page(lane):
-        # the lane's deepest live page, clamped to the walk: a correctly
-        # sized walk never clamps; an undersized one (caller bug) walks
-        # its first `walk` pages and writes nothing, so no page past the
-        # walk is read or written
-        return jnp.minimum(pos_ref[lane] // ps, walk - 1)
+        # the lane's deepest live page, clamped to the page table's
+        # width: a lane inside the table never clamps; one past it
+        # (caller bug) walks the whole table and writes nothing, so no
+        # page outside the table is read or written
+        return jnp.minimum(pos_ref[lane] // ps, width - 1)
 
     def block_copies(lane, blk, slot, wait):
         """Start, or wait for, the copies of block `blk` of `lane` into
@@ -139,8 +139,8 @@ def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, hm_ref,
     lp = last_page(b)
     nb = lp // ppb + 1
     off = pos % ps
-    writes = pos // ps == lp          # False only past an undersized walk
-    # last attended position: the lane's own, or its walk's end
+    writes = pos // ps == lp          # False only past the table's end
+    # last attended position: the lane's own, or the table's end
     end = jnp.minimum(pos, (lp + 1) * ps - 1)
     q = q_ref[0].astype(jnp.float32)                     # (H, D)
 
@@ -228,8 +228,7 @@ def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, hm_ref,
 def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
                  k_pages: jax.Array, v_pages: jax.Array,
                  page_table: jax.Array, pos: jax.Array, layer, *,
-                 window: int = 0, num_pages: int = 0,
-                 interpret: bool = False):
+                 window: int = 0, interpret: bool = False):
     """One fused decode step over the paged KV layout.
 
     q (B, H, D) — the step's queries (RoPE already applied);
@@ -244,16 +243,12 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     Each lane walks its own live pages, 0..pos//ps, in blocks of
     `pages_per_block` pages (from ps, Kv * D, the pool dtype, a VMEM
     budget and the table width); a step costs the lanes' live blocks,
-    whatever `num_pages` is.  num_pages statically bounds the walk (the
-    serving scheduler passes its bucketed live-page bound) and must
-    cover every lane: num_pages > max(pos) // ps.  A
-    lane past an undersized bound cannot corrupt the pools (it reads
-    its first num_pages pages and writes nothing), but its attention
-    window is truncated and the new token is not persisted — the bound
-    is the caller's contract.  Every logical page 0..pos//ps of each
-    lane must be mapped in the page table (the backend's `ensure`
-    guarantees this for live lanes; retired lanes must be mirrored onto
-    a live donor row).
+    and no static bound is needed.  The walk is clamped to the page
+    table's width: a lane past it (caller bug) reads the whole table
+    and writes nothing, so it cannot corrupt the pools.  Every logical
+    page 0..pos//ps of each lane must be mapped in the page table (the
+    backend's `ensure` guarantees this for live lanes; retired lanes
+    must be mirrored onto a live donor row).
 
     Softmax statistics and the score tile are f32 regardless of
     `attn_bf16_scores`: that flag is an HBM-traffic lever for the XLA
@@ -267,7 +262,6 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     assert h % kv == 0, f"H={h} not a multiple of Kv={kv}"
     g = h // kv
     max_pages = page_table.shape[1]
-    walk = min(num_pages, max_pages) if num_pages else max_pages
     ppb = pages_per_block(ps, kv, d, k_pages.dtype, max_pages)
     # head mask: query head r attends flattened block column c iff c's KV
     # head (c % Kv) is r's group (r // g)
@@ -298,7 +292,7 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     )
     o, kp, vp = pl.pallas_call(
         functools.partial(_kernel, scale=1.0 / math.sqrt(d), ps=ps, kv=kv,
-                          window=window, walk=walk, ppb=ppb),
+                          window=window, width=max_pages, ppb=ppb),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, h, d), q.dtype),

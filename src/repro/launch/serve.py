@@ -31,7 +31,7 @@ more memory than they save, so the search stays online).  Two workloads:
   PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
       --smoke --batch 4 --prompt-len 32 --gen 16
   PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
-      --smoke --workload mixed --requests 16 --slots 4 --admission overlap
+      --smoke --workload mixed --requests 16 --slots 4
 """
 from __future__ import annotations
 
@@ -117,11 +117,9 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=384)
     ap.add_argument("--prompt-bucket", type=int, default=256)
-    ap.add_argument("--admission", choices=("overlap", "wave"),
-                    default="overlap")
     ap.add_argument("--decode-chunk", type=int, default=1,
                     help="decode steps fused into one device dispatch "
-                         "(scheduler.make_chunked_decode_fns): EOS / "
+                         "(scheduler.make_decode_fns): EOS / "
                          "budget freezing stays on device and the host "
                          "syncs once per chunk instead of per token; "
                          "temperature-0 streams are bitwise-identical "
@@ -255,8 +253,7 @@ def main():
         if args.deadline_s is not None:
             for r in reqs:
                 r.deadline_s = args.deadline_s
-        stats = run_workload(cfg, params, dsg, reqs,
-                             admission=args.admission, n_slots=args.slots,
+        stats = run_workload(cfg, params, dsg, reqs, n_slots=args.slots,
                              max_seq=args.max_seq,
                              prompt_bucket=args.prompt_bucket,
                              cache_backend=args.cache_backend,
@@ -270,7 +267,7 @@ def main():
                              decode_chunk=args.decode_chunk,
                              prefix_sharing=args.prefix_sharing,
                              seed=args.seed)
-        tag = f"{stats['admission']}/{stats['cache_backend']}"
+        tag = stats["cache_backend"]
         if stats.get("prefix_sharing"):
             tag += "/shared"
         if stats["decode_chunk"] > 1:

@@ -129,13 +129,11 @@ def prefill(params, dsg, cfg: ModelConfig, inputs: dict, cache,
 
 
 def decode_step(params, dsg, cfg: ModelConfig, token, state, pos,
-                live_pages=None, mesh=None, batch_axes=None,
-                ffn_csr=None, collect_drs_scores: bool = False,
-                moe_count=None):
+                mesh=None, batch_axes=None, ffn_csr=None,
+                collect_drs_scores: bool = False, moe_count=None):
     if cfg.family in DECODER_FAMILIES:
         return transformer.decode_step(params, dsg, cfg, token, state, pos,
-                                       live_pages=live_pages, mesh=mesh,
-                                       batch_axes=batch_axes,
+                                       mesh=mesh, batch_axes=batch_axes,
                                        ffn_csr=ffn_csr,
                                        collect_drs_scores=collect_drs_scores,
                                        moe_count=moe_count)
@@ -154,43 +152,6 @@ def decode_step(params, dsg, cfg: ModelConfig, token, state, pos,
                                              state=state, pos0=pos)
         return logits[:, -1], st
     raise ValueError(cfg.family)
-
-
-# ---------------------------------------------------------------------------
-# per-slot cache surgery — DEPRECATED thin wrappers
-# ---------------------------------------------------------------------------
-#
-# The engine-facing cache surface now lives in repro.serving.kv_cache: a
-# pluggable KVCacheBackend ("dense" | "paged") builds and mutates an opaque
-# CacheHandle pytree (make / write / ensure / free / view_for_attention),
-# and the serving scheduler drives that protocol instead of these helpers.
-# They predate the backend API and are kept as thin wrappers for callers
-# that still hold raw dense cache dicts; they assume every cache leaf
-# carries the batch on axis 1 (L, B, ...), which holds for transformer and
-# encdec caches.
-
-def make_slot_cache(cfg: ModelConfig, max_seq: int, dtype=None):
-    """Deprecated: a 1-lane dense cache for solo prompt prefill.  Same as
-    ``make_cache(cfg, 1, max_seq)``; new code should build caches through a
-    serving.kv_cache backend."""
-    return make_cache(cfg, 1, max_seq, dtype)
-
-
-def prefill_slot(params, dsg, cfg: ModelConfig, tokens, lane_cache,
-                 mesh=None, batch_axes=None):
-    """Deprecated: prefill a single prompt lane.  tokens (1, P) int32 ->
-    (last_logits (1, V), filled 1-lane cache).  Same as ``prefill`` with a
-    ``{"tokens": ...}`` batch."""
-    return prefill(params, dsg, cfg, {"tokens": tokens}, lane_cache,
-                   mesh=mesh, batch_axes=batch_axes)
-
-
-def merge_slot_cache(cache, lane_cache, slot):
-    """Deprecated: scatter a 1-lane cache into lane `slot` of a batched
-    dense cache.  Delegates to serving.kv_cache.dense_merge (the
-    DenseBackend write primitive)."""
-    from repro.serving.kv_cache import dense_merge
-    return dense_merge(cache, lane_cache, slot)
 
 
 # ---------------------------------------------------------------------------

@@ -168,7 +168,7 @@ CHUNK_THRESHOLD = 1 << 24   # S*T above which the chunked path is used
 
 def _use_paged_kernel(mode: str) -> bool:
     """Resolve the paged decode executor: "kernel" forces the Pallas
-    kernel (interpret mode on CPU), "xla" forces the bounded-gather
+    kernel (interpret mode on CPU), "xla" forces the whole-table gather
     fallback, "auto" picks the kernel only where Mosaic compiles it."""
     if mode == "auto":
         return jax.default_backend() == "tpu"
@@ -184,7 +184,6 @@ def self_attention(p: dict, x: jax.Array, *, n_heads: int, n_kv: int,
                    cache_pos: Optional[jax.Array] = None,
                    cache_kv_pos: Optional[jax.Array] = None,
                    page_table: Optional[jax.Array] = None,
-                   live_pages: Optional[int] = None,
                    layer: Optional[jax.Array] = None,
                    paged_kernel: str = "auto",
                    shard: str = "auto", bf16_scores: bool = False):
@@ -210,12 +209,9 @@ def self_attention(p: dict, x: jax.Array, *, n_heads: int, n_kv: int,
       * Pallas kernel (kernels/paged_attention.py): fused scatter +
         a per-lane walk over blocks of whole pages + flash decode — each
         lane reads and spends work on only its pages at or below
-        `cache_pos`, so `live_pages` bounds the page table the kernel
-        may address, not its cost.
-      * XLA fallback: scatter through the page table, then gather the
-        leading `live_pages` pages (a static bound the scheduler sizes
-        to the deepest live lane, bucketed to limit recompiles) —
-        non-Pallas platforms stop paying worst-case whole-window reads.
+        `cache_pos`.
+      * XLA fallback (the CPU path, and the kernel tests' reference):
+        scatter through the page table, then gather the whole table.
 
     In both, logical positions beyond a lane's depth read junk
     (unallocated rows point at the scratch page) but are masked by
@@ -247,14 +243,11 @@ def self_attention(p: dict, x: jax.Array, *, n_heads: int, n_kv: int,
             raise ValueError("paged decode needs the layer index of its "
                              "pool in the stacked pools")
         ps_sz = cache["k"].shape[2]
-        max_pages = page_table.shape[1]
-        walk = min(live_pages, max_pages) if live_pages else max_pages
         if _use_paged_kernel(paged_kernel):
             from repro.kernels import ops as kernel_ops
             o, pk, pv = kernel_ops.paged_decode_attention(
                 q[:, 0], k_new[:, 0], v_new[:, 0], cache["k"], cache["v"],
-                page_table, cache_pos, layer, window=window,
-                num_pages=walk)
+                page_table, cache_pos, layer, window=window)
             out = jnp.einsum("bshk,hkd->bsd", o[:, None], p["wo"])
             # pool sharding is deferred to the kernel's page addressing
             return out, {"k": pk, "v": pv}
@@ -265,7 +258,7 @@ def self_attention(p: dict, x: jax.Array, *, n_heads: int, n_kv: int,
             k_new[:, 0].astype(cache["k"].dtype))
         pv = cache["v"].at[layer, pp, off].set(
             v_new[:, 0].astype(cache["v"].dtype))
-        t = jnp.arange(walk * ps_sz)
+        t = jnp.arange(page_table.shape[1] * ps_sz)
         k = pk[layer, page_table[:, t // ps_sz], t % ps_sz]
         v = pv[layer, page_table[:, t // ps_sz], t % ps_sz]
         kv_pos = (cache_kv_pos[..., :t.shape[0]]

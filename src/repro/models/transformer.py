@@ -198,7 +198,7 @@ def _drs_scores(h: jax.Array, r: jax.Array, fw: jax.Array,
 
 
 def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
-           page_table, live_pages, mesh, batch_axes, csr_l=None,
+           page_table, mesh, batch_axes, csr_l=None,
            collect_scores: bool = False, layer=None, count=None,
            experts=None, moe_layer=None):
     from repro.parallel import context as pctx
@@ -223,7 +223,7 @@ def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
         p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
         rope_theta=cfg.rope_theta, q_pos=q_pos, causal=True,
         window=cfg.window, cache=cache, cache_pos=cache_pos,
-        page_table=page_table, live_pages=live_pages, layer=layer,
+        page_table=page_table, layer=layer,
         paged_kernel=cfg.paged_attn_kernel, shard=cfg.attn_shard,
         bf16_scores=cfg.attn_bf16_scores)
     x = x + boundary(a)
@@ -242,7 +242,6 @@ def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
 def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
             tokens: jax.Array, *, prefix_embeds: Optional[jax.Array] = None,
             cache: Optional[dict] = None, pos0=0,
-            live_pages: Optional[int] = None,
             mesh: Optional[Mesh] = None, batch_axes=None,
             last_only: bool = False, ffn_csr: Optional[dict] = None,
             collect_drs_scores: bool = False,
@@ -269,10 +268,6 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
     the stack and written back (kernels/paged_attention.py).
     pos0: scalar start position, or a per-lane (B,) vector for continuous
     batching (each batch lane decodes at its own depth).
-    live_pages: static page-walk bound for paged decode — the number of
-    leading logical pages that cover every lane's depth (the serving
-    scheduler computes it per step, bucketed so the decode jit compiles
-    a handful of variants); None/0 walks the full table width.
     moe_count (B, S) bool: the tokens a served MoE forward counts (active
     lanes, true prompt tokens); the stats are the sums over the MoE
     layers of the rows routed to held experts, the held experts hit and
@@ -315,7 +310,7 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
         y, new_cache, aux, scores, stats = _block(
             p_l, dsg_l, r, xc, cfg, q_pos,
             cache_l if pools is None else pools, pos0, page_table,
-            live_pages, mesh, batch_axes, csr_l, collect_drs_scores, layer,
+            mesh, batch_axes, csr_l, collect_drs_scores, layer,
             moe_count, experts, i)
         if pools is None:
             return (y, None), (new_cache, aux, scores, stats)
@@ -422,16 +417,16 @@ def prefill(params, dsg, cfg: ModelConfig, tokens, cache,
 
 
 def decode_step(params, dsg, cfg: ModelConfig, token, cache, pos,
-                live_pages=None, mesh=None, batch_axes=None,
+                mesh=None, batch_axes=None,
                 ffn_csr=None, collect_drs_scores: bool = False,
                 moe_count=None):
     """One decode step.  token (B, 1), pos scalar or per-lane (B,) vector
     -> (logits (B, V), cache) [+ DRS scores (L, B, G) when
     collect_drs_scores] [+ MoE stats (3,) over the lanes where moe_count
-    (B, 1) holds].  live_pages: static paged-walk bound; ffn_csr:
-    per-layer group-CSR selection stacks (see forward)."""
+    (B, 1) holds].  ffn_csr: per-layer group-CSR selection stacks (see
+    forward)."""
     out = forward(params, dsg, cfg, token, cache=cache, pos0=pos,
-                  live_pages=live_pages, mesh=mesh, batch_axes=batch_axes,
+                  mesh=mesh, batch_axes=batch_axes,
                   ffn_csr=ffn_csr, collect_drs_scores=collect_drs_scores,
                   moe_count=moe_count)
     rest = out[3:]

@@ -203,7 +203,7 @@ class DSGRuntime:
 
     def bound(self) -> int:
         """Static CSR row width for this step: pow2 bucket over the
-        active lanes' counts (mirrors ServingEngine._live_pages)."""
+        active lanes' counts."""
         if self.lane_active.any():
             mc = int(self.counts[:, self.lane_active].max())
         else:

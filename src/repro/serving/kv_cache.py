@@ -221,8 +221,8 @@ def decode_view(handle: CacheHandle, free_mask=None, donor=None) -> dict:
     No logical (B, Smax, ...) window is ever materialized: the paged view
     is the physical pools + page table exactly as stored, and the
     per-lane depths ride separately as the decode `pos` vector — the
-    attention executor (Pallas kernel or bounded XLA gather) walks only
-    the pages at or below each lane's depth.
+    Pallas attention kernel walks only the pages at or below each
+    lane's depth (the XLA fallback gathers the whole table).
 
     free_mask/donor: a free paged lane's table row is all NULL — left
     alone it would gather scratch-page junk (nondeterministic row-0

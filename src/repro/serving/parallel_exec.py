@@ -511,32 +511,31 @@ class ShardedExecutor(ReplicaExecutor):
     """One device dispatch for the whole replica group.
 
     Each lockstep tick runs the host half of every active replica's step
-    (`ServingEngine.begin_step()`), stacks the decode operands and KV
-    caches along a leading replica axis, executes ONE jitted+vmapped
-    decode step (`scheduler.make_decode_fns` — the exact per-engine step
-    bodies, vmapped), then unstacks and commits per replica.  With a
-    mesh carrying a `replicas` axis (see `parallel.sharding.replica_mesh`)
-    the stacked operands are laid out over that axis, so each replica's
-    slice lives — and computes — on its own device; without a mesh the
-    vmapped step still collapses N dispatches into one, which is the win
-    when host dispatch dominates (many small replicas).
+    (`ServingEngine.begin_step()`), stacks each replica's decode operands
+    (`ServingEngine.decode_operands`, KV cache included) along a leading
+    replica axis, executes ONE jitted+vmapped decode program
+    (`scheduler.make_decode_fns` — the engines' own callables, vmapped),
+    then unstacks and commits per replica (`ServingEngine.commit`).
+    With a mesh carrying a `replicas` axis (see
+    `parallel.sharding.replica_mesh`) the stacked operands are laid out
+    over that axis, so each replica's slice lives — and computes — on
+    its own device; without a mesh the vmapped step still collapses N
+    dispatches into one, which is the win when host dispatch dominates
+    (many small replicas).
 
     Every tick batches the FULL replica group: engines with no active
-    work that tick ride along on a dummy plan (all lanes mirror donor 0
-    at position 0 — `warm_decode`'s pattern: the writes land in the
-    scratch page / lane bytes the next admission fully overwrites, and
-    nothing observes them), so the group step compiles one variant per
-    (live-page bucket, sample) instead of one per active-subset size,
-    and `warm()` can pre-compile them all (warmup_router calls it).
+    work that tick ride along on their idle plan
+    (`ServingEngine.idle_plan`: the writes land where nothing reads
+    them), so the group step compiles one program per sampling mode
+    instead of one per active-subset size, and `warm()` can pre-compile
+    them (warmup_router calls it).
 
     Scope and cost, honestly: admission prefills still run per-replica
-    on the host between ticks, stack/unstack touches every cache byte
-    per tick (on a sharded mesh the slices are device-local so the
-    reshuffle does not cross devices), and the static paged walk bound
-    is the MAX over the group's live-page buckets (a wider bound reads
-    more masked pages; content is unchanged).  This executor is the
-    scaling skeleton for replica groups on real device meshes; the
-    threaded executor is the general-purpose parallel choice.
+    on the host between ticks, and stack/unstack touches every cache
+    byte per tick (on a sharded mesh the slices are device-local so the
+    reshuffle does not cross devices).  This executor is the scaling
+    skeleton for replica groups on real device meshes; the threaded
+    executor is the general-purpose parallel choice.
     """
 
     name = "sharded"
@@ -547,11 +546,10 @@ class ShardedExecutor(ReplicaExecutor):
         super().__init__(engines)
         if any(getattr(e, "dsg_rt", None) is not None for e in engines):
             raise NotImplementedError(
-                "sharded executor batches the plain decode step "
-                "(scheduler.make_decode_fns); DSG-serving engines "
-                "dispatch the CSR/refresh variants inside "
-                "ServingEngine.step() — use exec_mode 'sequential' or "
-                "'threaded' with dsg_serving")
+                "sharded executor batches the dense-FFN decode step; "
+                "DSG-serving engines dispatch the CSR/refresh variants "
+                "inside ServingEngine.step() — use exec_mode "
+                "'sequential' or 'threaded' with dsg_serving")
         self.mesh = mesh
         self._sharding = None
         if mesh is not None:
@@ -590,28 +588,18 @@ class ShardedExecutor(ReplicaExecutor):
                         else jax.tree_util.tree_map(
                             lambda *ls: self._stack(list(ls)),
                             *[e.dsg for e in self.engines]))
-        if self.chunk > 1:
-            # fused-chunk group step: the chunked bodies vmapped over the
-            # replica axis, one dispatch per (chunk x replicas) tick
-            cg, cs = sched.make_chunked_decode_fns(e0.cfg, self.chunk,
-                                                   e0.max_seq)
-            self._jit_greedy = jax.jit(
-                jax.vmap(cg, in_axes=(p_ax, d_ax, 0, 0, 0, 0, 0, 0, None)),
-                donate_argnums=(3,), static_argnums=(8,))
-            self._jit_sample = jax.jit(
-                jax.vmap(cs, in_axes=(p_ax, d_ax, 0, 0, 0, 0, 0, 0, None,
-                                      0, 0, 0, 0)),
-                donate_argnums=(3,), static_argnums=(8,))
-        else:
-            greedy, sample = sched.make_decode_fns(e0.cfg)
-            self._jit_greedy = jax.jit(
-                jax.vmap(greedy, in_axes=(p_ax, d_ax, 0, 0, 0, 0, 0, None)),
-                donate_argnums=(3,), static_argnums=(7,))
-            self._jit_sample = jax.jit(
-                jax.vmap(sample,
-                         in_axes=(p_ax, d_ax, 0, 0, 0, 0, 0, None,
-                                  0, 0, 0, 0)),
-                donate_argnums=(3,), static_argnums=(7,))
+        # the group programs, indexed by `sample`: the engine's callables
+        # vmapped over every operand `decode_operands` stacks on the
+        # replica axis, the stacked caches donated as the engines donate
+        # theirs
+        greedy, sample = sched.make_decode_fns(e0.cfg, chunk=self.chunk,
+                                               max_seq=e0.max_seq)
+        idle = e0.idle_plan()
+        axes = [(p_ax, d_ax) + (0,) * len(e0.decode_operands(idle, s))
+                for s in (False, True)]
+        self._jit_group = (
+            jax.jit(jax.vmap(greedy, in_axes=axes[0]), donate_argnums=(3,)),
+            jax.jit(jax.vmap(sample, in_axes=axes[1]), donate_argnums=(3,)))
         # begin-phase failures deferred past the group step (one raise
         # per tick keeps sibling replicas consistent; see step_all)
         self._pending_failures: List[ReplicaFailure] = []
@@ -622,74 +610,19 @@ class ShardedExecutor(ReplicaExecutor):
             x = jax.device_put(x, self._sharding)
         return x
 
-    def _dummy_plan(self, eng) -> sched.StepPlan:
-        """Ride-along operands for an engine with no active lanes this
-        tick: every lane mirrors donor 0 at position 0, so the decode
-        writes land where nothing ever reads (see class docstring)."""
-        n = eng.n_slots
-        return sched.StepPlan(
-            active=[], donor=0,
-            tok=np.zeros(n, np.int32), pos=np.zeros(n, np.int32),
-            free_mask=np.ones(n, np.bool_),
-            temps=np.zeros(n, np.float32), top_ps=np.ones(n, np.float32),
-            live_pages=0, sample=False, chunk=eng.decode_chunk,
-            eos_ids=np.full(n, -1, np.int32),
-            emit_left=np.ones(n, np.int32))
-
-    def _group_step(self, plans, live: int, sample: bool):
+    def _group_step(self, plans, sample: bool):
         """One vmapped decode over the full group's stacked operands;
-        returns host next-tokens, the stacked output caches, and the
-        dispatch wall time."""
-        engines = self.engines
+        returns the host outputs stacked over replicas (all but the cache
+        and the scores), the stacked output caches, and the wall time."""
         t0 = time.perf_counter()
-        tok = self._stack([jnp.asarray(p.tok)[:, None] for p in plans])
-        pos = self._stack([jnp.asarray(p.pos) for p in plans])
-        free = np.stack([p.free_mask for p in plans])
-        donor = np.array([p.donor for p in plans], np.int32)
-        caches = jax.tree_util.tree_map(
-            lambda *ls: self._stack(list(ls)), *[e.cache for e in engines])
-        params, dsg = self._params_in, self._dsg_in
-        if sample:
-            keys = self._stack([e._base_key for e in engines])
-            steps = self._stack([jnp.int32(e.steps) for e in engines])
-            temps = np.stack([p.temps for p in plans])
-            top_ps = np.stack([p.top_ps for p in plans])
-            nxt, out = self._jit_sample(params, dsg, tok, caches, pos,
-                                        free, donor, live, keys, steps,
-                                        temps, top_ps)
-        else:
-            nxt, out = self._jit_greedy(params, dsg, tok, caches, pos,
-                                        free, donor, live)
-        nxt_host = np.array(nxt, np.int32)       # one device sync per tick
-        return nxt_host, out, time.perf_counter() - t0
-
-    def _group_chunk_step(self, plans, live: int, sample: bool):
-        """Fused-chunk analogue of _group_step: one vmapped dispatch runs
-        `chunk` scanned micro-steps for every replica.  Returns host
-        (blk, flags, next_tok) stacks, the output caches, and the wall."""
-        engines = self.engines
-        t0 = time.perf_counter()
-        tok = self._stack([jnp.asarray(p.tok) for p in plans])
-        pos = self._stack([jnp.asarray(p.pos) for p in plans])
-        done = np.stack([p.free_mask for p in plans])
-        left = np.stack([p.emit_left for p in plans])
-        eos = np.stack([p.eos_ids for p in plans])
-        caches = jax.tree_util.tree_map(
-            lambda *ls: self._stack(list(ls)), *[e.cache for e in engines])
-        params, dsg = self._params_in, self._dsg_in
-        if sample:
-            keys = self._stack([e._base_key for e in engines])
-            steps = self._stack([jnp.int32(e.steps) for e in engines])
-            temps = np.stack([p.temps for p in plans])
-            top_ps = np.stack([p.top_ps for p in plans])
-            blk, flags, nxt, out = self._jit_sample(
-                params, dsg, tok, caches, pos, done, left, eos, live,
-                keys, steps, temps, top_ps)
-        else:
-            blk, flags, nxt, out = self._jit_greedy(
-                params, dsg, tok, caches, pos, done, left, eos, live)
-        return (np.asarray(blk), np.asarray(flags),
-                np.array(nxt, np.int32), out, time.perf_counter() - t0)
+        tok, caches, *ops = jax.tree_util.tree_map(
+            lambda *ls: self._stack(list(ls)),
+            *[e.decode_operands(p, sample)
+              for e, p in zip(self.engines, plans)])
+        *outs, caches, _ = self._jit_group[sample](
+            self._params_in, self._dsg_in, tok, caches, *ops)
+        outs = [np.asarray(o) for o in outs]     # one device sync per tick
+        return outs, caches, time.perf_counter() - t0
 
     def step_all(self, indices):
         t0 = time.perf_counter()
@@ -705,30 +638,24 @@ class ShardedExecutor(ReplicaExecutor):
                 except BaseException as e:
                     # siblings that already began this tick have emitted
                     # tokens — finish the group step WITHOUT the failed
-                    # replica (it rides a dummy plan) and raise after
+                    # replica (it rides its idle plan) and raise after
                     # commit, so no sibling double-emits on the retry
                     failures.append(ReplicaFailure(i, e))
             if plan is not None:
                 real.append(i)
-            plans.append(plan if plan is not None
-                         else self._dummy_plan(eng))
+            plans.append(plan if plan is not None else eng.idle_plan())
         if not real:
             self.wall_seconds += time.perf_counter() - t0
             if failures:
                 self._pending_failures.extend(failures[1:])
                 raise failures[0]
             return
-        live = max(p.live_pages for p in plans)
-        sample = any(p.sample for p in plans)
-        if self.chunk > 1:
-            blk, flags, nxt_host, out, _ = self._group_chunk_step(
-                plans, live, sample)
-        else:
-            nxt_host, out, _ = self._group_step(plans, live, sample)
+        outs, out, _ = self._group_step(plans,
+                                        any(p.sample for p in plans))
         wall = time.perf_counter() - t0
         share = wall / len(real)
         for i, plan in enumerate(plans):
-            # rebinding is uniform: dummy riders only got scratch
+            # rebinding is uniform: idle riders only got scratch
             # scribbles in regions the next admission overwrites
             self.engines[i].cache = jax.tree_util.tree_map(
                 lambda x: x[i], out)
@@ -737,11 +664,7 @@ class ShardedExecutor(ReplicaExecutor):
                 # dispatch; busy_seconds gets the full wall (the replica
                 # was co-busy for all of it) — makespan uses
                 # wall_seconds either way
-                if self.chunk > 1:
-                    self.engines[i].commit_chunk(plan, blk[i], flags[i],
-                                                 nxt_host[i], share)
-                else:
-                    self.engines[i].commit_step(plan, nxt_host[i], share)
+                self.engines[i].commit(plan, [o[i] for o in outs], share)
                 self.busy_seconds[i] += wall
         self.wall_seconds += wall
         if failures:
@@ -749,28 +672,16 @@ class ShardedExecutor(ReplicaExecutor):
             raise failures[0]
 
     def warm(self, sample: bool = False):
-        """Pre-compile the group step for every live-page bucket this
-        executor can reach (the executor analogue of
+        """Pre-compile the group step (the executor analogue of
         `ServingEngine.warm_decode`; warmup_router calls it so no vmapped
-        compile lands inside a measured window).  All-dummy plans: the
-        dispatched writes are never observed."""
-        e0 = self.engines[0]
-        if e0.cache.kind == "paged":
-            buckets = sched.live_page_buckets(
-                e0.max_seq // e0.cache.page_size)
-        else:
-            buckets = [0]
-        plans = [self._dummy_plan(e) for e in self.engines]
-        for live in buckets:
-            for do_sample in ({False, sample}):
-                if self.chunk > 1:
-                    *_, out, _ = self._group_chunk_step(plans, live,
-                                                        do_sample)
-                else:
-                    nxt, out, _ = self._group_step(plans, live, do_sample)
-                for i in range(len(self.engines)):
-                    self.engines[i].cache = jax.tree_util.tree_map(
-                        lambda x: x[i], out)
+        compile lands inside a measured window) on every engine's idle
+        plan, whose writes nothing reads."""
+        plans = [e.idle_plan() for e in self.engines]
+        for do_sample in (False, True)[:1 + sample]:
+            _, out, _ = self._group_step(plans, do_sample)
+            for i in range(len(self.engines)):
+                self.engines[i].cache = jax.tree_util.tree_map(
+                    lambda x: x[i], out)
 
 
 def get_executor(mode, engines, *, mesh=None) -> ReplicaExecutor:

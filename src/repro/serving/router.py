@@ -240,7 +240,7 @@ class Router:
     """Front-end over N independent `ServingEngine` replicas.
 
     Construction mirrors `ServingEngine` — `**engine_kw` is forwarded to
-    every replica (`n_slots`, `max_seq`, `prompt_bucket`, `admission`,
+    every replica (`n_slots`, `max_seq`, `prompt_bucket`,
     `cache_backend`, `page_size`, `cache_tokens`, ...).  `cache_backend`
     must be a name, not a backend instance: a `PagedBackend` manages one
     live handle, so each replica builds its own.  `param_views` optionally

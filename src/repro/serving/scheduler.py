@@ -26,10 +26,6 @@ shapes; the decode step compiles exactly once.  Prompts longer than the
 largest bucket keep only their last `bucket` tokens; the request is
 flagged `truncated=True` and the engine warns once.
 
-`admission="wave"` preserves the old drain-then-refill policy (admit only
-when every lane is free) as a benchmark baseline — bench_serving.py
-measures the overlap speedup against it on mixed-length traffic.
-
 This is the single-host engine; at pod scale the same slot logic runs
 per data-parallel replica group with the model sharded over 'model'
 (the decode step is already the dry-run-verified sharded function).
@@ -37,6 +33,8 @@ per data-parallel replica group with the model sharded over 'model'
 from __future__ import annotations
 
 import collections
+import dataclasses
+import itertools
 import time
 import warnings
 from dataclasses import dataclass, field
@@ -66,22 +64,6 @@ def bucket_sizes(prompt_bucket: int, max_seq: int,
     cap = min(prompt_bucket, max_seq - 1)
     bs = buckets if buckets is not None else DEFAULT_BUCKETS
     return tuple(sorted({min(b, cap) for b in bs}))
-
-def live_page_bound(max_pos: int, page_size: int, max_pages: int) -> int:
-    """Static paged-decode walk bound covering a batch whose deepest lane
-    writes at max_pos: pages needed, rounded up to a power of two so the
-    decode step compiles at most log2(max_pages) variants instead of one
-    per depth, capped at the page-table width."""
-    need = max_pos // page_size + 1
-    return min(1 << (need - 1).bit_length(), max_pages)
-
-
-def live_page_buckets(max_pages: int) -> tuple:
-    """Every bound live_page_bound can return for a given table width —
-    the set warm_decode pre-compiles and traffic models enumerate."""
-    return tuple(sorted({min(1 << i, max_pages)
-                         for i in range(max_pages.bit_length() + 1)}))
-
 
 def params_device(params):
     """The one device every params leaf lives on; None (the process
@@ -159,7 +141,6 @@ class StepPlan:
     free_mask: np.ndarray             # (n_slots,) bool
     temps: np.ndarray                 # (n_slots,) float32
     top_ps: np.ndarray                # (n_slots,) float32
-    live_pages: int                   # static paged walk bound (0 = dense)
     sample: bool                      # any lane with temperature > 0
     # fused-chunk dispatch (decode_chunk > 1): `chunk` micro-steps run in
     # one device dispatch, with per-lane EOS / emit-budget freezing on
@@ -168,7 +149,8 @@ class StepPlan:
     chunk: int = 1
     eos_ids: Optional[np.ndarray] = None   # (n_slots,) int32
     emit_left: Optional[np.ndarray] = None  # (n_slots,) int32 budget
-    refresh: bool = False             # DSG: collect scores at last micro-step
+    refresh: bool = False             # DSG: collect the refresh scores
+    bound: int = 0                    # DSG: static CSR row width
     admits: int = 0                   # prompts admitted by this begin_step
 
 
@@ -180,239 +162,162 @@ def _restore_table(data, c):
     return {**data, "page_table": c.data["page_table"]}
 
 
-def make_decode_fns(cfg):
-    """Build the (greedy, sample) decode-step callables the engine jits.
-
-    Module-level (rather than closures in `ServingEngine.__init__`) so
-    the sharded replica executor can vmap THE SAME step bodies over a
-    leading replica axis — one definition, two compilation strategies,
-    no drift between the per-engine and batched paths.
-
-    A MoE model's step returns the next tokens (B,) followed by its three
-    expert-layer counts over the active lanes (models/moe.py,
-    `moe_ffn_dropless`), (B + 3,) int32, so that they reach the host in
-    the tokens' transfer; `commit_step` splits them.
-    """
-    def _step(p, d, tok, c, pos, free_mask, donor, live_pages):
-        view = kv_cache.decode_view(c, free_mask, donor)
-        if not cfg.is_moe:
-            logits, data = api.decode_step(p, d, cfg, tok, view, pos,
-                                           live_pages=live_pages)
-            return logits, data, None
-        return api.decode_step(p, d, cfg, tok, view, pos,
-                               live_pages=live_pages,
-                               moe_count=~free_mask[:, None])
-
-    def _out(nxt, stats, data, c):
-        if stats is not None:
-            nxt = jnp.concatenate([nxt, stats])
-        return nxt, CacheHandle(_restore_table(data, c), c.kind,
-                                c.page_size)
-
-    def _decode_greedy(p, d, tok, c, pos, free_mask, donor, live_pages):
-        logits, data, stats = _step(p, d, tok, c, pos, free_mask, donor,
-                                    live_pages)
+def _micro_step(cfg, p, d, tok, c, pos, free, donor, csr, collect,
+                sampling):
+    """One decode step over every lane: the body each decode program is
+    built from.  Lanes where `free` holds mirror lane `donor` through the
+    cache view (kv_cache.decode_view) and, for a DSG engine, through the
+    group-CSR operand `csr` (dsg_runtime.mirror_csr; None without DSG).
+    `collect` (python-static) also returns each layer's DRS group scores
+    of this step's FFN inputs, which a DSG refresh rewrites due lanes'
+    patterns from.  `sampling` is None for greedy argmax (no vocab sort)
+    or (key, step, temps, top_ps), keyed per (step, lane).  Returns
+    (next tokens (B,), cache, scores or None, a MoE model's counts over
+    the non-free lanes (3,) or None — models/moe.py `moe_ffn_dropless`)."""
+    view = kv_cache.decode_view(c, free, donor)
+    kw = {}
+    if csr is not None:
+        kw.update(ffn_csr=dsg_runtime.mirror_csr(csr, free, donor),
+                  collect_drs_scores=collect)
+    if cfg.is_moe:
+        kw["moe_count"] = ~free[:, None]
+    logits, data, *rest = api.decode_step(p, d, cfg, tok, view, pos, **kw)
+    scores = rest.pop(0) if collect else None
+    stats = rest.pop(0) if cfg.is_moe else None
+    if sampling is None:
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return _out(nxt, stats, data, c)
-
-    def _decode_sample(p, d, tok, c, pos, free_mask, donor, live_pages,
-                       key, step, temps, top_ps):
-        logits, data, stats = _step(p, d, tok, c, pos, free_mask, donor,
-                                    live_pages)
-        keys = jax.random.split(jax.random.fold_in(key, step),
-                                tok.shape[0])
+    else:
+        key, step, temps, top_ps = sampling
+        keys = jax.random.split(jax.random.fold_in(key, step), tok.shape[0])
         nxt = sample_tokens(logits, keys, temps, top_ps)
-        return _out(nxt, stats, data, c)
-
-    return _decode_greedy, _decode_sample
-
-
-def make_dsg_decode_fns(cfg):
-    """DSG-serving decode-step variants (engines with a DSGRuntime):
-    the make_decode_fns bodies plus (a) the group-CSR selection operand
-    `csr` = {'idx': (L, B, K), 'counts': (L, B)} — free lanes mirror the
-    donor's rows in-jit (dsg_runtime.mirror_csr) so paged duplicate K/V
-    writes stay bit-identical — and (b) a python-static `refresh` flag
-    that additionally returns each layer's DRS group scores of this
-    step's FFN inputs (None otherwise); the runtime rewrites due lanes'
-    patterns from them AFTER the step, off the measured decode window.
-    K is static (pow2 active-group bound), so the decode compiles
-    (bounds x refresh) variants, all pre-compiled by warm_decode."""
-    from repro.serving.dsg_runtime import mirror_csr
-
-    def _dsg_greedy(p, d, tok, c, pos, free_mask, donor, live_pages, csr,
-                    refresh):
-        view = kv_cache.decode_view(c, free_mask, donor)
-        csr_m = mirror_csr(csr, free_mask, donor)
-        out = api.decode_step(p, d, cfg, tok, view, pos,
-                              live_pages=live_pages, ffn_csr=csr_m,
-                              collect_drs_scores=refresh)
-        if refresh:
-            logits, data, scores = out
-        else:
-            (logits, data), scores = out, None
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (nxt, CacheHandle(_restore_table(data, c), c.kind,
-                                 c.page_size), scores)
-
-    def _dsg_sample(p, d, tok, c, pos, free_mask, donor, live_pages, csr,
-                    key, step, temps, top_ps, refresh):
-        view = kv_cache.decode_view(c, free_mask, donor)
-        csr_m = mirror_csr(csr, free_mask, donor)
-        out = api.decode_step(p, d, cfg, tok, view, pos,
-                              live_pages=live_pages, ffn_csr=csr_m,
-                              collect_drs_scores=refresh)
-        if refresh:
-            logits, data, scores = out
-        else:
-            (logits, data), scores = out, None
-        keys = jax.random.split(jax.random.fold_in(key, step),
-                                tok.shape[0])
-        nxt = sample_tokens(logits, keys, temps, top_ps)
-        return (nxt, CacheHandle(_restore_table(data, c), c.kind,
-                                 c.page_size), scores)
-
-    return _dsg_greedy, _dsg_sample
+    return (nxt, CacheHandle(_restore_table(data, c), c.kind, c.page_size),
+            scores, stats)
 
 
-def make_chunked_decode_fns(cfg, chunk: int, max_seq: int):
-    """Build the (greedy, sample) FUSED decode-chunk callables: `chunk`
-    decode steps scanned inside one jitted dispatch, so the per-token
-    host sync (the dispatch-bound wall BENCH_paged_decode.json measures)
-    is paid once per chunk instead of once per token.
+def _one_step(cfg, p, d, ops, sample, refresh):
+    """A single-token decode program: ops = (tok (B, 1), cache, pos,
+    free_mask, donor, csr[, key, step, temps, top_ps]); the host has
+    already mirrored the free lanes' tokens and positions (begin_step).
+    Returns (next tokens (B,), cache, scores or None); a MoE model's
+    counts follow the tokens, (B + 3,), so that they reach the host in
+    the tokens' transfer, and `commit_step` splits them."""
+    tok, c, pos, free_mask, donor, csr, *sampling = ops
+    nxt, c, scores, stats = _micro_step(cfg, p, d, tok, c, pos, free_mask,
+                                        donor, csr, refresh,
+                                        sampling if sample else None)
+    if stats is not None:
+        nxt = jnp.concatenate([nxt, stats])
+    return nxt, c, scores
 
-    The scan carry keeps (tok, pos, done, emit_left, cache) on device.
-    Per micro-step, lanes whose done bit is set (initially the free
-    lanes; later any lane that hit EOS / its max_new budget / max_seq)
-    mirror the first live lane exactly like the chunk=1 donor path —
-    `jnp.argmin(done)` re-picks the donor every micro-step because the
-    chunk=1 donor (first active lane) can itself finish mid-chunk.  A
-    frozen lane's writes are donor duplicates (paged) or overwritten at
-    readmission (dense), identical to the chunk=1 free-lane contract.
 
-    Outputs: `blk` (chunk, n_slots) int32 — the token each lane emitted
-    at each micro-step (its decode INPUT, matching begin_step's
-    emit-before-decode order at chunk=1) — and `flags` (chunk, n_slots)
-    bool marking which entries are real.  A lane's flag column is a
-    monotone prefix: done never unsets, so the host takes `blk[:n, i]`.
-    The final carry's tok is the lane's pending next-step token.
+def _chunk_steps(cfg, p, d, ops, sample, refresh, chunk, max_seq):
+    """A fused decode-chunk program: `chunk` micro-steps scanned in one
+    dispatch, so the per-token host sync is paid once per chunk.  ops =
+    (tok (B,), cache, pos, done, emit_left, eos_ids, csr[, key, step0,
+    temps, top_ps]); eos_ids uses -1 for "no stop token".
 
-    The sample variant folds the key schedule as (seed, step0 + k,
+    The scan carry keeps (tok, pos, done, emit_left, cache, MoE counts)
+    on device.  Per micro-step, lanes whose done bit is set (initially
+    the free lanes; later any lane that hit EOS / its max_new budget /
+    max_seq) mirror the first live lane exactly like the chunk=1 donor
+    path — `jnp.argmin(done)` re-picks the donor every micro-step
+    because the chunk=1 donor (first active lane) can itself finish
+    mid-chunk.  A frozen lane's writes are donor duplicates (paged) or
+    overwritten at readmission (dense), identical to the chunk=1
+    free-lane contract.  A MoE model's counts are summed over the chunk.
+
+    The CSR operand is constant across the chunk (the engine enforces
+    refresh_interval % chunk == 0, and lanes admit at chunk boundaries,
+    so a refresh-due point can only land on the last micro-step), so
+    with `refresh` the last micro-step runs outside the scan and
+    returns its DRS scores.
+
+    Returns `blk` (chunk, B) int32 — the token each lane emitted at each
+    micro-step (its decode INPUT, matching begin_step's emit-before-
+    decode order at chunk=1) — `flags` (chunk, B) bool marking which
+    entries are real (a lane's flag column is a monotone prefix: done
+    never unsets, so the host takes `blk[:n, i]`), each lane's pending
+    next-step token (MoE counts following, as in _one_step), the cache
+    and the scores or None.
+
+    The sampled program folds the key schedule as (seed, step0 + k,
     lane) — bitwise the per-step schedule, so a sampled lane's stream
     is invariant to the chunk size AS LONG AS its admission step and
     `_draws` count match (chunked scheduling admits at chunk boundaries,
     which shifts admission timing under load; temperature-0 streams are
-    unconditionally chunk-invariant).
-    """
-    def _make(sample):
-        def fn(p, d, tok, c, pos, done, emit_left, eos_ids, live_pages,
-               *extra):
-            if sample:
-                key, step0, temps, top_ps = extra
+    unconditionally chunk-invariant)."""
+    tok, c, pos, done, emit_left, eos_ids, csr, *sampling = ops
+    key, step0, temps, top_ps = sampling if sample else (None, 0, None,
+                                                         None)
 
-            def body(carry, k):
-                tok, pos, done, left, c = carry
-                donor = jnp.argmin(done)      # first live lane (False < True)
-                tok_in = jnp.where(done, tok[donor], tok)
-                pos_in = jnp.where(done, pos[donor], pos)
-                view = kv_cache.decode_view(c, done, donor)
-                logits, data = api.decode_step(p, d, cfg, tok_in[:, None],
-                                               view, pos_in,
-                                               live_pages=live_pages)
-                if sample:
-                    keys = jax.random.split(jax.random.fold_in(key, k),
-                                            tok.shape[0])
-                    nxt = sample_tokens(logits, keys, temps, top_ps)
-                else:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                live = ~done
-                fin = live & (((eos_ids >= 0) & (tok_in == eos_ids))
-                              | (left <= 1) | (pos_in + 1 >= max_seq))
-                c = CacheHandle(_restore_table(data, c), c.kind,
-                                c.page_size)
-                carry = (jnp.where(live, nxt, tok),
-                         jnp.where(live, pos_in + 1, pos),
-                         done | fin,
-                         jnp.where(live, left - 1, left), c)
-                return carry, (tok, live)
+    def micro(carry, k, collect):
+        tok, pos, done, left, c, stats = carry
+        donor = jnp.argmin(done)          # first live lane (False < True)
+        tok_in = jnp.where(done, tok[donor], tok)
+        pos_in = jnp.where(done, pos[donor], pos)
+        nxt, c, scores, st = _micro_step(
+            cfg, p, d, tok_in[:, None], c, pos_in, done, donor, csr,
+            collect, (key, k, temps, top_ps) if sample else None)
+        live = ~done
+        fin = live & (((eos_ids >= 0) & (tok_in == eos_ids))
+                      | (left <= 1) | (pos_in + 1 >= max_seq))
+        carry = (jnp.where(live, nxt, tok),
+                 jnp.where(live, pos_in + 1, pos),
+                 done | fin,
+                 jnp.where(live, left - 1, left), c,
+                 None if st is None else stats + st)
+        return carry, (tok, live), scores
 
-            xs = (step0 + jnp.arange(chunk)) if sample else None
-            carry0 = (tok, pos, done, emit_left, c)
-            (tok_f, _, _, _, c_f), (blk, flags) = jax.lax.scan(
-                body, carry0, xs, length=chunk)
-            return blk, flags, tok_f, c_f
-        return fn
-
-    return _make(False), _make(True)
+    n = chunk - 1 if refresh else chunk
+    xs = step0 + jnp.arange(n) if sample else None
+    carry = (tok, pos, done, emit_left, c,
+             jnp.zeros(3, jnp.int32) if cfg.is_moe else None)
+    carry, (blk, flags) = jax.lax.scan(
+        lambda cr, k: micro(cr, k, False)[:2], carry, xs, length=n)
+    scores = None
+    if refresh:
+        carry, (tok_l, live_l), scores = micro(carry, step0 + n, True)
+        blk = jnp.concatenate([blk, tok_l[None]], axis=0)
+        flags = jnp.concatenate([flags, live_l[None]], axis=0)
+    tok_f, _, _, _, c, stats = carry
+    if stats is not None:
+        tok_f = jnp.concatenate([tok_f, stats])
+    return blk, flags, tok_f, c, scores
 
 
-def make_chunked_dsg_decode_fns(cfg, chunk: int, max_seq: int):
-    """DSG variants of make_chunked_decode_fns: the CSR pattern operand
-    is CONSTANT across the chunk (the engine enforces refresh_interval %
-    chunk == 0, and lanes admit at chunk boundaries, so a refresh-due
-    point can only land on the LAST micro-step — the same token index at
-    which the chunk=1 cadence fires).  The last micro-step runs outside
-    the scan with the python-static `refresh` flag so it can return that
-    step's DRS group scores for the host-side pattern rewrite."""
-    from repro.serving.dsg_runtime import mirror_csr
+def make_decode_fns(cfg, *, dsg: bool = False, chunk: int = 1,
+                    max_seq: int = 0):
+    """Build the (greedy, sample) decode callables the engine jits, both
+    from one step body (`_micro_step`): a single-token step, or with
+    `chunk` > 1 the fused chunk that scans it.  Each is called as
+    fn(params, dsg, *ops, refresh=False) with the operands
+    `ServingEngine.decode_operands` builds; `refresh` (static) collects
+    a DSG engine's refresh scores.  Outputs end with (cache, scores or
+    None).
 
-    def _make(sample):
-        def fn(p, d, tok, c, pos, done, emit_left, eos_ids, live_pages,
-               csr, *extra):
-            if sample:
-                key, step0, temps, top_ps, refresh = extra
-            else:
-                (refresh,) = extra
+    Module-level (rather than closures in `ServingEngine.__init__`) so
+    the sharded replica executor can vmap THE SAME callables over a
+    leading replica axis.  The names are the programs' names in a
+    device trace: `_decode_greedy` / `_decode_sample`, `_dsg_greedy` /
+    `_dsg_sample` for a DSG engine's (chipbench's decode_step_ms reads
+    them), `_chunk_greedy` / `_chunk_sample` for a fused chunk's."""
+    kind = "chunk" if chunk > 1 else "dsg" if dsg else "decode"
 
-            def micro(carry, k, collect):
-                tok, pos, done, left, c = carry
-                donor = jnp.argmin(done)
-                tok_in = jnp.where(done, tok[donor], tok)
-                pos_in = jnp.where(done, pos[donor], pos)
-                view = kv_cache.decode_view(c, done, donor)
-                csr_m = mirror_csr(csr, done, donor)
-                out = api.decode_step(p, d, cfg, tok_in[:, None], view,
-                                      pos_in, live_pages=live_pages,
-                                      ffn_csr=csr_m,
-                                      collect_drs_scores=collect)
-                if collect:
-                    logits, data, scores = out
-                else:
-                    (logits, data), scores = out, None
-                if sample:
-                    keys = jax.random.split(jax.random.fold_in(key, k),
-                                            tok.shape[0])
-                    nxt = sample_tokens(logits, keys, temps, top_ps)
-                else:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                live = ~done
-                fin = live & (((eos_ids >= 0) & (tok_in == eos_ids))
-                              | (left <= 1) | (pos_in + 1 >= max_seq))
-                c = CacheHandle(_restore_table(data, c), c.kind,
-                                c.page_size)
-                carry = (jnp.where(live, nxt, tok),
-                         jnp.where(live, pos_in + 1, pos),
-                         done | fin,
-                         jnp.where(live, left - 1, left), c)
-                return carry, (tok, live), scores
+    def step(p, d, ops, sample, refresh):
+        if chunk > 1:
+            return _chunk_steps(cfg, p, d, ops, sample, refresh, chunk,
+                                max_seq)
+        return _one_step(cfg, p, d, ops, sample, refresh)
 
-            def body(carry, k):
-                carry, ys, _ = micro(carry, k, False)
-                return carry, ys
+    def greedy(p, d, *ops, refresh=False):
+        return step(p, d, ops, False, refresh)
 
-            xs = (step0 + jnp.arange(chunk - 1)) if sample else None
-            carry = (tok, pos, done, emit_left, c)
-            carry, (blk, flags) = jax.lax.scan(body, carry, xs,
-                                               length=chunk - 1)
-            k_last = (step0 + chunk - 1) if sample else 0
-            carry, (tok_l, live_l), scores = micro(carry, k_last, refresh)
-            blk = jnp.concatenate([blk, tok_l[None]], axis=0)
-            flags = jnp.concatenate([flags, live_l[None]], axis=0)
-            tok_f, _, _, _, c_f = carry
-            return blk, flags, tok_f, c_f, scores
-        return fn
+    def sample(p, d, *ops, refresh=False):
+        return step(p, d, ops, True, refresh)
 
-    return _make(False), _make(True)
+    greedy.__name__ = greedy.__qualname__ = f"_{kind}_greedy"
+    sample.__name__ = sample.__qualname__ = f"_{kind}_sample"
+    return greedy, sample
 
 
 def sample_tokens(logits: jax.Array, keys: jax.Array, temps: jax.Array,
@@ -463,13 +368,10 @@ class ServingEngine:
     def __init__(self, cfg, params, dsg, *, n_slots: int = 4,
                  max_seq: int = 256, prompt_bucket: int = 64,
                  buckets: Optional[Sequence[int]] = None,
-                 admission: str = "overlap",
                  cache_backend: Union[str, object] = "dense",
                  page_size: int = 16, cache_tokens: Optional[int] = None,
                  seed: int = 0, dsg_serving=None, decode_chunk: int = 1,
                  prefix_sharing: bool = False):
-        if admission not in ("overlap", "wave"):
-            raise ValueError(f"unknown admission policy {admission!r}")
         if decode_chunk < 1:
             raise ValueError(
                 f"decode_chunk must be >= 1 (got {decode_chunk})")
@@ -485,7 +387,6 @@ class ServingEngine:
         # range), so the largest bucket always leaves one position free
         self.prompt_bucket = min(prompt_bucket, max_seq - 1)
         self.buckets = bucket_sizes(prompt_bucket, max_seq, buckets)
-        self.admission = admission
         self.queue: collections.deque = collections.deque()
         self.slots = [_Slot() for _ in range(n_slots)]
         self.done: Dict[int, Request] = {}
@@ -548,8 +449,7 @@ class ServingEngine:
         self._next_tok = np.zeros(n_slots, np.int32)
 
         # sampling is fused into the jitted decode step (one device
-        # dispatch per step; the tiny-model regime is dispatch-bound, see
-        # bench_serving.py) — with a separate greedy-only variant so the
+        # dispatch per step) — with a separate greedy-only variant so the
         # common all-temperature-0 step never pays the full-vocab
         # sort/softmax of nucleus sampling.  Admission is three
         # dispatches (prefill, backend splice, first-token pick); it runs
@@ -574,35 +474,13 @@ class ServingEngine:
             return sample_tokens(logits[None], jax.random.split(k, 1),
                                  temp[None], top_p[None])[0]
 
-        # the engine cache handle is donated: the caller always rebinds
-        # self.cache to the result, and donation lets XLA update one
-        # lane / one token column in place instead of copying the whole
-        # cache every call.  live_pages is static: the paged decode jit
-        # compiles one variant per live-page bucket (see _live_pages).
-        _decode_greedy, _decode_sample = make_decode_fns(cfg)
         self._jit_prefill = jax.jit(_prefill)
         self._jit_prefill_moe = jax.jit(_prefill_moe) if cfg.is_moe else None
         self._jit_first = jax.jit(_first_tok)
-        self._jit_decode_greedy = jax.jit(_decode_greedy,
-                                          donate_argnums=(3,),
-                                          static_argnums=(7,))
-        self._jit_decode_sample = jax.jit(_decode_sample,
-                                          donate_argnums=(3,),
-                                          static_argnums=(7,))
-        # fused decode chunk (ROADMAP: device-resident decode loop) —
-        # decode_chunk micro-steps scanned per dispatch; only built when
-        # chunking is on, and then the chunk=1 decode jits above are
-        # never dispatched (warm_decode warms whichever set is live)
-        if decode_chunk > 1:
-            _cg, _cs = make_chunked_decode_fns(cfg, decode_chunk, max_seq)
-            self._jit_chunk_greedy = jax.jit(_cg, donate_argnums=(3,),
-                                             static_argnums=(8,))
-            self._jit_chunk_sample = jax.jit(_cs, donate_argnums=(3,),
-                                             static_argnums=(8,))
 
         # DSG serving runtime (serving/dsg_runtime.py): per-lane group-CSR
         # patterns feed a sparse FFN decode; refresh scores ride back out
-        # of the refresh-variant decode step
+        # of the refresh variant of the decode step
         scfg = dsg_runtime.as_serving_config(dsg_serving)
         self.dsg_rt = None
         if scfg is not None:
@@ -635,21 +513,20 @@ class ServingEngine:
                     collect_drs_scores=True)
                 return logits[0], lane, scores
 
-            _dsg_greedy, _dsg_sample = make_dsg_decode_fns(cfg)
             self._jit_prefill_dsg = jax.jit(_prefill_dsg)
-            self._jit_decode_greedy_dsg = jax.jit(_dsg_greedy,
-                                                  donate_argnums=(3,),
-                                                  static_argnums=(7, 9))
-            self._jit_decode_sample_dsg = jax.jit(_dsg_sample,
-                                                  donate_argnums=(3,),
-                                                  static_argnums=(7, 13))
-            if decode_chunk > 1:
-                _dcg, _dcs = make_chunked_dsg_decode_fns(
-                    cfg, decode_chunk, max_seq)
-                self._jit_chunk_greedy_dsg = jax.jit(
-                    _dcg, donate_argnums=(3,), static_argnums=(8, 10))
-                self._jit_chunk_sample_dsg = jax.jit(
-                    _dcs, donate_argnums=(3,), static_argnums=(8, 14))
+
+        # the decode programs, indexed by `sample`: the fused chunk's when
+        # decode_chunk > 1.  The engine cache handle is donated: the
+        # caller always rebinds self.cache to the result, and donation
+        # lets XLA update the pools in place instead of copying the whole
+        # cache every call.  Static: a DSG engine's `refresh`, and its CSR
+        # operand's width is a shape; nothing else varies, so warm_decode
+        # compiles every program the engine will dispatch.
+        greedy, sample = make_decode_fns(cfg, dsg=self.dsg_rt is not None,
+                                         chunk=decode_chunk, max_seq=max_seq)
+        self._jit_decode = (
+            jax.jit(greedy, donate_argnums=(3,), static_argnames=("refresh",)),
+            jax.jit(sample, donate_argnums=(3,), static_argnames=("refresh",)))
 
     # -- public API ---------------------------------------------------------
 
@@ -771,12 +648,8 @@ class ServingEngine:
         """Admit queued prompts into free lanes via backend cache surgery;
         returns how many were admitted.
 
-        Overlap policy: every free lane refills immediately (subject to
-        the paged backend having pages for the request's reservation).
-        Wave policy: admission waits until ALL lanes have drained (the old
-        baseline)."""
-        if self.admission == "wave" and any(not s.free for s in self.slots):
-            return 0
+        Every free lane refills immediately (subject to the paged
+        backend having pages for the request's reservation)."""
         admitted = 0
         for i, slot in enumerate(self.slots):
             if not slot.free or not self.queue:
@@ -897,21 +770,6 @@ class ServingEngine:
             self.slots[i].req = req
             self.slots[i].pos = pb
 
-    def _live_pages(self, pos: np.ndarray, span: int = 1) -> int:
-        """Static page-walk bound for this step's paged decode
-        (live_page_bound over the DEEPEST lane; free lanes mirror an
-        active donor, so the active max covers them).  The attention
-        executor reads only these pages — the whole point of the paged
-        layout (ROADMAP: read only live pages).  `span` widens the bound
-        to cover a fused chunk's deepest write (pos + span - 1); reads
-        past a lane's depth are masked, so a wider bound only changes
-        which pow2 compile variant runs, never the gathered values."""
-        if self.cache.kind != "paged":
-            return 0
-        deepest = min(int(pos.max()) + span - 1, self.max_seq - 1)
-        return live_page_bound(deepest, self.cache.page_size,
-                               self.max_seq // self.cache.page_size)
-
     def _kv_blocks(self, plan: StepPlan) -> int:
         """Blocks of pages the paged decode kernel walks in one layer of
         this step, summed over lanes (kernels/paged_attention.py), from
@@ -926,84 +784,62 @@ class ServingEngine:
             ps, kv, d, pool.dtype, self.cache.data["page_table"].shape[1])
         return paged_attention.walk_blocks(plan.pos, ps, block)
 
+    def idle_plan(self) -> StepPlan:
+        """A plan with no active lane: every lane mirrors donor 0 at
+        position 0, so its decode writes land in the scratch page (paged)
+        or in lane bytes the next admission fully overwrites (dense), and
+        nothing ever reads them.  warm_decode dispatches it, and the
+        sharded executor gives it to a replica with nothing to decode."""
+        n = self.n_slots
+        return StepPlan(active=[], donor=0, tok=np.zeros(n, np.int32),
+                        pos=np.zeros(n, np.int32),
+                        free_mask=np.ones(n, np.bool_),
+                        temps=np.zeros(n, np.float32),
+                        top_ps=np.ones(n, np.float32), sample=False,
+                        chunk=self.decode_chunk,
+                        eos_ids=np.full(n, -1, np.int32),
+                        emit_left=np.ones(n, np.int32))
+
+    def decode_operands(self, plan: StepPlan, sample: bool) -> tuple:
+        """The operands of this engine's decode program for `plan`, after
+        params and dsg, in make_decode_fns' order: the lanes' tokens,
+        the cache, their positions and free mask and the donor lane (a
+        fused chunk's tokens, cache, positions, done mask, emit budgets
+        and EOS ids), the DSG CSR operand at the plan's width (None
+        without DSG), then, when `sample`, the PRNG key, the step and the
+        lanes' temperatures and top-ps.  PRNG keys depend only on
+        (engine seed, step, lane), so mixing greedy-only and sampling
+        steps never shifts the key schedule."""
+        if plan.chunk > 1:
+            lanes = (jnp.asarray(plan.tok), self.cache, jnp.asarray(plan.pos),
+                     jnp.asarray(plan.free_mask),
+                     jnp.asarray(plan.emit_left), jnp.asarray(plan.eos_ids))
+        else:
+            lanes = (jnp.asarray(plan.tok)[:, None], self.cache,
+                     jnp.asarray(plan.pos), plan.free_mask, plan.donor)
+        csr = (None if self.dsg_rt is None
+               else self.dsg_rt.device_csr(plan.bound))
+        keys = ((self._base_key, self.steps, plan.temps, plan.top_ps)
+                if sample else ())
+        return lanes + (csr,) + keys
+
     @runs_on("worker")
     def warm_decode(self, sample: bool = False):
-        """Pre-compile the jitted decode step for every static live-page
-        bucket this engine can reach (_live_pages yields the pow2 series
-        up to max_pages; dense engines have a single variant), so no
-        compile lands inside a measured decode window.  Dispatches real
-        decode steps against the idle cache: every lane mirrors donor 0
-        and scatters into the scratch page (paged) or into lane bytes the
-        next admission fully overwrites (dense) — no later gather
-        observes the writes."""
-        if self.cache.kind == "paged":
-            buckets = live_page_buckets(self.max_seq // self.cache.page_size)
-        else:
-            buckets = [0]
-        tok = jnp.zeros((self.n_slots, 1), jnp.int32)
-        pos = jnp.zeros(self.n_slots, jnp.int32)
-        free_mask = np.ones(self.n_slots, np.bool_)
-        temps = np.full(self.n_slots, 0.5, np.float32)
-        top_ps = np.ones(self.n_slots, np.float32)
-        if self.decode_chunk > 1:
-            # a chunked engine only ever dispatches the fused variants —
-            # warm those instead.  All-done lanes mirror lane 0 exactly
-            # like the chunk=1 warm (writes land in the scratch page /
-            # overwritten lane bytes), and emit nothing.
-            tok1 = jnp.zeros(self.n_slots, jnp.int32)
-            done = jnp.ones(self.n_slots, bool)
-            left = jnp.ones(self.n_slots, jnp.int32)
-            eos = jnp.full(self.n_slots, -1, jnp.int32)
-            for live in buckets:
-                if self.dsg_rt is not None:
-                    for bnd in self.dsg_rt.warm_bounds():
-                        csr = self.dsg_rt.device_csr(bnd)
-                        for refresh in (False, True):
-                            _, _, _, self.cache, _ = \
-                                self._jit_chunk_greedy_dsg(
-                                    self.params, self.dsg, tok1,
-                                    self.cache, pos, done, left, eos,
-                                    live, csr, refresh)
-                            if sample:
-                                _, _, _, self.cache, _ = \
-                                    self._jit_chunk_sample_dsg(
-                                        self.params, self.dsg, tok1,
-                                        self.cache, pos, done, left, eos,
-                                        live, csr, self._base_key, 0,
-                                        temps, top_ps, refresh)
-                    continue
-                _, _, _, self.cache = self._jit_chunk_greedy(
-                    self.params, self.dsg, tok1, self.cache, pos, done,
-                    left, eos, live)
-                if sample:
-                    _, _, _, self.cache = self._jit_chunk_sample(
-                        self.params, self.dsg, tok1, self.cache, pos,
-                        done, left, eos, live, self._base_key, 0, temps,
-                        top_ps)
-            return
-        for live in buckets:
-            if self.dsg_rt is not None:
-                # (bound x refresh) variants of the DSG decode step; the
-                # plain decode fns are never dispatched by a DSG engine
-                for bnd in self.dsg_rt.warm_bounds():
-                    csr = self.dsg_rt.device_csr(bnd)
-                    for refresh in (False, True):
-                        _, self.cache, _ = self._jit_decode_greedy_dsg(
-                            self.params, self.dsg, tok, self.cache, pos,
-                            free_mask, 0, live, csr, refresh)
-                        if sample:
-                            _, self.cache, _ = self._jit_decode_sample_dsg(
-                                self.params, self.dsg, tok, self.cache,
-                                pos, free_mask, 0, live, csr,
-                                self._base_key, 0, temps, top_ps, refresh)
-                continue
-            _, self.cache = self._jit_decode_greedy(
-                self.params, self.dsg, tok, self.cache, pos, free_mask, 0,
-                live)
-            if sample:
-                _, self.cache = self._jit_decode_sample(
-                    self.params, self.dsg, tok, self.cache, pos, free_mask,
-                    0, live, self._base_key, 0, temps, top_ps)
+        """Compile every decode program this engine can dispatch, so no
+        compile lands inside a measured decode window: the greedy one
+        (and with `sample` the sampled one), and for a DSG engine each
+        at every CSR width its runtime can reach, with and without the
+        refresh scores.  Dispatches real decode steps on the idle plan
+        (`idle_plan`), whose writes nothing reads."""
+        rt = self.dsg_rt
+        for bound, refresh, samp in itertools.product(
+                (0,) if rt is None else rt.warm_bounds(),
+                (False,) if rt is None else (False, True),
+                (False, True)[:1 + sample]):
+            plan = dataclasses.replace(self.idle_plan(), bound=bound)
+            *_, self.cache, _ = self._jit_decode[samp](
+                self.params, self.dsg, *self.decode_operands(plan, samp),
+                refresh=refresh)
 
     @runs_on("worker")
     def begin_step(self) -> Optional[StepPlan]:
@@ -1098,31 +934,35 @@ class ServingEngine:
                 if not r.output:
                     r.first_token = time.perf_counter()   # TTFT stamp
                 r.output.append(int(tok[i]))
-            return StepPlan(active=active, donor=donor, tok=tok, pos=pos,
-                            free_mask=free_mask, temps=temps, top_ps=top_ps,
-                            live_pages=self._live_pages(pos),
-                            sample=bool((temps > 0).any()), admits=admits)
-        # chunked: emission happens on device; commit_chunk appends.  A
-        # DSG refresh-due point can only land on the last micro-step
-        # (refresh_interval % chunk == 0 and lanes admit at chunk
-        # boundaries) — predict it here so the dispatch picks the
-        # score-collecting compile variant.  Lanes that would freeze on
-        # budget/max_seq before the last micro-step never reach their due
-        # token; an unpredicted EOS freeze just wastes one score read.
-        refresh = False
-        if self.dsg_rt is not None:
-            R = self.dsg_rt.cfg.refresh_interval
-            refresh = any(
-                (len(self.slots[i].req.output) + C) % R == 0
-                and int(emit_left[i]) >= C
-                and self.max_seq - self.slots[i].pos >= C
-                for i in active)
+        # else emission happens on device and commit_chunk appends
+        rt = self.dsg_rt
+        refresh, bound = False, 0
+        if rt is not None:
+            # a lane is due for a DSG refresh when its emitted-token count
+            # crosses refresh_interval — its own history only, so streams
+            # stay invariant to co-scheduling and replica count.  In a
+            # fused chunk the due point can only land on the last
+            # micro-step (refresh_interval % chunk == 0 and lanes admit
+            # at chunk boundaries): predict it here.  Lanes that would
+            # freeze on budget/max_seq before the last micro-step never
+            # reach their due token; an unpredicted EOS freeze just
+            # wastes one score read.
+            R = rt.cfg.refresh_interval
+            bound = rt.bound()
+            if C == 1:
+                refresh = any(len(self.slots[i].req.output) % R == 0
+                              for i in active)
+            else:
+                refresh = any(
+                    (len(self.slots[i].req.output) + C) % R == 0
+                    and int(emit_left[i]) >= C
+                    and self.max_seq - self.slots[i].pos >= C
+                    for i in active)
         return StepPlan(active=active, donor=donor, tok=tok, pos=pos,
                         free_mask=free_mask, temps=temps, top_ps=top_ps,
-                        live_pages=self._live_pages(pos, C),
                         sample=bool((temps > 0).any()), chunk=C,
                         eos_ids=eos_ids, emit_left=emit_left,
-                        refresh=refresh, admits=admits)
+                        refresh=refresh, bound=bound, admits=admits)
 
     @runs_on("worker")
     def commit_step(self, plan: StepPlan, next_tok: np.ndarray,
@@ -1182,7 +1022,7 @@ class ServingEngine:
     @runs_on("worker")
     def commit_chunk(self, plan: StepPlan, blk: np.ndarray,
                      flags: np.ndarray, next_tok: np.ndarray,
-                     seconds: float, *, scores=None, bound=None):
+                     seconds: float) -> dict:
         """Record a fused decode chunk: append each lane's emitted tokens
         (a lane's flag column is a monotone prefix — once frozen it emits
         nothing more), latch pending next-step tokens, advance `steps` by
@@ -1190,17 +1030,9 @@ class ServingEngine:
         Host bookkeeping lags a full chunk behind the device; retirement
         re-derives the freeze conditions from the appended output, which
         mirrors the device's done logic exactly (EOS == output[-1],
-        len(output) >= max_new, pos >= max_seq)."""
+        len(output) >= max_new, pos >= max_seq).  A MoE chunk's counts
+        follow the tokens, as in commit_step."""
         with self.telemetry.span("repro.engine.commit") as span:
-            rt = self.dsg_rt
-            if rt is not None and bound is not None:
-                # one FLOP-model entry per micro-step, over the lanes still
-                # live at that micro-step — keeps flop_stats comparable to a
-                # chunk=1 run of the same traffic
-                for k in range(flags.shape[0]):
-                    live = [i for i in plan.active if flags[k, i]]
-                    if live:
-                        rt.record_step(live, bound)
             emitted = 0
             for i in plan.active:
                 slot = self.slots[i]
@@ -1214,79 +1046,45 @@ class ServingEngine:
                 slot.req.output.extend(int(t) for t in blk[:n, i])
                 slot.pos += n
                 emitted += n
-            self._next_tok = np.array(next_tok, np.int32)
+            self._next_tok = np.array(next_tok[:self.n_slots], np.int32)
             self.decode_seconds += seconds
             self.decode_tokens += emitted
             self.steps += int(flags.any(axis=1).sum())
-            retired = self._retire(plan.active)
-            span.set(retired=len(retired))
-            if rt is not None:
-                for i in retired:
-                    rt.reset_lane(i)
-                if scores is not None:
-                    R = rt.cfg.refresh_interval
-                    due = [i for i in plan.active
-                           if self.slots[i].req is not None
-                           and len(self.slots[i].req.output) % R == 0]
-                    rt.update_from_scores(scores, due)
+            span.set(retired=len(self._retire(plan.active)))
+            return self._count_moe(next_tok[self.n_slots:])
 
     @runs_on("worker")
-    def _dispatch_chunk(self, plan: StepPlan):
-        """Device half of a fused chunk: one jitted dispatch running
-        `plan.chunk` scanned decode micro-steps.  Returns the device's
-        (blk, flags, next_tok) plus (scores, bound) for DSG engines."""
-        args = (self.params, self.dsg, jnp.asarray(plan.tok), self.cache,
-                jnp.asarray(plan.pos), jnp.asarray(plan.free_mask),
-                jnp.asarray(plan.emit_left), jnp.asarray(plan.eos_ids),
-                plan.live_pages)
-        scores = bound = None
-        if self.dsg_rt is not None:
-            rt = self.dsg_rt
-            bound = rt.bound()
-            csr = rt.device_csr(bound)
-            if plan.sample:
-                blk, flags, tok_f, self.cache, scores = \
-                    self._jit_chunk_sample_dsg(
-                        *args, csr, self._base_key, self.steps,
-                        plan.temps, plan.top_ps, plan.refresh)
-            else:
-                blk, flags, tok_f, self.cache, scores = \
-                    self._jit_chunk_greedy_dsg(*args, csr, plan.refresh)
-        elif plan.sample:
-            blk, flags, tok_f, self.cache = self._jit_chunk_sample(
-                *args, self._base_key, self.steps, plan.temps,
-                plan.top_ps)
-        else:
-            blk, flags, tok_f, self.cache = self._jit_chunk_greedy(*args)
-        return blk, flags, tok_f, scores, bound
+    def commit(self, plan: StepPlan, outs, seconds: float) -> dict:
+        """Record the host copies `outs` of a decode program's outputs
+        (all but the cache and the scores): `(next_tok,)` for a single
+        step (commit_step), `(blk, flags, next_tok)` for a fused chunk
+        (commit_chunk).  Returns the step span's MoE attrs."""
+        if plan.chunk > 1:
+            return self.commit_chunk(plan, *outs, seconds)
+        return self.commit_step(plan, outs[0], seconds)
 
     @runs_on("worker")
-    def _dispatch_dsg(self, plan: StepPlan):
-        """DSG-serving decode dispatch: per-lane refresh cadence (a lane
-        is due when its emitted-token count crosses refresh_interval —
-        depending only on the lane's own history, so streams stay
-        invariant to co-scheduling and replica count), CSR operands at
-        the current pow2 bound, and the FLOP-model log entry."""
+    def _settle_dsg(self, plan: StepPlan, outs, scores) -> None:
+        """DSG host bookkeeping after a commit: the FLOP-model entry of
+        each micro-step over the lanes live in it (a fused chunk's
+        counters match a chunk=1 run of the same traffic), the retired
+        lanes' patterns reset, and the due lanes' patterns rewritten from
+        the refresh scores.  The host's pattern state lags the device
+        step, as the page table does."""
         rt = self.dsg_rt
-        due = [i for i in plan.active
-               if len(self.slots[i].req.output)
-               % rt.cfg.refresh_interval == 0]
-        refresh = bool(due)
-        bound = rt.bound()
-        csr = rt.device_csr(bound)
-        rt.record_step(plan.active, bound)
-        if plan.sample:
-            next_tok, self.cache, scores = self._jit_decode_sample_dsg(
-                self.params, self.dsg, jnp.asarray(plan.tok)[:, None],
-                self.cache, jnp.asarray(plan.pos), plan.free_mask,
-                plan.donor, plan.live_pages, csr, self._base_key,
-                self.steps, plan.temps, plan.top_ps, refresh)
-        else:
-            next_tok, self.cache, scores = self._jit_decode_greedy_dsg(
-                self.params, self.dsg, jnp.asarray(plan.tok)[:, None],
-                self.cache, jnp.asarray(plan.pos), plan.free_mask,
-                plan.donor, plan.live_pages, csr, refresh)
-        return next_tok, scores, due
+        live = ([plan.active] if plan.chunk == 1
+                else [[i for i in plan.active if f[i]] for f in outs[1]])
+        for lanes in live:
+            if lanes:
+                rt.record_step(lanes, plan.bound)
+        for i in plan.active:
+            if self.slots[i].req is None:          # retired in commit
+                rt.reset_lane(i)
+        if scores is not None:
+            R = rt.cfg.refresh_interval
+            rt.update_from_scores(scores, [
+                i for i in plan.active if self.slots[i].req is not None
+                and len(self.slots[i].req.output) % R == 0])
 
     @runs_on("worker")
     def step(self):
@@ -1304,53 +1102,21 @@ class ServingEngine:
     @runs_on("worker")
     def _decode(self, plan: StepPlan) -> dict:
         """The device half of `step()` and its commit.  `decode_seconds`
-        gains the dispatch and the wait for its tokens, the spans
+        gains the dispatch and the wait for its outputs, the spans
         `repro.engine.dispatch` and `repro.engine.sync`.  Returns the
         step span's MoE attrs (commit_step)."""
         tel = self.telemetry
-        if plan.chunk > 1:
-            with tel.span("repro.engine.dispatch",
-                          live_pages=plan.live_pages,
-                          kv_blocks=self._kv_blocks(plan)) as disp:
-                blk, flags, tok_f, scores, bound = self._dispatch_chunk(plan)
-            with tel.span("repro.engine.sync") as sync:
-                blk, flags = np.asarray(blk), np.asarray(flags)
-                tok_f = np.array(tok_f, np.int32)
-            self.commit_chunk(plan, blk, flags, tok_f,
-                              disp.seconds + sync.seconds, scores=scores,
-                              bound=bound)
-            return {}
-        scores = due = None
         with tel.span("repro.engine.dispatch",
-                      live_pages=plan.live_pages,
                       kv_blocks=self._kv_blocks(plan)) as disp:
-            # PRNG keys depend only on (engine seed, step, lane), so mixing
-            # greedy-only and sampling steps never shifts the key schedule
-            if self.dsg_rt is not None:
-                next_tok, scores, due = self._dispatch_dsg(plan)
-            elif plan.sample:
-                next_tok, self.cache = self._jit_decode_sample(
-                    self.params, self.dsg, jnp.asarray(plan.tok)[:, None],
-                    self.cache, jnp.asarray(plan.pos), plan.free_mask,
-                    plan.donor, plan.live_pages, self._base_key, self.steps,
-                    plan.temps, plan.top_ps)
-            else:
-                next_tok, self.cache = self._jit_decode_greedy(
-                    self.params, self.dsg, jnp.asarray(plan.tok)[:, None],
-                    self.cache, jnp.asarray(plan.pos), plan.free_mask,
-                    plan.donor, plan.live_pages)
+            *outs, self.cache, scores = self._jit_decode[plan.sample](
+                self.params, self.dsg,
+                *self.decode_operands(plan, plan.sample),
+                refresh=plan.refresh)
         with tel.span("repro.engine.sync") as sync:
-            next_host = np.array(next_tok, np.int32)
-        attrs = self.commit_step(plan, next_host, disp.seconds + sync.seconds)
+            outs = [np.asarray(o) for o in outs]
+        attrs = self.commit(plan, outs, disp.seconds + sync.seconds)
         if self.dsg_rt is not None:
-            # host pattern bookkeeping lags the device step (the paged
-            # page-table split): retire first, then rewrite due lanes
-            # from the refresh scores (update skips inactive lanes)
-            for i in plan.active:
-                if self.slots[i].req is None:          # retired in commit
-                    self.dsg_rt.reset_lane(i)
-            if scores is not None:
-                self.dsg_rt.update_from_scores(scores, due)
+            self._settle_dsg(plan, outs, scores)
         return attrs
 
     # -- fault containment (called by serving/router.py failover) ------------

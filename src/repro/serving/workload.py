@@ -1,10 +1,10 @@
 """Synthetic serving workloads + engine measurement harness.
 
 Mixed-length traffic is where overlap admission earns its keep: short and
-long prompts (and short and long generations) interleave, so a wave-admission
-engine strands free lanes until the whole batch drains while overlap refills
-them immediately.  bench_serving.py and `launch/serve.py --workload mixed`
-both drive the engine through this module so the numbers agree.
+long prompts (and short and long generations) interleave, and every lane a
+retirement frees refills at the next step.  The CPU benchmarks and
+`launch/serve.py --workload mixed` drive the engine through this module so
+the numbers agree.
 
 Open-loop evaluation (docs/serving.md): closed-loop drains measure the
 system at its own pace — every retirement immediately frees capacity for
@@ -248,12 +248,11 @@ def warmup_engine(eng: ServingEngine, vocab: int,
     engine's counters: one throwaway admission per prompt bucket (the
     prefill variants + the decode step), the sampling decode/admission
     variants when the traffic samples (same compiled shapes for any
-    temperature > 0), and every static live-page bucket of the decode
-    step — chunked engines warm the fused-chunk variants instead (paged
-    engines recompile per pow2 depth bucket — see
-    ServingEngine._live_pages; traffic alone only reaches the buckets
-    its depths happen to cross).  Pass the workload's `requests` so the
-    sampling variants are warmed exactly when the traffic needs them."""
+    temperature > 0), and every decode program the engine can dispatch
+    (ServingEngine.warm_decode; a DSG engine's CSR widths reach beyond
+    what a short warm-up's traffic crosses).  Pass the workload's
+    `requests` so the sampling variants are warmed exactly when the
+    traffic needs them."""
     if requests is not None:
         warm_temp = warm_temp_for(requests, warm_temp)
     rng = np.random.default_rng(12345)
@@ -276,8 +275,7 @@ def warmup_engine(eng: ServingEngine, vocab: int,
 
 def warmup_router(router: Router, vocab: int, warm_temp: float = 0.0,
                   max_steps: int = 100_000, requests=None):
-    """Warm EVERY replica's prefill buckets and decode live-page variants
-    (each replica owns its own jitted callables — nothing is shared), then
+    """Warm EVERY replica's prefill buckets and decode programs (each replica owns its own jitted callables — nothing is shared), then
     zero the router's timing counters so measured makespans are
     steady-state.  Engines are warmed directly (not through the
     executor), which is safe while no run is in flight; the executor's
@@ -344,8 +342,7 @@ def latency_stats(done: Dict[int, Request]) -> Dict[str, float]:
 
 
 def run_workload(cfg, params, dsg, requests: List[Request], *,
-                 admission: str = "overlap", n_slots: int = 4,
-                 max_seq: int = 384, prompt_bucket: int = 256,
+                 n_slots: int = 4, max_seq: int = 384, prompt_bucket: int = 256,
                  cache_backend: str = "dense", page_size: int = 16,
                  cache_tokens=None, seed: int = 0, replicas: int = 1,
                  route_policy: str = "least_queue",
@@ -379,7 +376,7 @@ def run_workload(cfg, params, dsg, requests: List[Request], *,
     uncontained kill would just crash the run) and forces the Router
     path.  Chaos runs add failed/timed_out/replica_health stats."""
     engine_kw = dict(n_slots=n_slots, max_seq=max_seq,
-                     prompt_bucket=prompt_bucket, admission=admission,
+                     prompt_bucket=prompt_bucket,
                      cache_backend=cache_backend, page_size=page_size,
                      cache_tokens=cache_tokens, dsg_serving=dsg_serving,
                      decode_chunk=decode_chunk,
@@ -420,7 +417,6 @@ def run_workload(cfg, params, dsg, requests: List[Request], *,
             runner.close()
     toks = sum(len(r.output) for r in done.values())
     stats = {
-        "admission": admission,
         "cache_backend": cache_backend,
         "replicas": replicas,
         "decode_chunk": decode_chunk,

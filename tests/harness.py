@@ -1,7 +1,7 @@
 """Shared differential-test harness for the serving stack.
 
 The equivalence surface grown across PRs 1-3 (dense vs paged backends,
-XLA vs Pallas decode executors, overlap vs wave admission — and now
+XLA vs Pallas decode executors — and now
 1 vs N router replicas) all reduces to the same check: drive the same
 requests through two configurations and compare the per-request greedy
 token streams.  This module is that check, extracted from the copies
@@ -68,10 +68,9 @@ def make_engine_parts(arch: str = SMOKE_ARCH,
 def engine_spec(cfg, params, dsg, **engine_kw) -> dict:
     """Bundle model parts + engine kwargs into the spec run_and_collect
     consumes.  Defaults match the historical serving-test engines
-    (2 slots, max_seq 64, prompt bucket 32, overlap admission)."""
+    (2 slots, max_seq 64, prompt bucket 32)."""
     spec = {"cfg": cfg, "params": params, "dsg": dsg,
-            "n_slots": 2, "max_seq": 64, "prompt_bucket": 32,
-            "admission": "overlap"}
+            "n_slots": 2, "max_seq": 64, "prompt_bucket": 32}
     spec.update(engine_kw)
     return spec
 

@@ -21,6 +21,7 @@ from pathlib import Path
 
 import pytest
 
+from repro.analysis.callgraph import Index
 from repro.analysis.contracts import (CheckedCondition, GuardedDeque,
                                       GuardedDict, GuardedList,
                                       TsanViolation)
@@ -343,6 +344,35 @@ def test_src_tree_clean_against_baseline():
     baseline = load_baseline(REPO / "scripts" / "lint_baseline.json")
     new, _ = baseline.split(findings)
     assert new == [], "\n".join(f.render() for f in new)
+
+
+@pytest.fixture(scope="module")
+def src_index():
+    return Index.build(REPO / "src")
+
+
+@pytest.mark.parametrize("mod", ["scheduler", "parallel_exec", "kv_cache"])
+def test_serving_jit_sites_resolve(src_index, mod):
+    """Every jax.jit call in the serving engine and its executors names a
+    function the index resolves (a lambda or a comprehension variable
+    does not), so jit_hygiene walks what each program traces."""
+    mi = src_index.modules[f"repro.serving.{mod}"]
+    found = collections.defaultdict(set)
+    for scope, call, target in src_index._jit_sites(mi):
+        fn_expr, _ = src_index._unwrap_jit(call)
+        fi = target or src_index._resolve_fn_expr(mi, scope, fn_expr)
+        found[call.lineno].add(fi and fi.qualname)
+    unresolved = sorted(ln for ln, q in found.items() if q == {None})
+    assert found and not unresolved, f"{mod}.py lines {unresolved}"
+
+
+@pytest.mark.parametrize("qualname", [
+    "scheduler._micro_step", "scheduler._one_step", "scheduler._chunk_steps",
+    "scheduler.sample_tokens", "kv_cache.decode_view",
+    "dsg_runtime.mirror_csr"])
+def test_decode_step_reached_from_jit_roots(src_index, qualname):
+    traced = src_index.traced_functions(src_index.jit_roots())
+    assert f"repro.serving.{qualname}" in traced
 
 
 @pytest.mark.parametrize("snippet", [BAD_HOST_SYNC, BAD_UNLOCKED,

@@ -75,7 +75,7 @@ def _compile(name, fn, sharding, *shapes):
 def test_paged_decode_compiles(one_chip, h, kv, max_pages):
     def step(q, kn, vn, kp, vp, pt, pos, layer):
         return paged_attention.paged_decode(q, kn, vn, kp, vp, pt, pos,
-                                            layer, num_pages=max_pages)
+                                            layer)
     pool = ((2, B * max_pages + 1, PAGE, kv, D), BF16)
     _compile("paged_decode", step, one_chip,
              ((B, h, D), BF16), ((B, kv, D), BF16), ((B, kv, D), BF16),
@@ -120,11 +120,10 @@ def test_decode_step_updates_pools_in_place(one_chip, monkeypatch):
                  jax.ShapeDtypeStruct((B,), jnp.int32),
                  jax.ShapeDtypeStruct((B,), jnp.bool_),
                  jax.ShapeDtypeStruct((), jnp.int32)))
-    step = jax.jit(make_decode_fns(cfg)[0], donate_argnums=(3,),
-                   static_argnums=(7,))
+    step = jax.jit(make_decode_fns(cfg)[0], donate_argnums=(3,))
     tok, pos, free, donor = lanes
     compiled = step.lower(params, None, tok, handle, pos, free, donor,
-                          MAX_PAGES).compile()
+                          None).compile()
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
     assert "%paged_decode" in text
@@ -206,10 +205,9 @@ def test_moe_decode_step_updates_pools_in_place(one_chip, monkeypatch):
                                  jax.ShapeDtypeStruct((B,), jnp.int32),
                                  jax.ShapeDtypeStruct((B,), jnp.bool_),
                                  jax.ShapeDtypeStruct((), jnp.int32)))
-    step = jax.jit(make_decode_fns(cfg)[0], donate_argnums=(3,),
-                   static_argnums=(7,))
+    step = jax.jit(make_decode_fns(cfg)[0], donate_argnums=(3,))
     compiled = step.lower(params, None, tok, handle, pos, free, donor,
-                          MAX_PAGES).compile()
+                          None).compile()
     text = compiled.as_text()
     assert "%paged_decode" in text and "%moe_experts" in text
     assert not _pool_moves(compiled, layer_pool, pool)

@@ -16,8 +16,7 @@ from repro.models import api
 from repro.models import moe as moe_mod
 from repro.serving import kv_cache
 from repro.serving.kv_cache import CacheHandle
-from repro.serving.scheduler import (Request, ServingEngine, _restore_table,
-                                     live_page_bound)
+from repro.serving.scheduler import Request, ServingEngine, _restore_table
 
 ARCH = "deepseek-moe-16b"
 
@@ -226,12 +225,10 @@ def serve_logits(cfg, params, prompts, n_slots, steps, page_size=8,
         for i in range(n):
             c = backend.ensure(c, i, int(pos[i]))
         tok[free], pos[free] = tok[0], pos[0]
-        live = live_page_bound(int(pos.max()), page_size,
-                               max_seq // page_size)
         logits, data = api.decode_step(
             params, None, cfg, jnp.asarray(tok)[:, None],
             kv_cache.decode_view(c, jnp.asarray(free), 0),
-            jnp.asarray(pos), live_pages=live)
+            jnp.asarray(pos))
         c = CacheHandle(_restore_table(data, c), c.kind, c.page_size)
         for i in range(n):
             out[i].append(np.asarray(logits[i]))
@@ -303,9 +300,9 @@ def test_pools_cross_both_layer_scans(parts):
     handle = kv_cache.PagedBackend(page_size=8).make(cfg, n_slots, 64)
     pool = tuple(handle.data["pages_k"].shape)
     assert pool[0] == cfg.n_layers == 3
-    closed = jax.make_jaxpr(make_decode_fns(cfg)[0], static_argnums=(7,))(
+    closed = jax.make_jaxpr(make_decode_fns(cfg)[0])(
         params, None, jnp.zeros((n_slots, 1), jnp.int32), handle,
-        jnp.zeros(n_slots, jnp.int32), jnp.zeros(n_slots, bool), 0, 8)
+        jnp.zeros(n_slots, jnp.int32), jnp.zeros(n_slots, bool), 0, None)
     shape = lambda vs: [tuple(v.aval.shape) for v in vs]   # noqa: E731
     carried = []
     for n in (cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers):
@@ -348,6 +345,31 @@ def test_engine_counts_true_prompt_tokens_and_active_lanes(parts):
                                            for s in spans)
     assert tel.counters["moe.experts_hit"] == sum(
         s.attrs["moe_experts_hit"] for s in spans)
+
+
+def test_fused_chunk_counts_the_same_rows(parts):
+    """A decode_chunk=8 engine sums each micro-step's counts over the
+    chunk and records them as a single-step engine does: on the same
+    greedy traffic its `moe.rows` total is non-zero and equals the
+    decode_chunk=1 engine's, and its step spans carry the counts."""
+    cfg, params = parts
+    rows, streams = {}, {}
+    for chunk in (1, 8):
+        eng = ServingEngine(cfg, params, None, n_slots=4, max_seq=64,
+                            prompt_bucket=32, cache_backend="paged",
+                            page_size=8, decode_chunk=chunk)
+        rng = np.random.default_rng(3)
+        for u, (n, new) in enumerate([(11, 9), (5, 13), (20, 6)]):
+            eng.submit(Request(uid=u, max_new=new, prompt=rng.integers(
+                0, cfg.vocab, n, dtype=np.int32)))
+        eng.run()
+        rows[chunk] = eng.telemetry.counters["moe.rows"]
+        streams[chunk] = {u: r.output for u, r in eng.done.items()}
+        steps = [s for s in eng.telemetry.spans(name="repro.engine.step")
+                 if s.attrs["lanes"]]
+        assert steps and all(s.attrs["moe_rows"] > 0 for s in steps)
+    assert streams[8] == streams[1]
+    assert rows[8] == rows[1] > 0
 
 
 def test_engine_counts_match_the_layer_counts(parts):

@@ -269,8 +269,7 @@ def test_retire_readmit_reuses_and_reclaims(parts):
     from repro.serving.scheduler import ServingEngine
     cfg, params, dsg = parts
     eng = ServingEngine(cfg, params, dsg, n_slots=2, max_seq=64,
-                        buckets=(24,), admission="overlap",
-                        cache_backend="paged", page_size=PAGE,
+                        buckets=(24,), cache_backend="paged", page_size=PAGE,
                         cache_tokens=256, prefix_sharing=True)
     wave1 = shared_prefix_traffic(cfg, n=4, prompt_len=24, prefix_len=24,
                                   max_new=6)

@@ -158,8 +158,7 @@ def test_least_pages_never_admits_beyond_reservation(engine_parts):
     cfg, params, dsg = engine_parts
     router = Router(cfg, params, dsg, n_replicas=2, policy="least_pages",
                     n_slots=2, max_seq=64, prompt_bucket=32,
-                    admission="overlap", cache_backend="paged",
-                    page_size=8, cache_tokens=48)
+                    cache_backend="paged", page_size=8, cache_tokens=48)
     for r in mixed_traffic(cfg):
         router.submit(r)
     while router._busy():

@@ -24,7 +24,7 @@ def engine_parts():
 
 def _make_engine(cfg, params, dsg):
     return ServingEngine(cfg, params, dsg, n_slots=2, max_seq=64,
-                         prompt_bucket=32, admission="overlap")
+                         prompt_bucket=32)
 
 
 def _solo_output(cfg, params, dsg, req_proto):

@@ -1,18 +1,16 @@
 """Hypothesis property tests for the serving substrate: the paged
 backend's BlockAllocator (random alloc/free interleavings never
 double-assign a physical page and conserve the free-list count) and the
-scheduler's static-shape helpers live_page_bound / live_page_buckets /
-bucket_sizes (monotone, pow2-bucketed, always covering the write
-position).  These are host-side pure functions — no jit, no device —
-so hundreds of examples run in milliseconds."""
+scheduler's prompt buckets, bucket_sizes (sorted, unique, capped).
+These are host-side pure functions — no jit, no device — so hundreds of
+examples run in milliseconds."""
 import pytest
 
 pytest.importorskip("hypothesis")  # dev-only dep (requirements-dev.txt); skip, don't error
 from hypothesis import given, settings, strategies as st
 
 from repro.serving.kv_cache import BlockAllocator, OutOfPages
-from repro.serving.scheduler import (DEFAULT_BUCKETS, bucket_sizes,
-                                     live_page_bound, live_page_buckets)
+from repro.serving.scheduler import DEFAULT_BUCKETS, bucket_sizes
 
 
 # ---------------------------------------------------------------------------
@@ -144,50 +142,6 @@ def test_register_is_first_writer_wins_and_live_only(alloc_pages, extra):
     with pytest.raises(ValueError):
         a.register(b"k2", pages[0])
     assert a.index_size == 0
-
-
-# ---------------------------------------------------------------------------
-# live_page_bound / live_page_buckets
-# ---------------------------------------------------------------------------
-
-_PAGE_SIZES = st.sampled_from([4, 8, 16, 32])
-
-
-@settings(max_examples=200)
-@given(_PAGE_SIZES, st.integers(1, 64), st.data())
-def test_live_page_bound_covers_and_buckets(ps, max_pages, data):
-    """The static walk bound always covers the deepest write position,
-    never exceeds the page-table width, and lands in the pre-compiled
-    pow2 bucket set (so warm_decode has compiled it)."""
-    pos = data.draw(st.integers(0, max_pages * ps - 1))
-    b = live_page_bound(pos, ps, max_pages)
-    assert 1 <= b <= max_pages
-    assert b * ps > pos                       # bound covers the write
-    assert b in live_page_buckets(max_pages)  # warm_decode compiled it
-    assert b == max_pages or (b & (b - 1)) == 0   # pow2 unless capped
-
-
-@settings(max_examples=200)
-@given(_PAGE_SIZES, st.integers(1, 64), st.data())
-def test_live_page_bound_monotone(ps, max_pages, data):
-    """Deeper batches can only widen the walk: the bound is monotone in
-    max_pos, so a bound computed for the deepest lane covers every lane."""
-    hi = max_pages * ps - 1
-    p1 = data.draw(st.integers(0, hi))
-    p2 = data.draw(st.integers(p1, hi))
-    assert live_page_bound(p1, ps, max_pages) \
-        <= live_page_bound(p2, ps, max_pages)
-
-
-@settings(max_examples=100)
-@given(st.integers(1, 64))
-def test_live_page_buckets_membership(max_pages):
-    buckets = live_page_buckets(max_pages)
-    assert buckets == tuple(sorted(set(buckets)))       # sorted, unique
-    assert buckets[-1] == max_pages                     # cap is reachable
-    for b in buckets:
-        assert 1 <= b <= max_pages
-        assert b == max_pages or (b & (b - 1)) == 0
 
 
 # ---------------------------------------------------------------------------

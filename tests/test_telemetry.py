@@ -8,9 +8,12 @@ a dense, a DSG and a fused-chunk engine, the bounded ring, the registry
 and the recorders of engines under the threaded executor.
 """
 import gc
+import re
 
+import jax
 import pytest
 
+import harness
 from harness import (engine_spec, make_engine_parts, mixed_traffic,
                      run_and_collect)
 from repro.serving import telemetry
@@ -66,7 +69,7 @@ def test_span_tree(engine_parts, kind):
                  "repro.engine.commit"):
         assert len(named[name]) == len(decode)
         assert {s.parent for s in named[name]} == {s.sid for s in decode}
-    assert all({"live_pages", "kv_blocks"} <= set(s.attrs)
+    assert all(set(s.attrs) == {"kv_blocks"}
                for s in named["repro.engine.dispatch"])
     # one growth pass per step that has active lanes, inside begin
     assert len(named["repro.kv.grow"]) == len(decode)
@@ -244,3 +247,74 @@ def test_threaded_engines_keep_their_own_recorders(engine_parts):
         assert {u for u, _ in router.dispatch_log} == set(uids)
     finally:
         router.close()
+
+
+def _reader_programs(metric):
+    """The XLA module-name regexes a chipbench metric reads from a device
+    trace (chipbench/metrics/<metric>.py, `PROGRAMS`)."""
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "chipbench" / "metrics" \
+        / f"{metric}.py"
+    spec = importlib.util.spec_from_file_location(metric, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.PROGRAMS
+
+
+def _module_name(lowered):
+    return re.search(r"module @(\S+)", lowered.as_text()).group(1)
+
+
+@pytest.mark.parametrize("kind", ["dense", "dsg", "moe"])
+def test_engine_programs_match_the_trace_readers(engine_parts, kind):
+    """The device trace finds the engine's programs by XLA module name:
+    decode_step_ms.batch reads the greedy and sampled decode programs,
+    prefill_ms.chat the prefill.  A rename would turn both null with no
+    other test failing."""
+    import jax.numpy as jnp
+
+    from repro.models import api
+    from repro.serving.scheduler import ServingEngine
+    cfg, params, dsg = engine_parts
+    if kind == "moe":
+        cfg = harness.smoke_cfg("deepseek-moe-16b")
+        cfg = cfg.replace(dsg=cfg.dsg._replace(enabled=False))
+        params, dsg = api.init_model(jax.random.PRNGKey(0), cfg), None
+    eng = ServingEngine(cfg, params, dsg, n_slots=2, max_seq=64,
+                        prompt_bucket=32, **ENGINES.get(kind, PAGED))
+    decode = _reader_programs("decode_step_ms.batch")
+    for sample in (False, True):
+        name = _module_name(eng._jit_decode[sample].lower(
+            eng.params, eng.dsg,
+            *eng.decode_operands(eng.idle_plan(), sample)))
+        assert any(re.match(p, name) for p in decode), name
+    if kind == "moe":
+        return          # the chat cell, where prefill_ms is read, is dense
+    prefill = eng._jit_prefill_dsg if kind == "dsg" else eng._jit_prefill
+    name = _module_name(prefill.lower(eng.params, eng.dsg,
+                                      jnp.zeros((1, 16), jnp.int32),
+                                      eng._lane0))
+    assert any(re.match(p, name) for p in
+               _reader_programs("prefill_ms.chat")), name
+
+
+def test_sharded_group_programs_match_the_trace_readers(engine_parts):
+    """The sharded executor's group programs vmap the engine's decode
+    callables and keep their names, so a trace of a sharded run is read
+    by decode_step_ms.batch as an engine's is."""
+    import jax.numpy as jnp
+
+    from repro.serving.parallel_exec import ShardedExecutor
+    from repro.serving.scheduler import ServingEngine
+    engs = [ServingEngine(*engine_parts, n_slots=2, max_seq=64,
+                          prompt_bucket=32, **PAGED) for _ in range(2)]
+    ex = ShardedExecutor(engs)
+    decode = _reader_programs("decode_step_ms.batch")
+    for sample in (False, True):
+        ops = jax.tree_util.tree_map(
+            lambda *ls: jnp.stack(ls),
+            *[e.decode_operands(e.idle_plan(), sample) for e in engs])
+        name = _module_name(ex._jit_group[sample].lower(
+            ex._params_in, ex._dsg_in, *ops))
+        assert any(re.match(p, name) for p in decode), name
