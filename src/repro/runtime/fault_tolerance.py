@@ -97,8 +97,8 @@ class ReplicaFault:
                step's tokens land (the clean worker-death case);
       delay  — sleep `delay_s` inside the step (a straggler; trips the
                router's stall timeout when one is configured);
-      poison — overwrite the last emitted token of every resident lane
-               with POISON_TOKEN, then raise (the dirty death: failover
+      poison — overwrite the last emitted token of every stream still
+               in progress with POISON_TOKEN, then raise (the dirty death: failover
                must discard the partial output and replay from the
                prompt, or the corruption survives into the stream).
     """
@@ -122,8 +122,9 @@ class ServingFaultInjector:
     replica restarts or benchmark repeats (until `reset()` re-arms).
 
     `attach(engines)` stamps each engine's `replica_index` and installs
-    the injector as its `fault_injector`; `ServingEngine.begin_step()`
-    calls `on_step(engine)` at every step boundary.  Attach AFTER warmup:
+    the injector as its `fault_injector`; the engine calls
+    `on_step(engine)` at every step boundary (`ServingEngine.step()`,
+    `begin_step()`).  Attach AFTER warmup:
     warmup resets step counters, so a fault keyed on an early step would
     otherwise fire inside the compile pass."""
 
@@ -164,9 +165,13 @@ class ServingFaultInjector:
                 time.sleep(f.delay_s)
                 continue
             if f.kind == "poison":
+                # streams still in progress: a complete one is done
+                # already while its lane awaits the commit of its last
+                # decode
                 for slot in eng.slots:
-                    if slot.req is not None and slot.req.output:
-                        slot.req.output[-1] = POISON_TOKEN
+                    r = slot.req
+                    if r is not None and r.output and r.status == "pending":
+                        r.output[-1] = POISON_TOKEN
             raise InjectedFault(
                 f"injected {f.kind} at replica {f.replica} "
                 f"step {f.step}")

@@ -204,10 +204,11 @@ class SequentialExecutor(ReplicaExecutor):
             for i in indices:
                 ti = time.perf_counter()
                 try:
-                    # each engine's step is atomic (begin -> dispatch ->
-                    # commit inside), so a failure here never corrupts a
-                    # sibling replica's state — replicas after `i` simply
-                    # skip this tick, which lockstep never promised anyway
+                    # each engine's step touches only its own state (its
+                    # plan, dispatch and commits), so a failure here never
+                    # corrupts a sibling replica's state — replicas after
+                    # `i` simply skip this tick, which lockstep never
+                    # promised anyway
                     self.engines[i].step()
                 except BaseException as e:
                     raise ReplicaFailure(i, e) from e
@@ -359,6 +360,7 @@ class ThreadedExecutor(ReplicaExecutor):
             while True:                      # step outside the lock
                 done0 = len(eng.done)
                 queued0 = len(eng.queue)
+                free0 = eng.free_slots()
                 t0 = time.perf_counter()
                 try:
                     eng.step()
@@ -377,11 +379,14 @@ class ThreadedExecutor(ReplicaExecutor):
                     self.busy_seconds[i] += dt
                     self._progress[i] = time.perf_counter()
                 # wake the router only on events a policy can act on — a
-                # retirement freed a lane, or an admission drained this
-                # replica's queue.  Signaling every step would have the
-                # router thread and N workers convoying; the sticky Event
-                # keeps even an inconveniently-timed wake from being lost.
-                if (len(eng.done) != done0 or len(eng.queue) < queued0):
+                # request finished, a retirement freed a lane (a step
+                # after its stream finished, when a decode was in flight),
+                # or an admission drained this replica's queue.  Signaling
+                # every step would have the router thread and N workers
+                # convoying; the sticky Event keeps even an
+                # inconveniently-timed wake from being lost.
+                if (len(eng.done) != done0 or eng.free_slots() > free0
+                        or len(eng.queue) < queued0):
                     self._router_wake.set()
                 # observe close() promptly even while work remains —
                 # engines are always between steps here, so stopping is

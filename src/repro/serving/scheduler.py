@@ -38,7 +38,7 @@ import itertools
 import time
 import warnings
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Union
+from typing import Dict, List, NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -131,12 +131,15 @@ class StepPlan:
     split exists so replica executors (serving/parallel_exec.py) can
     batch the device half across engines — the sharded executor stacks
     the operands of several plans along a leading replica axis and runs
-    one vmapped decode — while `ServingEngine.step()` stays the
-    single-engine begin -> dispatch -> commit composition.
+    one vmapped decode.  `ServingEngine.step()` builds its plans the same
+    way but keeps one decode in flight: it dispatches the next plan
+    before it commits the last.
     """
     active: List[int]                 # slot indices decoding this step
     donor: int                        # active lane free lanes mirror
-    tok: np.ndarray                   # (n_slots,) int32 decode inputs
+    # (n_slots,) int32 decode inputs; -1 where the lane takes the device
+    # token of the step still in flight (an `ahead` plan)
+    tok: np.ndarray
     pos: np.ndarray                   # (n_slots,) int32 write positions
     free_mask: np.ndarray             # (n_slots,) bool
     temps: np.ndarray                 # (n_slots,) float32
@@ -152,6 +155,19 @@ class StepPlan:
     refresh: bool = False             # DSG: collect the refresh scores
     bound: int = 0                    # DSG: static CSR row width
     admits: int = 0                   # prompts admitted by this begin_step
+    # dispatched before the host read the previous step's tokens: its
+    # lanes decode those tokens straight from the device (ServingEngine.
+    # step); `then` is set on the previous plan, whose commit appends them
+    ahead: bool = False
+    then: Optional["StepPlan"] = None
+
+
+class _InFlight(NamedTuple):
+    """A decode dispatched and not yet read (ServingEngine.step)."""
+    plan: StepPlan
+    outs: list                        # device outputs but cache and scores
+    scores: object                    # a DSG engine's refresh scores or None
+    seconds: float                    # the dispatch's host time
 
 
 def _restore_table(data, c):
@@ -195,15 +211,20 @@ def _micro_step(cfg, p, d, tok, c, pos, free, donor, csr, collect,
 
 
 def _one_step(cfg, p, d, ops, sample, refresh):
-    """A single-token decode program: ops = (tok (B, 1), cache, pos,
-    free_mask, donor, csr[, key, step, temps, top_ps]); the host has
-    already mirrored the free lanes' tokens and positions (begin_step).
+    """A single-token decode program: ops = (tok (B,), cache, pos,
+    free_mask, donor, prev, csr[, key, step, temps, top_ps]).  A lane
+    decodes its host token where `tok` >= 0, else its entry of `prev`:
+    the next tokens of the decode dispatched before this one, still on
+    the device (an ahead plan; ServingEngine.step).  Free lanes then
+    mirror the donor's token; the host has mirrored their positions.
     Returns (next tokens (B,), cache, scores or None); a MoE model's
     counts follow the tokens, (B + 3,), so that they reach the host in
     the tokens' transfer, and `commit_step` splits them."""
-    tok, c, pos, free_mask, donor, csr, *sampling = ops
-    nxt, c, scores, stats = _micro_step(cfg, p, d, tok, c, pos, free_mask,
-                                        donor, csr, refresh,
+    tok, c, pos, free_mask, donor, prev, csr, *sampling = ops
+    tok = jnp.where(tok >= 0, tok, prev[:tok.shape[0]])
+    tok = jnp.where(free_mask, tok[donor], tok)
+    nxt, c, scores, stats = _micro_step(cfg, p, d, tok[:, None], c, pos,
+                                        free_mask, donor, csr, refresh,
                                         sampling if sample else None)
     if stats is not None:
         nxt = jnp.concatenate([nxt, stats])
@@ -343,7 +364,8 @@ def sample_tokens(logits: jax.Array, keys: jax.Array, temps: jax.Array,
 
 @owned_by("worker", "queue", "done", "slots", "cache", "steps",
           "decode_seconds", "decode_tokens", "_next_tok", "_draws",
-          "_warned_truncation", "_prefill_cache", "prefill_cache_hits")
+          "_warned_truncation", "_prefill_cache", "prefill_cache_hits",
+          "_inflight", "_tok_dev")
 class ServingEngine:
     """Continuous batching over a fixed slot count.
 
@@ -447,6 +469,16 @@ class ServingEngine:
         # token each lane feeds to its next decode step (sampled from the
         # lane's latest logits; junk for free lanes, masked at emit time)
         self._next_tok = np.zeros(n_slots, np.int32)
+        # the same tokens on the device, as the latest decode returned them
+        # (a MoE model's counts after them): the `prev` operand of every
+        # single-token decode, whose lanes of an ahead plan decode them
+        # before the host has read them.  Zeros until the first decode,
+        # placed as the cache is, so that every dispatch passes one kind
+        # of array and hits one compiled program.
+        with jax.default_device(params_device(params)):
+            self._tok_dev = jnp.zeros(n_slots + 3 * cfg.is_moe, jnp.int32)
+        # the decode dispatched and not yet read (an _InFlight), or None
+        self._inflight = None
 
         # sampling is fused into the jitted decode step (one device
         # dispatch per step) — with a separate greedy-only variant so the
@@ -527,6 +559,13 @@ class ServingEngine:
         self._jit_decode = (
             jax.jit(greedy, donate_argnums=(3,), static_argnames=("refresh",)),
             jax.jit(sample, donate_argnums=(3,), static_argnames=("refresh",)))
+        # step() keeps one decode in flight when a step's operands need no
+        # host read of the step before it: not under DSG, whose next CSR
+        # operand comes from the scores read on the host, nor in a fused
+        # chunk, whose budgets and EOS freezes are settled at its commit
+        self._pipelined = self.dsg_rt is None and decode_chunk == 1
+        self._moe_none = (dict(moe_rows=0, moe_experts_hit=0, moe_rows_max=0)
+                          if cfg.is_moe else {})
 
     # -- public API ---------------------------------------------------------
 
@@ -544,9 +583,12 @@ class ServingEngine:
 
     @runs_on("worker")
     def run(self, max_steps: int = 10_000) -> Dict[int, Request]:
+        """Step until no request is queued or resident, or `max_steps`
+        decodes have been dispatched; commits the one in flight."""
         while (self.queue or any(not s.free for s in self.slots)) \
-                and self.steps < max_steps:
+                and self.steps + (self._inflight is not None) < max_steps:
             self.step()
+        self._settle()
         return self.done
 
     def drain(self, max_steps: int = 10_000) -> Dict[int, Request]:
@@ -815,12 +857,14 @@ class ServingEngine:
                      jnp.asarray(plan.free_mask),
                      jnp.asarray(plan.emit_left), jnp.asarray(plan.eos_ids))
         else:
-            lanes = (jnp.asarray(plan.tok)[:, None], self.cache,
-                     jnp.asarray(plan.pos), plan.free_mask, plan.donor)
+            lanes = (plan.tok, self.cache, jnp.asarray(plan.pos),
+                     plan.free_mask, plan.donor, self._tok_dev)
         csr = (None if self.dsg_rt is None
                else self.dsg_rt.device_csr(plan.bound))
-        keys = ((self._base_key, self.steps, plan.temps, plan.top_ps)
-                if sample else ())
+        # an ahead plan is dispatched while the step before it is
+        # uncommitted, so its index is one past the committed count
+        keys = ((self._base_key, self.steps + plan.ahead, plan.temps,
+                 plan.top_ps) if sample else ())
         return lanes + (csr,) + keys
 
     @runs_on("worker")
@@ -830,16 +874,19 @@ class ServingEngine:
         (and with `sample` the sampled one), and for a DSG engine each
         at every CSR width its runtime can reach, with and without the
         refresh scores.  Dispatches real decode steps on the idle plan
-        (`idle_plan`), whose writes nothing reads."""
+        (`idle_plan`), whose writes nothing reads, after committing the
+        step in flight."""
+        self._settle()
         rt = self.dsg_rt
         for bound, refresh, samp in itertools.product(
                 (0,) if rt is None else rt.warm_bounds(),
                 (False,) if rt is None else (False, True),
                 (False, True)[:1 + sample]):
             plan = dataclasses.replace(self.idle_plan(), bound=bound)
-            *_, self.cache, _ = self._jit_decode[samp](
+            *outs, self.cache, _ = self._jit_decode[samp](
                 self.params, self.dsg, *self.decode_operands(plan, samp),
                 refresh=refresh)
+            self._tok_dev = outs[-1]
 
     @runs_on("worker")
     def begin_step(self) -> Optional[StepPlan]:
@@ -848,13 +895,19 @@ class ServingEngine:
         write positions, and build the decode operands.  Returns None
         when no lane is active (and raises if prompts are queued but can
         never be admitted).  Callers must follow a non-None plan with
-        the jitted decode dispatch and `commit_step()` — `step()` is
-        that composition; replica executors batch the middle."""
+        the jitted decode dispatch and `commit_step()` — the synchronous
+        composition that replica executors batch the middle of; `step()`
+        keeps a decode in flight instead.  Commits the step `step()` left
+        in flight first."""
+        self._settle()
         with self.telemetry.span("repro.engine.begin"):
-            return self._begin_step()
+            self._boundary()
+            return self._plan(None)
 
     @runs_on("worker")
-    def _begin_step(self) -> Optional[StepPlan]:
+    def _boundary(self) -> None:
+        """The step boundary's fault hooks: a pending abort, then the
+        chaos harness."""
         if self.abort:
             # cleared here (not left sticky) so a restarted replica does
             # not immediately re-abort; ServingEngine.reset() also clears
@@ -867,10 +920,33 @@ class ServingEngine:
             # tokens land), delay sleeps inside the step, poison corrupts
             # resident outputs then raises — see runtime/fault_tolerance
             self.fault_injector.on_step(self)
-        admits = self._admit()
-        active = [i for i, s in enumerate(self.slots) if not s.free]
+
+    @runs_on("worker")
+    def _finished(self, i: int) -> bool:
+        """Whether lane `i`'s stream is complete: its last token is its
+        EOS, it holds `max_new` tokens, or its next write position is past
+        the cache.  The lane still decodes that last token once, and
+        leaves at the commit of that decode."""
+        slot = self.slots[i]
+        r = slot.req
+        return ((r.eos_id is not None and r.output[-1] == r.eos_id)
+                or len(r.output) >= r.max_new or slot.pos >= self.max_seq)
+
+    @runs_on("worker")
+    def _plan(self, inflight: Optional[StepPlan]) -> Optional[StepPlan]:
+        """Build the next decode's plan.  With no decode in flight: admit,
+        and every resident lane decodes its host token, emitted now.
+        Ahead of the decode `inflight`: no admission; each of its lanes
+        whose stream goes on decodes its token from the device, emitted
+        when `inflight` is committed, and any other resident lane its
+        host token."""
+        ahead = inflight is not None
+        admits = 0 if ahead else self._admit()
+        fed = set(inflight.active) if ahead else set()
+        active = [i for i, s in enumerate(self.slots)
+                  if not s.free and not (i in fed and self._finished(i))]
         if not active:
-            if self.queue:
+            if self.queue and not ahead:
                 raise RuntimeError(
                     "engine stalled: queued prompts cannot be admitted — "
                     "the paged cache pool is smaller than a single "
@@ -884,9 +960,12 @@ class ServingEngine:
         # with junk.  Mirrored lanes emit nothing; their K/V scribbles land
         # in a lane that the next admission fully overwrites (dense) or in
         # the donor's own pages as identical duplicates (paged — see
-        # kv_cache.decode_view) and are never observed.
+        # kv_cache.decode_view) and are never observed.  The decode program
+        # mirrors their tokens; their positions are mirrored here.
         donor = active[0]
+        live = set(active)
         tok = np.array(self._next_tok, np.int32)
+        tok[list(fed & live)] = -1
         pos = np.empty(self.n_slots, np.int32)
         free_mask = np.zeros(self.n_slots, np.bool_)
         temps = np.zeros(self.n_slots, np.float32)
@@ -897,9 +976,8 @@ class ServingEngine:
         pushes = 0                  # page-table pushes to the device
         with self.telemetry.span("repro.kv.grow") as grow:
             for i, s in enumerate(self.slots):
-                if s.free:
+                if i not in live:
                     free_mask[i] = True
-                    tok[i] = self._next_tok[donor]
                     pos[i] = self.slots[donor].pos
                 elif C == 1:
                     pos[i] = s.pos
@@ -930,10 +1008,12 @@ class ServingEngine:
             grow.set(pushes=pushes)
         if C == 1:
             for i in active:
-                r = self.slots[i].req
-                if not r.output:
-                    r.first_token = time.perf_counter()   # TTFT stamp
-                r.output.append(int(tok[i]))
+                s = self.slots[i]
+                s.pos += 1                 # the write is dispatched now
+                if tok[i] >= 0:
+                    if not s.req.output:
+                        s.req.first_token = time.perf_counter()   # TTFT
+                    s.req.output.append(int(tok[i]))
         # else emission happens on device and commit_chunk appends
         rt = self.dsg_rt
         refresh, bound = False, 0
@@ -962,25 +1042,41 @@ class ServingEngine:
                         free_mask=free_mask, temps=temps, top_ps=top_ps,
                         sample=bool((temps > 0).any()), chunk=C,
                         eos_ids=eos_ids, emit_left=emit_left,
-                        refresh=refresh, bound=bound, admits=admits)
+                        refresh=refresh, bound=bound, admits=admits,
+                        ahead=ahead)
 
     @runs_on("worker")
     def commit_step(self, plan: StepPlan, next_tok: np.ndarray,
                     seconds: float) -> dict:
         """Record a decode result: latch each lane's next input token,
-        account the device time/tokens, and retire finished lanes.
-        `next_tok` must already be host-side (the caller syncs — that is
-        where the device wait belongs in the timing).  A MoE step's
-        counts follow the tokens (make_decode_fns); they are counted and
-        returned as span attrs (empty for a dense model)."""
+        account the device time/tokens, and retire the lanes whose last
+        token this step decoded.  When a later plan already decodes these
+        tokens from the device (`plan.then`), they are emitted here, and a
+        stream they complete finishes now; its lane leaves at the commit
+        of that later decode, so no page is freed under a step that
+        writes it.  `next_tok` must already be host-side (the caller
+        syncs — that is where the device wait belongs in the timing).  A
+        MoE step's counts follow the tokens (make_decode_fns); they are
+        counted and returned as span attrs (empty for a dense model)."""
         with self.telemetry.span("repro.engine.commit") as span:
             self._next_tok = np.array(next_tok[:self.n_slots], np.int32)
             self.decode_seconds += seconds
             self.decode_tokens += len(plan.active)
             self.steps += 1
-            for i in plan.active:
-                self.slots[i].pos += 1
-            span.set(retired=len(self._retire(plan.active)))
+            then = plan.then
+            fed = [] if then is None else [i for i in then.active
+                                           if then.tok[i] < 0]
+            span.set(retired=len(self._retire(
+                [i for i in plan.active if i not in fed])))
+            for i in fed:
+                r = self.slots[i].req
+                r.output.append(int(next_tok[i]))
+                if r.eos_id is not None and r.output[-1] == r.eos_id:
+                    # the decode in flight was fed the stop token unseen;
+                    # its output is discarded at its commit
+                    self.telemetry.count("engine.lanes_past_eos")
+                if self._finished(i):
+                    self._finish_request(r)
             return self._count_moe(next_tok[self.n_slots:])
 
     @runs_on("worker")
@@ -1006,18 +1102,22 @@ class ServingEngine:
         retired = []
         for i in lanes:
             slot = self.slots[i]
-            r = slot.req
-            hit_eos = r.eos_id is not None and r.output[-1] == r.eos_id
-            if hit_eos or len(r.output) >= r.max_new \
-                    or slot.pos >= self.max_seq:
-                r.status = "ok"
-                r.finished = time.perf_counter()
-                self.done[r.uid] = r
+            if self._finished(i):
+                self._finish_request(slot.req)
                 slot.req = None
                 slot.pos = 0
                 self.cache = self.backend.free(self.cache, i)
                 retired.append(i)
         return retired
+
+    @runs_on("worker")
+    def _finish_request(self, r: Request) -> None:
+        """Stamp a complete stream `ok` and file it in `done` (once: a
+        stream completed ahead of its last decode keeps its stamp)."""
+        if r.status == "pending":
+            r.status = "ok"
+            r.finished = time.perf_counter()
+            self.done[r.uid] = r
 
     @runs_on("worker")
     def commit_chunk(self, plan: StepPlan, blk: np.ndarray,
@@ -1088,36 +1188,93 @@ class ServingEngine:
 
     @runs_on("worker")
     def step(self):
-        """One full engine step: begin (host) -> jitted decode (device)
-        -> commit (host).  Replica executors that batch the device half
-        across engines call the begin/commit halves directly."""
-        with self.telemetry.span("repro.engine.step",
-                                 step=self.steps) as span:
-            plan = self.begin_step()
+        """One engine step.  A pipelined engine keeps one decode in
+        flight: it builds and dispatches decode n+1 on decode n's tokens,
+        still on the device, and only then reads and commits decode n, so
+        its host work runs under the device's.  It reads decode n first
+        (a drain) where the synchronous order would admit a prompt, and
+        commits it without a successor once no lane goes on.  A DSG or
+        fused-chunk engine dispatches and commits each step in turn
+        (`_pipelined`)."""
+        tel = self.telemetry
+        with tel.span("repro.engine.step", step=self.steps) as span:
+            self._boundary()
+            attrs = dict(self._moe_none)
+            prev = self._inflight
+            if prev is not None and self._admits_next(prev.plan):
+                self._inflight = None
+                attrs.update(self._finish(prev))
+                prev = None
+            with tel.span("repro.engine.begin"):
+                plan = self._plan(None if prev is None else prev.plan)
             span.set(lanes=len(plan.active) if plan else 0,
-                     admits=plan.admits if plan else 0)
+                     admits=plan.admits if plan else 0,
+                     ahead=int(plan is not None and plan.ahead))
+            launch = None
             if plan is not None:
-                span.set(**self._decode(plan))
+                if plan.ahead:
+                    prev.plan.then = plan
+                    tel.count("engine.ahead_steps")
+                launch = self._launch(plan)
+            self._inflight = launch if self._pipelined else None
+            if prev is not None:
+                attrs.update(self._finish(prev))
+            if launch is not None and not self._pipelined:
+                attrs.update(self._finish(launch))
+            span.set(**attrs)
 
     @runs_on("worker")
-    def _decode(self, plan: StepPlan) -> dict:
-        """The device half of `step()` and its commit.  `decode_seconds`
-        gains the dispatch and the wait for its outputs, the spans
-        `repro.engine.dispatch` and `repro.engine.sync`.  Returns the
-        step span's MoE attrs (commit_step)."""
-        tel = self.telemetry
-        with tel.span("repro.engine.dispatch",
-                      kv_blocks=self._kv_blocks(plan)) as disp:
+    def _admits_next(self, inflight: StepPlan) -> bool:
+        """Whether the synchronous order would admit a prompt before the
+        next decode, judged before the decode in flight is read: a prompt
+        is queued and a lane leaves at that decode's commit, or a lane is
+        free and the queue's head fits (or has expired, which `_admit`
+        settles)."""
+        if not self.queue:
+            return False
+        if any(self._finished(i) for i in inflight.active):
+            return True
+        head = self.queue[0]
+        expired = (head.deadline_s is not None and
+                   time.perf_counter() - head.submitted > head.deadline_s)
+        return self.free_slots() > 0 and (
+            expired or self.can_admit_request(head))
+
+    @runs_on("worker")
+    def _launch(self, plan: StepPlan) -> _InFlight:
+        """Dispatch `plan`'s decode (enqueue only)."""
+        with self.telemetry.span("repro.engine.dispatch",
+                                 kv_blocks=self._kv_blocks(plan)) as disp:
             *outs, self.cache, scores = self._jit_decode[plan.sample](
                 self.params, self.dsg,
                 *self.decode_operands(plan, plan.sample),
                 refresh=plan.refresh)
+        self._tok_dev = outs[-1]
+        return _InFlight(plan, outs, scores, disp.seconds)
+
+    @runs_on("worker")
+    def _finish(self, launch: _InFlight) -> dict:
+        """Read a dispatched decode's outputs and commit it.
+        `decode_seconds` gains its dispatch and the wait for its outputs,
+        the spans `repro.engine.dispatch` and `repro.engine.sync`.  A read
+        that no later dispatch is queued behind counts as a drain.
+        Returns the step span's MoE attrs (commit_step)."""
+        plan, outs, scores, dispatch_s = launch
+        tel = self.telemetry
         with tel.span("repro.engine.sync") as sync:
             outs = [np.asarray(o) for o in outs]
-        attrs = self.commit(plan, outs, disp.seconds + sync.seconds)
+        if plan.then is None:
+            tel.count("engine.drains")
+        attrs = self.commit(plan, outs, dispatch_s + sync.seconds)
         if self.dsg_rt is not None:
             self._settle_dsg(plan, outs, scores)
         return attrs
+
+    @runs_on("worker")
+    def _settle(self) -> dict:
+        """Read and commit the decode in flight, if any."""
+        launch, self._inflight = self._inflight, None
+        return {} if launch is None else self._finish(launch)
 
     # -- fault containment (called by serving/router.py failover) ------------
     #
@@ -1137,7 +1294,10 @@ class ServingEngine:
         request does NOT land in `done`.  The partial output is kept —
         the caller decides between replay (the router's failover clears
         it so re-decode from the prompt is bit-identical at temperature
-        0) and surfacing the partial stream."""
+        0) and surfacing the partial stream.  The decode in flight is
+        committed first; a lane whose stream completed ahead of its last
+        decode is released and returns None, its request being done."""
+        self._settle()
         slot = self.slots[i]
         req = slot.req
         if req is None:
@@ -1147,7 +1307,7 @@ class ServingEngine:
         self.cache = self.backend.free(self.cache, i)
         if self.dsg_rt is not None:
             self.dsg_rt.reset_lane(i)
-        return req
+        return req if req.status == "pending" else None
 
     @runs_on("worker")
     def evict_request(self, uid: int) -> Optional[Request]:
@@ -1172,7 +1332,11 @@ class ServingEngine:
         requests that retired before the failure completed correctly.
         The engine itself stays warm (compiled callables, cache pool,
         PRNG base key) — this IS the replica restart path; a restarted
-        replica serves its next request with no recompilation."""
+        replica serves its next request with no recompilation.  The
+        decode in flight is discarded unread: the failure path must not
+        wait on a device that may be at fault, and every lane it wrote is
+        reclaimed here."""
+        self._inflight = None
         reclaimed = []
         for i in range(self.n_slots):
             req = self.evict_slot(i)

@@ -116,14 +116,15 @@ def test_decode_step_updates_pools_in_place(one_chip, monkeypatch):
                               "page_table": jax.ShapeDtypeStruct(
                                   (B, MAX_PAGES), jnp.int32)}),
                          "paged", PAGE)
-    lanes = put((jax.ShapeDtypeStruct((B, 1), jnp.int32),
+    lanes = put((jax.ShapeDtypeStruct((B,), jnp.int32),
                  jax.ShapeDtypeStruct((B,), jnp.int32),
                  jax.ShapeDtypeStruct((B,), jnp.bool_),
-                 jax.ShapeDtypeStruct((), jnp.int32)))
+                 jax.ShapeDtypeStruct((), jnp.int32),
+                 jax.ShapeDtypeStruct((B,), jnp.int32)))
     step = jax.jit(make_decode_fns(cfg)[0], donate_argnums=(3,))
-    tok, pos, free, donor = lanes
+    tok, pos, free, donor, prev = lanes
     compiled = step.lower(params, None, tok, handle, pos, free, donor,
-                          None).compile()
+                          prev, None).compile()
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
     assert "%paged_decode" in text
@@ -201,13 +202,15 @@ def test_moe_decode_step_updates_pools_in_place(one_chip, monkeypatch):
                               "page_table": jax.ShapeDtypeStruct(
                                   (B, MAX_PAGES), jnp.int32)}),
                          "paged", PAGE)
-    tok, pos, free, donor = put((jax.ShapeDtypeStruct((B, 1), jnp.int32),
-                                 jax.ShapeDtypeStruct((B,), jnp.int32),
-                                 jax.ShapeDtypeStruct((B,), jnp.bool_),
-                                 jax.ShapeDtypeStruct((), jnp.int32)))
+    tok, pos, free, donor, prev = put((
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.bool_),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((B + 3,), jnp.int32)))     # MoE counts after
     step = jax.jit(make_decode_fns(cfg)[0], donate_argnums=(3,))
     compiled = step.lower(params, None, tok, handle, pos, free, donor,
-                          None).compile()
+                          prev, None).compile()
     text = compiled.as_text()
     assert "%paged_decode" in text and "%moe_experts" in text
     assert not _pool_moves(compiled, layer_pool, pool)

@@ -301,8 +301,9 @@ def test_pools_cross_both_layer_scans(parts):
     pool = tuple(handle.data["pages_k"].shape)
     assert pool[0] == cfg.n_layers == 3
     closed = jax.make_jaxpr(make_decode_fns(cfg)[0])(
-        params, None, jnp.zeros((n_slots, 1), jnp.int32), handle,
-        jnp.zeros(n_slots, jnp.int32), jnp.zeros(n_slots, bool), 0, None)
+        params, None, jnp.zeros(n_slots, jnp.int32), handle,
+        jnp.zeros(n_slots, jnp.int32), jnp.zeros(n_slots, bool), 0,
+        jnp.zeros(n_slots + 3, jnp.int32), None)
     shape = lambda vs: [tuple(v.aval.shape) for v in vs]   # noqa: E731
     carried = []
     for n in (cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers):
@@ -334,9 +335,11 @@ def test_engine_counts_true_prompt_tokens_and_active_lanes(parts):
     tel = eng.telemetry
     (admit,) = tel.spans(name="repro.engine.admit")
     assert 0 < admit.attrs["moe_rows"] <= n_moe * most * len(prompt)
+    # each step span carries the counts of the decode it commits, one
+    # step after dispatching it; the first commits none
     steps = tel.spans(name="repro.engine.step")
-    assert len(steps) == 4
-    for s in steps:                                  # one active lane of 4
+    assert [bool(s.attrs["moe_rows"]) for s in steps] == [False] + [True] * 4
+    for s in steps[1:]:                              # one active lane of 4
         assert 0 < s.attrs["moe_rows"] <= n_moe * most
         assert s.attrs["moe_rows_max"] <= s.attrs["moe_rows"]
         assert 0 < s.attrs["moe_experts_hit"] <= n_moe * cfg.moe_held
@@ -365,8 +368,10 @@ def test_fused_chunk_counts_the_same_rows(parts):
         eng.run()
         rows[chunk] = eng.telemetry.counters["moe.rows"]
         streams[chunk] = {u: r.output for u, r in eng.done.items()}
-        steps = [s for s in eng.telemetry.spans(name="repro.engine.step")
-                 if s.attrs["lanes"]]
+        tel = eng.telemetry
+        commits = {s.parent for s in tel.spans(name="repro.engine.commit")}
+        steps = [s for s in tel.spans(name="repro.engine.step")
+                 if s.sid in commits]
         assert steps and all(s.attrs["moe_rows"] > 0 for s in steps)
     assert streams[8] == streams[1]
     assert rows[8] == rows[1] > 0

@@ -329,8 +329,9 @@ def test_decode_layer_scan_carries_the_pools(engine_parts, executor,
     handle = PagedBackend(page_size=8).make(cfg, n_slots, 64)
     pool = tuple(handle.data["pages_k"].shape)
     assert pool[0] == cfg.n_layers
-    args = (params, dsg, jnp.zeros((n_slots, 1), jnp.int32), handle,
-            jnp.zeros(n_slots, jnp.int32), jnp.zeros(n_slots, bool), 0)
+    args = (params, dsg, jnp.zeros(n_slots, jnp.int32), handle,
+            jnp.zeros(n_slots, jnp.int32), jnp.zeros(n_slots, bool), 0,
+            jnp.zeros(n_slots, jnp.int32))
     greedy = make_decode_fns(cfg, dsg=sparse_ffn)[0]
     if sparse_ffn:
         csr = {"idx": jnp.zeros((cfg.n_layers, n_slots, 2), jnp.int32),
@@ -372,16 +373,16 @@ def test_kernel_engine_stream_matches_dense(engine_parts, page_size,
 
 
 def _record_depths(monkeypatch):
-    """The lanes' depths of each step an engine begins from now on."""
-    depths, begin = [], ServingEngine.begin_step
+    """The lanes' depths of each step an engine plans from now on."""
+    depths, plan_step = [], ServingEngine._plan
 
-    def record(self):
-        plan = begin(self)
+    def record(self, inflight):
+        plan = plan_step(self, inflight)
         if plan is not None:
             depths.append(plan.pos.copy())
         return plan
 
-    monkeypatch.setattr(ServingEngine, "begin_step", record)
+    monkeypatch.setattr(ServingEngine, "_plan", record)
     return depths
 
 

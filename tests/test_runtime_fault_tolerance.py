@@ -120,10 +120,11 @@ def test_run_with_restarts_exhausts_retries():
 
 # -- serving chaos injector ---------------------------------------------------
 
-def _fake_engine(replica=0, steps=0, outputs=()):
-    slots = [SimpleNamespace(req=SimpleNamespace(output=list(o))
+def _fake_engine(replica=0, steps=0, outputs=(), statuses=None):
+    statuses = statuses or ["pending"] * len(outputs)
+    slots = [SimpleNamespace(req=SimpleNamespace(output=list(o), status=st)
                              if o is not None else None)
-             for o in outputs]
+             for o, st in zip(outputs, statuses)]
     # mirror the ServingEngine fields on_step()/attach() touch
     return SimpleNamespace(replica_index=replica, steps=steps,
                            slots=slots, fault_injector=None)
@@ -172,11 +173,14 @@ def test_serving_injector_delay_sleeps_without_raising():
 def test_serving_injector_poison_corrupts_resident_lanes():
     inj = ServingFaultInjector(
         [ReplicaFault(replica=0, step=2, kind="poison")])
-    eng = _fake_engine(steps=2, outputs=([5, 6], None, []))
+    eng = _fake_engine(steps=2, outputs=([5, 6], None, [], [7, 8]),
+                       statuses=["pending", None, "pending", "ok"])
     with pytest.raises(InjectedFault, match="poison"):
         inj.on_step(eng)
     assert eng.slots[0].req.output == [5, POISON_TOKEN]
     assert eng.slots[2].req.output == []       # nothing emitted yet
+    # a complete stream whose lane awaits its last decode's commit
+    assert eng.slots[3].req.output == [7, 8]
 
 
 def test_serving_injector_attach_detach():

@@ -2,8 +2,9 @@
 
 Each engine records, for every step, one span per host phase: the step,
 its begin half (admissions, page-table growth), the decode dispatch, the
-wait for its tokens and the commit; plus one span per admission and per
-retirement.  These tests pin that tree, its nesting and its attributes on
+wait for a decode's tokens and its commit (of the decode dispatched one
+step earlier, where the engine keeps one in flight); plus one span per
+admission and per retirement.  These tests pin that tree, its nesting and its attributes on
 a dense, a DSG and a fused-chunk engine, the bounded ring, the registry
 and the recorders of engines under the threaded executor.
 """
@@ -58,17 +59,28 @@ def test_span_tree(engine_parts, kind):
 
     steps = named["repro.engine.step"]
     decode = [s for s in steps if s.attrs["lanes"]]
-    assert all(set(s.attrs) == {"step", "lanes", "admits"} for s in steps)
+    assert all(set(s.attrs) == {"step", "lanes", "admits", "ahead"}
+               for s in steps)
     assert [s.attrs["step"] for s in steps] == sorted(
         s.attrs["step"] for s in steps)
     assert all(parent(s) is None for s in steps)
     for name in ("repro.engine.begin",):
         assert len(named[name]) == len(steps)
         assert all(parent(s) == "repro.engine.step" for s in named[name])
+    # one dispatch per decode step, one read and one commit per dispatch:
+    # in the step that dispatched it, or for a decode left in flight
+    # (dense) in the next step
     for name in ("repro.engine.dispatch", "repro.engine.sync",
                  "repro.engine.commit"):
         assert len(named[name]) == len(decode)
-        assert {s.parent for s in named[name]} == {s.sid for s in decode}
+        parents = {s.parent for s in named[name]}
+        if name == "repro.engine.dispatch" or kind != "dense":
+            assert parents == {s.sid for s in decode}
+        else:
+            assert parents <= {s.sid for s in steps}
+    ahead = [s.attrs["ahead"] for s in steps]
+    assert sum(ahead) == eng.telemetry.counters["engine.ahead_steps"]
+    assert any(ahead) == (kind == "dense")
     assert all(set(s.attrs) == {"kv_blocks"}
                for s in named["repro.engine.dispatch"])
     # one growth pass per step that has active lanes, inside begin
@@ -158,9 +170,11 @@ def test_dsg_flop_model_lives_in_counters(engine_parts):
     assert (st["steps"], st["dense_units"], st["csr_units"],
             st["bound_units"]) == (c["dsg.steps"], c["dsg.dense_units"],
                                    c["dsg.csr_units"], c["dsg.bound_units"])
+    engine = {k: v for k, v in c.items() if k.startswith("engine.")}
+    assert engine == {"engine.drains": eng.steps}   # one read per step
     eng.telemetry.count("other")
     eng.telemetry.reset_counters("dsg.")
-    assert dict(c) == {"other": 1}
+    assert dict(c) == {"other": 1, **engine}
     with pytest.raises(ValueError, match="no decode steps"):
         rt.flop_stats()
 
