@@ -1,6 +1,7 @@
 """Architecture registry: --arch <id> resolves here."""
 from repro.configs import (
     deepseek_moe_16b,
+    deepseek_v2_lite,
     internlm2_1_8b,
     llama3_2_3b,
     llama4_scout_17b_a16e,
@@ -16,7 +17,7 @@ from repro.configs.base import SHAPES, SMOKE_SHAPE, ModelConfig, ShapeConfig, sh
 _MODULES = (
     mistral_nemo_12b, internlm2_1_8b, llama3_2_3b, phi3_mini_3_8b,
     deepseek_moe_16b, llama4_scout_17b_a16e, xlstm_350m, llava_next_34b,
-    whisper_large_v3, zamba2_7b,
+    whisper_large_v3, zamba2_7b, deepseek_v2_lite,
 )
 
 ARCHS = {m.ARCH_ID: m for m in _MODULES}

@@ -47,6 +47,18 @@ class ModelConfig:
     dec_ratio: int = 8          # dec_len = seq_len // dec_ratio for enc-dec shapes
     # --- VLM ---
     vision_prefix: int = 0      # number of stub patch-embedding positions
+    # --- multi-head latent attention (DeepSeek-V2's MLA) ---
+    kv_lora_rank: int = 0       # latent width of the cached row; > 0 is MLA
+    qk_nope_dim: int = 0        # per-head query/key width without rotary
+    qk_rope_dim: int = 0        # rotary width, one key part shared by heads
+    v_head_dim: int = 0         # per-head value width
+    # --- YaRN rotary scaling (rope_scaling type "yarn") ---
+    rope_factor: float = 0.0    # 0 -> plain RoPE at rope_theta
+    rope_original_max: int = 0  # original_max_position_embeddings
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # --- attention ---
     window: int = 0             # sliding-window size (0 = full); used for
                                 # sub-quadratic long-context variants
@@ -85,6 +97,16 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or (self.d_model // self.n_heads)
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row(self) -> int:
+        """Width of an MLA layer's cached row: the latent and the shared
+        rotary key part."""
+        return self.kv_lora_rank + self.qk_rope_dim
 
     @property
     def is_moe(self) -> bool:

@@ -106,6 +106,20 @@ def paged_decode_attention(q, k_new, v_new, k_pages, v_pages, page_table,
         window=window, interpret=_interpret())
 
 
+@partial(jax.jit, static_argnames=("lat", "scale"))
+def mla_decode_attention(q, c_new, c_pages, page_table, pos, layer, *,
+                         lat: int, scale: float):
+    """Absorbed multi-head latent attention decode step
+    (kernels/paged_attention.mla_decode): write each lane's new latent row
+    into layer `layer` of the stacked pool and attend its rows 0..pos.
+
+    q (B, H, R), c_new (B, R), c_pages (L, P, ps, R), page_table
+    (B, max_pages), pos (B,), layer () -> (o (B, H, lat), c_pages')."""
+    return paged_attention.mla_decode(q, c_new, c_pages, page_table, pos,
+                                      layer, lat=lat, scale=scale,
+                                      interpret=_interpret())
+
+
 @jax.jit
 def moe_experts(x, starts, sizes, w_gate, w_up, w_down, layer=0):
     """Grouped SwiGLU over the held experts (kernels/moe_experts.py): x

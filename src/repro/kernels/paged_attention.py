@@ -1,5 +1,7 @@
 """Paged-attention decode (Pallas TPU): fused page-table scatter + a walk
-over blocks of each lane's live pages + flash-decode online softmax.
+over blocks of each lane's live pages + flash-decode online softmax; and
+`mla_decode`, multi-head latent attention's absorbed decode over a pool of
+latent rows on the same walk (the end of this file).
 
 Motivation (ROADMAP "Pallas gather kernel for decode"): the XLA paged
 decode path gathers the full `max_pages * page_size` logical window
@@ -97,51 +99,104 @@ def walk_blocks(pos, page_size: int, block: int) -> int:
     return int((-(-live // block)).sum())
 
 
-def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, hm_ref,
-            kp_hbm, vp_hbm, o_ref, ko_hbm, vo_hbm, kbuf, vbuf, sems,
-            wsems, slot_ref, *, scale: float, ps: int, kv: int,
-            window: int, width: int, ppb: int):
-    b = pl.program_id(0)
-    layer = layer_ref[0]
-    rows = ppb * ps * kv
-    d = q_ref.shape[-1]
+class _Walk:
+    """The walk over one lane's live pages that both decode kernels share
+    (`paged_decode` and `mla_decode`): grid step b is lane b, its pages
+    0..pos//ps copied block by block into two VMEM slots, the next block's
+    copies (the next lane's first block, when this lane ends) started
+    before this block's wait.
 
-    def last_page(lane):
+    `pools` pairs each stacked HBM pool with its (2, ppb, ps, ...) VMEM
+    slots; `sems` is a DMA semaphore array (2, len(pools)).  Building the
+    walk starts lane 0's first block (on grid step 0) and reads the lane's
+    position: `pos`, its deepest live page `lp` (clamped to the table's
+    `width`), the block count `nb`, the write row `off` in page `lp`,
+    `writes` (False only past the table's end) and `end`, the last position
+    the walk holds.  `loop` then runs the blocks."""
+
+    def __init__(self, pt_ref, pos_ref, layer_ref, pools, sems, slot_ref, *,
+                 ps: int, width: int, ppb: int):
+        self.pt_ref, self.pos_ref, self.pools = pt_ref, pos_ref, pools
+        self.sems, self.slot_ref, self.ppb = sems, slot_ref, ppb
+        self.b = b = pl.program_id(0)
+        self.layer = layer_ref[0]
+        self.ps, self.width = ps, width
+
+        @pl.when(b == 0)
+        def _first():
+            slot_ref[0] = 0
+            self.block_copies(0, 0, 0, wait=False)
+
+        self.pos = pos = pos_ref[b]
+        self.lp = lp = self.last_page(b)
+        self.nb = lp // ppb + 1
+        self.off = pos % ps
+        self.writes = pos // ps == lp     # False only past the table's end
+        # last attended position: the lane's own, or the table's end
+        self.end = jnp.minimum(pos, (lp + 1) * ps - 1)
+
+    def last_page(self, lane):
         # the lane's deepest live page, clamped to the page table's
         # width: a lane inside the table never clamps; one past it
         # (caller bug) walks the whole table and writes nothing, so no
         # page outside the table is read or written
-        return jnp.minimum(pos_ref[lane] // ps, width - 1)
+        return jnp.minimum(self.pos_ref[lane] // self.ps, self.width - 1)
 
-    def block_copies(lane, blk, slot, wait):
+    def block_copies(self, lane, blk, slot, wait):
         """Start, or wait for, the copies of block `blk` of `lane` into
-        `slot`: one per live page, K and V each on the slot's
-        semaphore."""
-        first = blk * ppb
+        `slot`: one per live page and pool, each pool on the slot's
+        semaphore of its own."""
+        first = blk * self.ppb
 
         def page(i, carry):
-            phys = pt_ref[lane, first + i]
-            for c, (src, buf) in enumerate(((kp_hbm, kbuf), (vp_hbm, vbuf))):
-                cp = pltpu.make_async_copy(src.at[layer, phys],
-                                           buf.at[slot, i], sems.at[slot, c])
+            phys = self.pt_ref[lane, first + i]
+            for c, (src, buf) in enumerate(self.pools):
+                cp = pltpu.make_async_copy(src.at[self.layer, phys],
+                                           buf.at[slot, i],
+                                           self.sems.at[slot, c])
                 cp.wait() if wait else cp.start()
             return carry
 
-        n = jnp.minimum(last_page(lane) - first + 1, ppb)
+        n = jnp.minimum(self.last_page(lane) - first + 1, self.ppb)
         jax.lax.fori_loop(0, n, page, 0)
 
-    @pl.when(b == 0)
-    def _first():
-        slot_ref[0] = 0
-        block_copies(0, 0, 0, wait=False)
+    def loop(self, attend, init):
+        """Walk the lane's blocks: attend(blk, slot, *carry) -> carry runs
+        on each block once its copies have landed in `slot`.  Returns the
+        last carry and the slot after the last block's (the next lane's
+        first); the last block sat in the other one."""
+        b, nb = self.b, self.nb
 
-    pos = pos_ref[b]
-    lp = last_page(b)
-    nb = lp // ppb + 1
-    off = pos % ps
-    writes = pos // ps == lp          # False only past the table's end
-    # last attended position: the lane's own, or the table's end
-    end = jnp.minimum(pos, (lp + 1) * ps - 1)
+        def body(blk, carry):
+            *acc, slot = carry
+            nxt = 1 - slot
+
+            @pl.when(blk + 1 < nb)
+            def _next_block():
+                self.block_copies(b, blk + 1, nxt, wait=False)
+
+            @pl.when((blk + 1 == nb) & (b + 1 < pl.num_programs(0)))
+            def _next_lane():
+                self.block_copies(b + 1, 0, nxt, wait=False)
+
+            self.block_copies(b, blk, slot, wait=True)
+            return (*attend(blk, slot, *acc), nxt)
+
+        *acc, slot = jax.lax.fori_loop(0, nb, body,
+                                       (*init, self.slot_ref[0]))
+        self.slot_ref[0] = slot
+        return acc, slot
+
+
+def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, hm_ref,
+            kp_hbm, vp_hbm, o_ref, ko_hbm, vo_hbm, kbuf, vbuf, sems,
+            wsems, slot_ref, *, scale: float, ps: int, kv: int,
+            window: int, width: int, ppb: int):
+    w = _Walk(pt_ref, pos_ref, layer_ref, ((kp_hbm, kbuf), (vp_hbm, vbuf)),
+              sems, slot_ref, ps=ps, width=width, ppb=ppb)
+    b, layer, pos, lp, off, end = w.b, w.layer, w.pos, w.lp, w.off, w.end
+    rows = ppb * ps * kv
+    d = q_ref.shape[-1]
     q = q_ref[0].astype(jnp.float32)                     # (H, D)
 
     def row_writes(slot, i):
@@ -153,21 +208,8 @@ def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, hm_ref,
                 for c, (buf, dst) in enumerate(((kbuf, ko_hbm),
                                                 (vbuf, vo_hbm)))]
 
-    def body(blk, carry):
-        m_prev, l_prev, acc, slot = carry
-        nxt = 1 - slot
-
-        @pl.when(blk + 1 < nb)
-        def _next_block():
-            block_copies(b, blk + 1, nxt, wait=False)
-
-        @pl.when((blk + 1 == nb) & (b + 1 < pl.num_programs(0)))
-        def _next_lane():
-            block_copies(b + 1, 0, nxt, wait=False)
-
-        block_copies(b, blk, slot, wait=True)
-
-        @pl.when((blk + 1 == nb) & writes)
+    def attend(blk, slot, m_prev, l_prev, acc):
+        @pl.when((blk + 1 == w.nb) & w.writes)
         def _insert():
             # cast to the pool dtype FIRST so the stored and attended
             # values match the XLA scatter
@@ -207,19 +249,18 @@ def _kernel(pt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, hm_ref,
         acc = acc * corr + jax.lax.dot_general(
             p, v_t, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return m_new, l_new, acc, nxt
+        return m_new, l_new, acc
 
     h = q.shape[0]
     init = (jnp.full((h, 1), NEG, jnp.float32),
             jnp.zeros((h, 1), jnp.float32),
-            jnp.zeros((h, d), jnp.float32), slot_ref[0])
-    _, l_run, acc, slot = jax.lax.fori_loop(0, nb, body, init)
-    slot_ref[0] = slot
+            jnp.zeros((h, d), jnp.float32))
+    (_, l_run, acc), slot = w.loop(attend, init)
 
-    @pl.when(writes)
+    @pl.when(w.writes)
     def _wait_writes():
         # the last block sat in the slot before `slot`
-        for cp in row_writes(1 - slot, lp - (nb - 1) * ppb):
+        for cp in row_writes(1 - slot, lp - (w.nb - 1) * ppb):
             cp.wait()
 
     o_ref[0] = (acc / jnp.maximum(l_run, 1e-20)).astype(o_ref.dtype)
@@ -312,3 +353,134 @@ def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
       jnp.reshape(layer, (1,)).astype(jnp.int32),
       q, k_new, v_new, head_mask, k_pages, v_pages)
     return o, kp, vp
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2's MLA), absorbed form
+# ---------------------------------------------------------------------------
+
+def _mla_kernel(pt_ref, pos_ref, layer_ref, q_ref, cn_ref, cp_hbm, o_ref,
+                co_hbm, cbuf, sems, wsem, slot_ref, *, scale: float,
+                ps: int, width: int, ppb: int, lat: int):
+    w = _Walk(pt_ref, pos_ref, layer_ref, ((cp_hbm, cbuf),), sems,
+              slot_ref, ps=ps, width=width, ppb=ppb)
+    rows = ppb * ps
+    r = q_ref.shape[-1]
+    q = q_ref[0]                                         # (H, R)
+
+    def page_write(slot, i):
+        """The copy of page `i` of block slot `slot`, which holds the new
+        row, back to the lane's write page in the pool."""
+        return pltpu.make_async_copy(
+            cbuf.at[slot, i], co_hbm.at[w.layer, pt_ref[w.b, w.lp]],
+            wsem.at[0])
+
+    def attend(blk, slot, m_prev, l_prev, acc):
+        @pl.when((blk + 1 == w.nb) & w.writes)
+        def _insert():
+            # a bf16 row is half a packed sublane, too narrow for a store
+            # or a copy of its own: the new row goes into its page by a
+            # select over the whole page, and the page goes back whole
+            i = w.lp - blk * ppb
+            page = cbuf[slot, i]
+            at = jax.lax.broadcasted_iota(jnp.int32, page.shape, 0) == w.off
+            cbuf[slot, i] = jnp.where(at, cn_ref[0], page)
+            page_write(slot, i).start()
+
+        c_t = cbuf[slot].reshape(rows, r)
+        s = jax.lax.dot_general(
+            q, c_t, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (H, rows)
+        hi = w.end - blk * rows + 1
+        valid = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < hi
+        s = jnp.where(valid, s, NEG)
+        # the first `lat` columns of a row are its value; rows past `end`
+        # hold junk: zero them so 0 * junk cannot reach acc
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, lat), 0)
+        v_t = jnp.where(row < hi, c_t[:, :lat], jnp.zeros((), c_t.dtype))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            p.astype(v_t.dtype), v_t, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    h = q.shape[0]
+    init = (jnp.full((h, 1), NEG, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, lat), jnp.float32))
+    (_, l_run, acc), slot = w.loop(attend, init)
+
+    @pl.when(w.writes)
+    def _wait_write():
+        # the last block sat in the slot before `slot`
+        page_write(1 - slot, w.lp - (w.nb - 1) * ppb).wait()
+
+    o_ref[0] = (acc / jnp.maximum(l_run, 1e-20)).astype(o_ref.dtype)
+
+
+def mla_decode(q: jax.Array, c_new: jax.Array, c_pages: jax.Array,
+               page_table: jax.Array, pos: jax.Array, layer, *, lat: int,
+               scale: float, interpret: bool = False):
+    """One decode step of multi-head latent attention over a paged pool of
+    latent rows, in the absorbed form.
+
+    q (B, H, R): each head's query against a cached row, `lat` columns of
+    q_nope W_UK then the roped q_pe (R = lat + rotary width); c_new (B, R)
+    the new token's row, [normalised latent c | roped k_pe]; c_pages
+    (L, P, ps, R) every layer's pool of rows, shared by all heads;
+    page_table (B, max_pages) int32; pos (B,) int32 write positions;
+    layer () int32.  Scores are q . row x `scale`, softmax over the lane's
+    rows 0..pos (the new one included), and the output (B, H, lat) is the
+    softmax-weighted sum of each row's first `lat` columns (the latent is
+    both key and value, stored once).  Returns (o, c_pages') with the new
+    row written at `pos` in layer `layer`, in place.
+
+    The walk over each lane's pages is `paged_decode`'s (`_Walk`).  In the
+    lane's last block the new row is put into its page in VMEM before the
+    block is read, so the token attends itself, and that page is copied
+    back to the pool whole: a bf16 row is half of a packed sublane, which
+    no store or copy addresses alone.  R must be a multiple of 128, the
+    lane tiling of the pool in HBM (models/mla.py pads the rows).
+    Products take the pool's dtype with f32 accumulation; softmax
+    statistics are f32."""
+    b, h, r = q.shape
+    ps = c_pages.shape[2]
+    max_pages = page_table.shape[1]
+    ppb = pages_per_block(ps, 1, r, c_pages.dtype, max_pages)
+    lane = lambda bb, pt, pos_, ly: (bb, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,      # page_table, pos, layer
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, r), lane),
+                  pl.BlockSpec((1, 1, r), lane),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec((1, h, lat), lane),
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, ps, r), c_pages.dtype),   # row slots
+            pltpu.SemaphoreType.DMA((2, 1)),    # [slot, pool] block reads
+            pltpu.SemaphoreType.DMA((1,)),      # the written page
+            pltpu.SMEM((1,), jnp.int32),        # slot of the lane's block 0
+        ],
+    )
+    o, cp = pl.pallas_call(
+        functools.partial(_mla_kernel, scale=scale, ps=ps, width=max_pages,
+                          ppb=ppb, lat=lat),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, h, lat), q.dtype),
+                   jax.ShapeDtypeStruct(c_pages.shape, c_pages.dtype)],
+        # flat operand 5 = c_pages (after the 3 scalar-prefetch args)
+        # aliases pool output 1 (in place)
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="mla_decode",
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q.astype(c_pages.dtype), c_new.astype(c_pages.dtype)[:, None],
+      c_pages)
+    return o, cp

@@ -12,7 +12,8 @@ make_cache builds the dense worst-case layout; serving picks the cache
 LAYOUT through repro.serving.kv_cache backends ("dense" | "paged") and
 passes the backend's view into prefill/decode_step — decoder-family
 decode also accepts the paged view ({'pages_k','pages_v','page_table'},
-see serving/kv_cache.py).
+or a latent-attention model's {'pages_c','page_table'}; see
+serving/kv_cache.py).
 """
 from __future__ import annotations
 

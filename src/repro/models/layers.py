@@ -58,6 +58,57 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+# --- YaRN (rope_scaling type "yarn", DeepSeek-V2) ---------------------------
+#
+# YaRN re-tunes the rotary frequencies of every position, not only past the
+# original context: pairs rotating fewer than `beta_slow` times over the
+# original context are divided by `factor`, pairs rotating more than
+# `beta_fast` times keep theirs, and the pairs between blend linearly.
+
+def yarn_correction_range(dim: int, theta: float, original_max: int,
+                          beta_fast: float, beta_slow: float) -> tuple:
+    """(low, high) pair indices of the blend, clamped to [0, dim - 1]."""
+    def at(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(at(beta_fast)), 0),
+            min(math.ceil(at(beta_slow)), dim - 1))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 mscale ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, original_max: int,
+               beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN inverse frequencies (dim/2,): the plain ones below the
+    correction range, divided by `factor` above it, blended between."""
+    extra = rope_freqs(dim, theta)
+    low, high = yarn_correction_range(dim, theta, original_max, beta_fast,
+                                      beta_slow)
+    high = high + 0.001 if low == high else high
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope_pairs(x: jax.Array, positions: jax.Array,
+                     inv_freq: jax.Array, mscale: float = 1.0) -> jax.Array:
+    """Rotary embedding in DeepSeek-V2's layout: x (..., S, H, dim) holds
+    its pairs interleaved, (x0, x1), (x2, x3), ...; they are gathered to
+    the two halves and rotated as rotate-half RoPE at `inv_freq` (dim/2,),
+    cos and sin scaled by `mscale`.  positions: broadcastable to (..., S)."""
+    dim = x.shape[-1]
+    x = x.astype(jnp.float32)
+    x = jnp.swapaxes(x.reshape(x.shape[:-1] + (dim // 2, 2)), -1, -2)
+    x1, x2 = x[..., 0, :], x[..., 1, :]
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = (jnp.cos(angles) * mscale)[..., None, :]
+    sin = (jnp.sin(angles) * mscale)[..., None, :]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
 # --- init helpers ------------------------------------------------------------
 
 def dense_init(key: jax.Array, shape, fan_in: Optional[int] = None,

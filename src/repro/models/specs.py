@@ -147,6 +147,9 @@ def cache_specs(cache, cfg: ModelConfig, ax: Axes, n_model: int):
             lead = (None,) * (leaf.ndim - 4)
             # (..., B, S, Kv, D)
             return P(*lead, b, m, None, None)
+        if name == "c" and cfg.is_mla:
+            # latent rows (L, B, S, R), sequence-sharded as K/V are
+            return P(None, b, m, None)
         # recurrent states: (..., B, ...) with leading stack dims
         if name in ("ssm", "conv_x", "conv_bc", "m_c", "m_n",
                     "c", "n", "m", "h"):

@@ -24,6 +24,7 @@ from repro.configs.base import ModelConfig
 from repro.core import dsg_linear as dl
 from repro.core import projection
 from repro.models import attention as attn
+from repro.models import mla
 from repro.models import moe as moe_mod
 from repro.models.layers import embed_init, norm_apply, norm_init
 
@@ -43,8 +44,9 @@ def init_layer(key: jax.Array, cfg: ModelConfig, dense: bool = False) -> dict:
     dt = _dtype(cfg)
     p = {
         "ln_attn": norm_init(cfg.norm, cfg.d_model, dt),
-        "attn": attn.init_attention(ka, cfg.d_model, cfg.n_heads, cfg.n_kv,
-                                    cfg.head_dim, dt),
+        "attn": (mla.init_mla(ka, cfg, dt) if cfg.is_mla else
+                 attn.init_attention(ka, cfg.d_model, cfg.n_heads, cfg.n_kv,
+                                     cfg.head_dim, dt)),
         "ln_ffn": norm_init(cfg.norm, cfg.d_model, dt),
     }
     if cfg.is_moe and not dense:
@@ -200,7 +202,7 @@ def _drs_scores(h: jax.Array, r: jax.Array, fw: jax.Array,
 def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
            page_table, mesh, batch_axes, csr_l=None,
            collect_scores: bool = False, layer=None, count=None,
-           experts=None, moe_layer=None):
+           experts=None, moe_layer=None, fresh: bool = False):
     from repro.parallel import context as pctx
 
     def boundary(t):
@@ -219,13 +221,18 @@ def _block(p: dict, dsg_l, r, x, cfg: ModelConfig, q_pos, cache, cache_pos,
         ba = pctx.batch_axes()
         x = pctx.constrain(x, ba, "model", None)
     h = norm_apply(cfg.norm, p["ln_attn"], x, cfg.norm_eps)
-    a, new_cache = attn.self_attention(
-        p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
-        rope_theta=cfg.rope_theta, q_pos=q_pos, causal=True,
-        window=cfg.window, cache=cache, cache_pos=cache_pos,
-        page_table=page_table, layer=layer,
-        paged_kernel=cfg.paged_attn_kernel, shard=cfg.attn_shard,
-        bf16_scores=cfg.attn_bf16_scores)
+    if cfg.is_mla:
+        a, new_cache = mla.self_attention(
+            p["attn"], h, cfg, q_pos=q_pos, cache=cache, cache_pos=cache_pos,
+            page_table=page_table, layer=layer, fresh=fresh)
+    else:
+        a, new_cache = attn.self_attention(
+            p["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
+            rope_theta=cfg.rope_theta, q_pos=q_pos, causal=True,
+            window=cfg.window, cache=cache, cache_pos=cache_pos,
+            page_table=page_table, layer=layer,
+            paged_kernel=cfg.paged_attn_kernel, shard=cfg.attn_shard,
+            bf16_scores=cfg.attn_bf16_scores)
     x = x + boundary(a)
     h = norm_apply(cfg.norm, p["ln_ffn"], x, cfg.norm_eps)
     scores = None
@@ -258,10 +265,12 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
     reads them to rewrite patterns off the measured decode window.
 
     prefix_embeds (B, P, d): VLM stub patch embeddings, prepended.
-    cache: stacked per-layer KV {'k': (L,B,Smax,Kv,D), 'v': ...} for decode,
+    cache: stacked per-layer KV {'k': (L,B,Smax,Kv,D), 'v': ...} for decode
+    (an MLA model's: {'c': (L,B,Smax,R)} latent rows, models/mla.py),
     or a paged-backend view {'pages_k': (L,P,ps,Kv,D), 'pages_v': ...,
-    'page_table': (B, max_pages)} (see serving/kv_cache.py; the page table
-    is shared by all layers, so it rides outside the layer scan).  The
+    'page_table': (B, max_pages)} (MLA: {'pages_c': (L,P,ps,R), ...}; see
+    serving/kv_cache.py; the page table is shared by all layers, so it
+    rides outside the layer scan).  The
     dense cache is scanned as xs/ys; the paged pools ride the scan's carry
     and each layer's attention addresses them at its layer index, so
     the pools are updated in place and no layer's pool is sliced out of
@@ -276,7 +285,9 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
     page_table = None
     if cache is not None and "page_table" in cache:
         page_table = cache["page_table"]
-        cache = {"k": cache["pages_k"], "v": cache["pages_v"]}
+        cache = pool_leaves(cache)
+    # a prompt written from position 0 (python-static): MLA's prefill
+    fresh = isinstance(pos0, int) and pos0 == 0
     x = params["embed"].astype(_dtype(cfg))[tokens]
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
@@ -311,7 +322,7 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
             p_l, dsg_l, r, xc, cfg, q_pos,
             cache_l if pools is None else pools, pos0, page_table,
             mesh, batch_axes, csr_l, collect_drs_scores, layer,
-            moe_count, experts, i)
+            moe_count, experts, i, fresh)
         if pools is None:
             return (y, None), (new_cache, aux, scores, stats)
         return (y, new_cache), (None, aux, scores, stats)
@@ -339,7 +350,7 @@ def forward(params: dict, dsg: Optional[dict], cfg: ModelConfig,
         carry, stack, dsg_stack, n_dense, cfg.n_layers - n_dense, ffn_csr,
         experts is not None)
     if page_table is not None:
-        new_cache = {"pages_k": pools["k"], "pages_v": pools["v"],
+        new_cache = {**{POOL + k: v for k, v in pools.items()},
                      "page_table": page_table}
     elif cache is not None and n_dense:
         new_cache = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
@@ -384,18 +395,39 @@ def train_loss(params: dict, dsg: Optional[dict], cfg: ModelConfig,
     return cross_entropy(logits, labels) + 0.01 * aux
 
 
+#: prefix of a paged view's pool leaves: pool 'k' is the view's 'pages_k'
+POOL = "pages_"
+
+
+def pool_leaves(view: dict) -> dict:
+    """The pools of a paged view by their cache names ({'k', 'v'}, or MLA's
+    {'c'}), without the page table."""
+    return {k[len(POOL):]: v for k, v in view.items() if k.startswith(POOL)}
+
+
+def cache_row(cfg: ModelConfig) -> dict:
+    """Trailing shape of one token's cache entry in each pool leaf: K and
+    V (Kv, D) each, or an MLA model's one latent row (R,)."""
+    if cfg.is_mla:
+        return {"c": (mla.row_width(cfg),)}
+    row = (cfg.n_kv, cfg.head_dim)
+    return {"k": row, "v": row}
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
                dtype=jnp.float32) -> dict:
-    shape = (cfg.n_layers, batch, max_seq, cfg.n_kv, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    """Dense cache: each leaf (L, batch, max_seq, *row) (`cache_row`)."""
+    return {k: jnp.zeros((cfg.n_layers, batch, max_seq) + row, dtype)
+            for k, row in cache_row(cfg).items()}
 
 
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      dtype=jnp.float32) -> dict:
     """Physical page pool for the paged KV-cache backend
-    (serving/kv_cache.py): K/V each (L, n_pages, page_size, Kv, D)."""
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    (serving/kv_cache.py): each leaf (L, n_pages, page_size, *row) —
+    K/V (Kv, D) each, or MLA's one latent row."""
+    return {k: jnp.zeros((cfg.n_layers, n_pages, page_size) + row, dtype)
+            for k, row in cache_row(cfg).items()}
 
 
 def prefill(params, dsg, cfg: ModelConfig, tokens, cache,

@@ -22,6 +22,13 @@ in the static treedef.
     pages_k / pages_v : (L, n_pages, page_size, Kv, D)   physical pool
     page_table        : (n_slots, max_seq // page_size)  int32 logical->physical
 
+A multi-head latent attention model (models/mla.py) has one pool leaf
+instead, `pages_c` (L, n_pages, page_size, R): one latent row per token,
+shared by the heads.  Every pool operation (merge, subset merge, page copy,
+resident bytes) goes over the pool leaves the handle holds, whichever they
+are: `pages_<name>` is the pool of the dense cache's leaf `<name>`
+(`transformer.cache_row`).
+
 A host-side free-list `BlockAllocator` hands out physical pages; lanes
 allocate pages as `pos` grows and return them on retirement, so short
 requests stop paying worst-case `Smax` memory — the DSG move (exploit
@@ -81,7 +88,8 @@ class CacheHandle:
     """Opaque KV-cache pytree + static layout tag.
 
     `data` holds the device arrays (dense: {'k','v'}; paged:
-    {'pages_k','pages_v','page_table'}); `kind`/`page_size` are static
+    {'pages_k','pages_v','page_table'}; an MLA model's: {'c'} and
+    {'pages_c','page_table'}); `kind`/`page_size` are static
     aux data, so jitted functions can rebuild the handle around updated
     leaves without retracing on layout.
     """
@@ -352,22 +360,37 @@ def prefix_chain(tokens: np.ndarray, page_size: int) -> list:
     return keys
 
 
+def pools_of(data: dict) -> dict:
+    """The pool leaves of a paged handle's data, without the page table."""
+    return {k: v for k, v in data.items() if k.startswith(transformer.POOL)}
+
+
+def _page_size(pools: dict) -> int:
+    return next(iter(pools.values())).shape[2]
+
+
+def _lane_pages(lane_leaf: jax.Array, n_lp: int, ps: int) -> jax.Array:
+    """The first `n_lp` pages of a 1-lane dense cache leaf (L, 1, S, *row):
+    (L, n_lp, ps, *row)."""
+    l, _, _, *row = lane_leaf.shape
+    return lane_leaf[:, 0, :n_lp * ps].reshape(l, n_lp, ps, *row)
+
+
 def _paged_merge(pools: dict, lane: dict, pp: jax.Array) -> dict:
     """Scatter the leading `len(pp)` pages of a 1-lane dense cache into the
     physical pages `pp` of the pool (one compile per page count, i.e. per
     prompt bucket).  Freshly allocated pages are fully overwritten, so a
     previous occupant's K/V cannot leak.
     """
-    ps = pools["pages_k"].shape[2]
+    ps = _page_size(pools)
     n_lp = pp.shape[0]
 
     def upd(pool, lane_leaf):
-        l, _, _, kv, d = lane_leaf.shape
-        chunks = lane_leaf[:, 0, :n_lp * ps].reshape(l, n_lp, ps, kv, d)
+        chunks = _lane_pages(lane_leaf, n_lp, ps)
         return pool.at[:, pp].set(chunks.astype(pool.dtype))
 
-    return {"pages_k": upd(pools["pages_k"], lane["k"]),
-            "pages_v": upd(pools["pages_v"], lane["v"])}
+    return {k: upd(pool, lane[k[len(transformer.POOL):]])
+            for k, pool in pools.items()}
 
 
 def _paged_merge_subset(pools: dict, lane: dict, pp: jax.Array,
@@ -377,25 +400,22 @@ def _paged_merge_subset(pools: dict, lane: dict, pp: jax.Array,
     pages into physical pages `pp` — shared pages already hold identical
     bytes and must not be rewritten (other lanes read them).  One
     compile per (n_lp, len(lps)) pair."""
-    ps = pools["pages_k"].shape[2]
+    ps = _page_size(pools)
 
     def upd(pool, lane_leaf):
-        l, _, _, kv, d = lane_leaf.shape
-        chunks = lane_leaf[:, 0, :n_lp * ps].reshape(l, n_lp, ps, kv, d)
+        chunks = _lane_pages(lane_leaf, n_lp, ps)
         return pool.at[:, pp].set(chunks[:, lps].astype(pool.dtype))
 
-    return {"pages_k": upd(pools["pages_k"], lane["k"]),
-            "pages_v": upd(pools["pages_v"], lane["v"])}
+    return {k: upd(pool, lane[k[len(transformer.POOL):]])
+            for k, pool in pools.items()}
 
 
 def _page_copy(pools: dict, src: jax.Array, dst: jax.Array) -> dict:
     """Copy one physical page across every layer (the copy half of
     copy-on-write).  src/dst ride as traced scalars — one compile
     total, not one per page id."""
-    return {"pages_k": pools["pages_k"].at[:, dst]
-            .set(pools["pages_k"][:, src]),
-            "pages_v": pools["pages_v"].at[:, dst]
-            .set(pools["pages_v"][:, src])}
+    return {k: pool.at[:, dst].set(pool[:, src])
+            for k, pool in pools.items()}
 
 
 class PagedBackend(_Backend):
@@ -460,8 +480,8 @@ class PagedBackend(_Backend):
         self._table = np.full((n_slots, self.max_pages), NULL_PAGE, np.int32)
         self._resv = np.zeros(n_slots, np.int64)
         # the pools' device: every later page-table push lands beside them
-        (self._device,) = pool["k"].devices()
-        data = {"pages_k": pool["k"], "pages_v": pool["v"],
+        (self._device,) = next(iter(pool.values())).devices()
+        data = {**{transformer.POOL + k: v for k, v in pool.items()},
                 "page_table": self._device_table()}
         return CacheHandle(data, "paged", self.page_size)
 
@@ -555,8 +575,7 @@ class PagedBackend(_Backend):
         tail = 1 if sharing and n_tokens % self.page_size else 0
         self._resv[slot] = need - n_lp + tail
         with self.telemetry.span("repro.kv.write", pages=len(fresh_lps)):
-            pools = {"pages_k": handle.data["pages_k"],
-                     "pages_v": handle.data["pages_v"]}
+            pools = pools_of(handle.data)
             if fresh_lps:
                 if slot_kv is None:
                     raise ValueError(
@@ -585,10 +604,8 @@ class PagedBackend(_Backend):
         self._table[slot, lp] = new
         self._resv[slot] = max(int(self._resv[slot]) - 1, 0)
         self.cow_copies += 1
-        pools = self._copy_page(
-            {"pages_k": handle.data["pages_k"],
-             "pages_v": handle.data["pages_v"]},
-            jnp.int32(old), jnp.int32(new))
+        pools = self._copy_page(pools_of(handle.data),
+                                jnp.int32(old), jnp.int32(new))
         pools["page_table"] = self._device_table()
         return CacheHandle(pools, "paged", self.page_size)
 

@@ -785,6 +785,7 @@ class ServingEngine:
             self.cache = self.backend.write(self.cache, lane, i,
                                             n_tokens=pb, reserve_tokens=need,
                                             chain=chain)
+            tel.count("kv.rows_written", pb)
             # _draws advances for every admission so the sampling key
             # schedule doesn't depend on how many greedy requests preceded
             self._draws += 1
@@ -820,11 +821,19 @@ class ServingEngine:
         if self.cache.kind != "paged" or not _use_paged_kernel(
                 self.cfg.paged_attn_kernel):
             return 0
-        pool = self.cache.data["pages_k"]
-        _, _, ps, kv, d = pool.shape
+        pool = next(iter(kv_cache.pools_of(self.cache.data).values()))
+        _, _, ps, *row = pool.shape
+        kv, d = row if len(row) == 2 else (1, row[0])   # K/V, or MLA rows
         block = paged_attention.pages_per_block(
             ps, kv, d, pool.dtype, self.cache.data["page_table"].shape[1])
         return paged_attention.walk_blocks(plan.pos, ps, block)
+
+    @staticmethod
+    def _kv_rows(plan: StepPlan) -> int:
+        """Keys the step's attention reads at the active lanes' true
+        depths, summed over lanes: a lane writing at `pos` attends rows
+        0..pos.  A fused chunk counts its first micro-step."""
+        return int((plan.pos[plan.active] + 1).sum()) if plan.active else 0
 
     def idle_plan(self) -> StepPlan:
         """A plan with no active lane: every lane mirrors donor 0 at
@@ -1243,8 +1252,11 @@ class ServingEngine:
     @runs_on("worker")
     def _launch(self, plan: StepPlan) -> _InFlight:
         """Dispatch `plan`'s decode (enqueue only)."""
+        self.telemetry.count("kv.rows_written",
+                             len(plan.active) * plan.chunk)
         with self.telemetry.span("repro.engine.dispatch",
-                                 kv_blocks=self._kv_blocks(plan)) as disp:
+                                 kv_blocks=self._kv_blocks(plan),
+                                 kv_rows=self._kv_rows(plan)) as disp:
             *outs, self.cache, scores = self._jit_decode[plan.sample](
                 self.params, self.dsg,
                 *self.decode_operands(plan, plan.sample),

@@ -81,7 +81,7 @@ def test_span_tree(engine_parts, kind):
     ahead = [s.attrs["ahead"] for s in steps]
     assert sum(ahead) == eng.telemetry.counters["engine.ahead_steps"]
     assert any(ahead) == (kind == "dense")
-    assert all(set(s.attrs) == {"kv_blocks"}
+    assert all(set(s.attrs) == {"kv_blocks", "kv_rows"}
                for s in named["repro.engine.dispatch"])
     # one growth pass per step that has active lanes, inside begin
     assert len(named["repro.kv.grow"]) == len(decode)
@@ -172,9 +172,11 @@ def test_dsg_flop_model_lives_in_counters(engine_parts):
                                    c["dsg.csr_units"], c["dsg.bound_units"])
     engine = {k: v for k, v in c.items() if k.startswith("engine.")}
     assert engine == {"engine.drains": eng.steps}   # one read per step
+    kv = {k: v for k, v in c.items() if k.startswith("kv.")}
+    assert set(kv) == {"kv.rows_written"} and kv["kv.rows_written"] > 0
     eng.telemetry.count("other")
     eng.telemetry.reset_counters("dsg.")
-    assert dict(c) == {"other": 1, **engine}
+    assert dict(c) == {"other": 1, **engine, **kv}
     with pytest.raises(ValueError, match="no decode steps"):
         rt.flop_stats()
 
